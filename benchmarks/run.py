@@ -313,6 +313,9 @@ def main() -> None:
             os.environ.get("XLA_FLAGS", ""),
             f"--xla_force_host_platform_device_count={args.devices}"]))
 
+    from repro import compile_cache
+
+    compile_cache.enable()
     if args.engine == "fleetsim":
         run_fleetsim(args)
         return

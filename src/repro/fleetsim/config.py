@@ -181,11 +181,12 @@ class FleetConfig:
     # matching serve.engine.DecodeReplica); 1 halves per-slot progress at
     # full occupancy (compute-bound prefill-heavy regime).
     batch_coupling: float = 0.0
-    # response-filter backend: "vectorized" (one scatter/tick, default),
-    # "scan" (exact lane-sequential switch_jax.filter semantics), "pallas"
-    # (kernels.fingerprint_filter — the VMEM-resident filter kernel), or
-    # "tickfuse" (kernels.tickfuse — StateT + filter fused in ONE kernel,
-    # both tables VMEM-resident; what EngineOptions selects on accelerators)
+    # response-filter backend: "vectorized" (one scatter/tick, default on
+    # every platform), "scan" (exact lane-sequential switch_jax.filter
+    # semantics), "pallas" (kernels.fingerprint_filter — the SMEM filter
+    # kernel), or "tickfuse" (kernels.tickfuse — StateT + filter fused in
+    # ONE kernel).  The two kernels run only where a config names them, and
+    # their tables must fit the kernel's SMEM (checked below)
     filter_backend: str = "vectorized"
     # log-spaced latency histogram (≈6% bin resolution over 1 µs … 2 s)
     hist_bins: int = 256
@@ -206,6 +207,8 @@ class FleetConfig:
         if self.filter_backend not in ("vectorized", "scan", "pallas",
                                        "tickfuse"):
             raise ValueError(f"unknown filter_backend {self.filter_backend!r}")
+        if self.filter_backend in ("pallas", "tickfuse"):
+            self._check_kernel_smem()
         if self.arrival not in ("poisson", "trace"):
             raise ValueError(f"unknown arrival kind {self.arrival!r}")
         if self.n_servers < 2:
@@ -239,6 +242,26 @@ class FleetConfig:
                     f"hedge_wheel_slots must exceed the delay horizon "
                     f"({self.hedge_delay_ticks} ticks) so an armed entry "
                     "cannot alias a pending slot")
+
+    def _check_kernel_smem(self) -> None:
+        """The switch kernels hold one fabric's table stack in SMEM; refuse
+        a fabric whose tables cannot fit instead of failing in the TPU
+        compiler (or silently running another backend)."""
+        from repro.kernels.tickfuse import SMEM_LIMIT_BYTES, smem_bytes
+
+        if self.filter_backend == "tickfuse":
+            servers, lanes = self.n_servers_total, self.max_responses
+        else:  # pallas: no StateT, lanes padded to the kernel's 256 block
+            servers, lanes = 0, -(-self.max_responses // 256) * 256
+        need = smem_bytes((self.n_racks + 1) * self.n_filter_tables,
+                          self.n_filter_slots, servers, lanes)
+        if need > SMEM_LIMIT_BYTES:
+            raise ValueError(
+                f"filter_backend={self.filter_backend!r} needs {need} B of "
+                f"TPU SMEM for {self.n_racks} racks x {self.n_filter_tables}"
+                f" x {self.n_filter_slots}-slot filter tables, over the "
+                f"kernel limit SMEM_LIMIT_BYTES={SMEM_LIMIT_BYTES}; use "
+                "filter_backend='vectorized' or smaller tables")
 
     @property
     def n_groups(self) -> int:

@@ -219,6 +219,30 @@ def _core_telemetry(cfg: FleetConfig, params: RunParams
     return state.metrics, state.trace, state.series
 
 
+#: the threefry PRNG mode every engine program traces and lowers under —
+#: the mode the checked-in goldens were captured with (JAX's default before
+#: it flipped ``jax_threefry_partitionable`` to True), pinned so results do
+#: not depend on the process-wide flag
+THREEFRY_PARTITIONABLE = False
+
+
+class PinnedPrng:
+    """A jitted function whose calls and lowerings run under
+    :data:`THREEFRY_PARTITIONABLE` (the flag is part of jit's cache key, so
+    a process that flips it globally still reuses the pinned program)."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+
+    def __call__(self, *args, **kwargs):
+        with jax.threefry_partitionable(THREEFRY_PARTITIONABLE):
+            return self._jitted(*args, **kwargs)
+
+    def lower(self, *args, **kwargs):
+        with jax.threefry_partitionable(THREEFRY_PARTITIONABLE):
+            return self._jitted.lower(*args, **kwargs)
+
+
 # One jitted entry per execution shape (backend × batch × telemetry ×
 # donation × fused chunk length), built on demand and cached so every
 # caller of the same shape shares one jit cache.  The compiled programs
@@ -248,8 +272,8 @@ def _entry(backend: str, batch: bool, telemetry: bool, donate: bool,
             return jax.vmap(lambda p: core(cfg, p))(params)
         return core(cfg, params)
 
-    return jax.jit(run, static_argnames=("cfg", "registry_version"),
-                   donate_argnums=(2,) if donate else ())
+    return PinnedPrng(jax.jit(run, static_argnames=("cfg", "registry_version"),
+                              donate_argnums=(2,) if donate else ()))
 
 
 def _check_telemetry(cfg: FleetConfig) -> None:

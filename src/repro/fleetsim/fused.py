@@ -17,12 +17,12 @@ semantics:
   unpacked inside the chunk.  Integer round-trips within the bound are
   exact, so packing cannot change a single bit of the results.  REQ_ID
   carriers (spine ``seq``, filter tables, client dedup) stay int32;
-* **fused switch kernel** — where Pallas is native (TPU/GPU), the switch
-  response path runs as the TickFuse megakernel
-  (``repro.kernels.tickfuse``): StateT write + fingerprint filter in one
-  launch with both switch tables VMEM-resident, selected per platform via
-  ``cfg.filter_backend`` (CPU keeps the measured-fastest ``vectorized``
-  scatter path).
+* **fused switch kernel (opt-in)** — a config with
+  ``filter_backend="tickfuse"`` runs the switch response path as the
+  TickFuse kernel (``repro.kernels.tickfuse``): StateT write + fingerprint
+  filter in one launch.  Nothing selects it automatically — the default
+  stays the ``vectorized`` scatter path on every platform until a chip
+  measurement says which is faster.
 
 Because every tick replays :func:`repro.fleetsim.stages.build_step`
 verbatim — same PRNG draws, same op order — the fused backend is

@@ -20,18 +20,19 @@ Backends
 ``'fused'``
     TickFuse (``repro.fleetsim.fused``): the same staged tick, chunked
     ``K`` ticks per outer scan step with the integer state dtype-packed at
-    chunk boundaries, and (on accelerators) the switch response path fused
-    into one Pallas kernel with both switch tables VMEM-resident.
+    chunk boundaries.  The switch response path runs as the TickFuse
+    Pallas kernel only when the config asks for it
+    (``FleetConfig.filter_backend="tickfuse"``); no backend selects it.
     **Bit-identical** to ``'staged'`` on the non-stage policy matrix
     (baseline / c-clone / netclone / racksched / netclone+racksched) — the
     chunks replay the exact staged tick ops in the exact order, and integer
     pack/round-trips are exact.  Stage policies (laedge / hedge) and
     telemetry are not supported; ``'auto'`` falls back for them.
 ``'auto'``
-    ``'fused'`` where it is native and supported (TPU/GPU, no optional
-    stage, no telemetry), ``'staged'`` otherwise — CPU included, where the
-    Pallas kernels only run in interpret mode and the staged program is the
-    measured-fastest path (see docs/architecture.md, "TickFuse megakernel").
+    ``'fused'`` on an accelerator backend (TPU/GPU) when the config has
+    no optional stage and no telemetry, ``'staged'`` otherwise — CPU
+    included, where the staged program is the measured-fastest path (see
+    docs/architecture.md, "TickFuse megakernel").
 
 The JSON form (:meth:`to_json` / :meth:`from_json`) is the strict-keyed
 ``engine`` sub-object scenario and sweep files carry, mirroring ``shard``.

@@ -40,7 +40,6 @@ histogram equality (see :func:`repro.fleetsim.validate.shard_equivalence`).
 
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 from typing import NamedTuple
 
@@ -49,20 +48,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec
 
-try:  # jax <= 0.4.x: shard_map lives in experimental and needs
-    # check_rep=False (no replication rule for the while-loop inside
-    # jax.random.poisson; nothing here relies on inferred replication —
-    # the only collective is the explicit psum)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
-except ImportError:  # newer jax: the public API, check_rep → check_vma
-    from jax import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_vma": False}
-
 from repro.fleetsim.config import FleetConfig
-from repro.fleetsim.engine import RunParams, _entry, _simulate_core
+from repro.fleetsim.engine import (
+    PinnedPrng,
+    RunParams,
+    _entry,
+    _simulate_core,
+)
 from repro.fleetsim.state import Metrics
 from repro.scenarios import registry
 
@@ -201,12 +193,9 @@ def plan_grid(params: RunParams, spec: ShardSpec) -> GridPlan:
 # (post-compile policy registrations must retrace the grown switch tables)
 # and additionally on the mesh + backend, so layout and backend changes
 # each get their own executable.
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "registry_version", "mesh",
-                                    "backend", "ticks_per_chunk"))
-def _simulate_sharded_jit(cfg: FleetConfig, registry_version: int,
-                          mesh: Mesh, params: RunParams, mask: jax.Array,
-                          backend: str = "staged", ticks_per_chunk: int = 0):
+def _simulate_sharded(cfg: FleetConfig, registry_version: int,
+                      mesh: Mesh, params: RunParams, mask: jax.Array,
+                      backend: str = "staged", ticks_per_chunk: int = 0):
     axis = mesh.axis_names[0]
     if backend == "fused":
         from repro.fleetsim.fused import fused_core
@@ -229,11 +218,17 @@ def _simulate_sharded_jit(cfg: FleetConfig, registry_version: int,
 
     spec_g = PartitionSpec(axis)
     # the psum's result is replicated by construction, which is what the
-    # P() out_spec declares; the replication *checker* is disabled at the
-    # import site above (_SHARD_MAP_KW) for jax-version reasons
-    return _shard_map(slab, mesh=mesh, in_specs=(spec_g, spec_g),
-                      out_specs=(spec_g, PartitionSpec()),
-                      **_SHARD_MAP_KW)(params, mask)
+    # P() out_spec declares; the varying-manual-axes checker is off because
+    # the while loop inside jax.random.poisson has no rule for it (nothing
+    # here relies on inferred replication — the only collective is the psum)
+    return jax.shard_map(slab, mesh=mesh, in_specs=(spec_g, spec_g),
+                         out_specs=(spec_g, PartitionSpec()),
+                         check_vma=False)(params, mask)
+
+
+_simulate_sharded_jit = PinnedPrng(jax.jit(
+    _simulate_sharded, static_argnames=("cfg", "registry_version", "mesh",
+                                        "backend", "ticks_per_chunk")))
 
 
 def lower_sharded(cfg: FleetConfig, plan: GridPlan,

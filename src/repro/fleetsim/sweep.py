@@ -36,6 +36,7 @@ from repro.fleetsim.shard import (
     lower_sharded,
     plan_grid,
 )
+from repro.fleetsim.state import Metrics
 from repro.fleetsim.telemetry import RunTelemetry, decode_run
 from repro.fleetsim.telemetry.device import SeriesState, TraceBuffer
 from repro.scenarios import registry
@@ -67,6 +68,9 @@ class SweepResult:
     # backend exposes it; None otherwise
     cost_flops: float | None = None
     cost_bytes: float | None = None
+    # the raw host Metrics the results were summarized from (leading axis:
+    # grid rows, padding stripped) — for bit-identity checks across runs
+    metrics: Metrics | None = field(default=None, repr=False)
 
     @property
     def simulated_mrps(self) -> float:
@@ -90,15 +94,12 @@ class SweepResult:
 
 def compiled_cost(compiled) -> tuple[float | None, float | None]:
     """Pull ``(flops, bytes accessed)`` out of a compiled program's
-    ``cost_analysis()`` — best effort: backends that expose nothing (or a
-    different shape; older jax returned a list of dicts) yield ``None``s
-    rather than failing the sweep."""
+    ``cost_analysis()`` — best effort: backends that expose nothing yield
+    ``None``s rather than failing the sweep."""
     try:
         ca = compiled.cost_analysis()
     except Exception:
         return None, None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     if not isinstance(ca, dict):
         return None, None
     flops = ca.get("flops")
@@ -325,4 +326,5 @@ def sweep_grid(
         telemetry=telemetry,
         cost_flops=cost_flops,
         cost_bytes=cost_bytes,
+        metrics=metrics,
     )

@@ -1,18 +1,19 @@
-"""Pallas TPU kernel: NetClone fingerprint filter (paper §3.5) in VMEM.
+"""Pallas TPU kernel: NetClone fingerprint filter (paper §3.5).
 
 The switch keeps its filter tables in register arrays updated at line rate;
-the TPU analogue keeps them resident in VMEM and processes a whole batch of
-responses per kernel launch.  Semantics are *sequential in lane order* —
-identical to packets traversing the pipeline one after another — which is why
-the update loop is a ``fori_loop`` over the batch rather than a vectorized
-scatter (two responses of the same request in one batch must see each other's
-writes).
+the TPU analogue processes a whole batch of responses per kernel launch.
+Semantics are *sequential in lane order* — identical to packets traversing
+the pipeline one after another — which is why the update loop is a
+``fori_loop`` over the batch rather than a vectorized scatter (two responses
+of the same request in one batch must see each other's writes).
 
-Memory budget: ``n_tables × n_slots × 4 B`` must fit VMEM alongside the
-response block; the prototype's 2×2¹⁷ 32-bit slots are 1.05 MB — an easy fit
-(v5e VMEM ≈ 128 MB/core).  The batch dimension is tiled by the grid; the
-tables use a single whole-array block aliased in/out so the grid steps see
-each other's updates.
+This is the TickFuse kernel (:mod:`repro.kernels.tickfuse`) with no StateT:
+each launch DMAs the tables from HBM into SMEM scratch (the only TPU memory
+with scalar stores), runs the lane loop and DMAs them back.  The budget is
+:func:`~repro.kernels.tickfuse.smem_bytes` against
+:data:`~repro.kernels.tickfuse.SMEM_LIMIT_BYTES` (1 MiB less one 4 KiB
+tile, what the v5e compiler accepts): the serving cluster's 2×4096 slots
+(padded to 8 rows) take 136 KiB; the prototype's 2×2¹⁷ slots do not fit.
 """
 
 from __future__ import annotations
@@ -21,84 +22,30 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-_HASH_MULT = 2654435761
-
-
-def _filter_kernel(req_id_ref, idx_ref, clo_ref, tables_in_ref, tables_ref,
-                   drop_ref):
-    """One grid step: process a block of responses sequentially.
-
-    ``tables_ref`` (the output) is aliased onto ``tables_in_ref`` — all reads
-    and writes go through the output ref so successive grid steps observe each
-    other's updates, exactly like the switch's register arrays."""
-    del tables_in_ref  # aliased with tables_ref
-    n_slots = tables_ref.shape[1]
-    block = req_id_ref.shape[0]
-
-    def body(i, _):
-        rid = req_id_ref[i]
-        idx = idx_ref[i]
-        clo = clo_ref[i]
-        # multiplicative fingerprint hash (matches repro.core.tables)
-        x = (rid.astype(jnp.uint32) * jnp.uint32(_HASH_MULT)) >> jnp.uint32(15)
-        slot = (x % jnp.uint32(n_slots)).astype(jnp.int32)
-        occupant = tables_ref[idx, slot]
-        hit = (clo > 0) & (occupant == rid)
-        # hit  → clear the slot and drop the (slower) response
-        # miss → insert/overwrite the fingerprint and forward
-        new_val = jnp.where(hit, jnp.int32(0), rid)
-
-        @pl.when(clo > 0)
-        def _():
-            tables_ref[idx, slot] = new_val
-
-        drop_ref[i] = hit.astype(jnp.int32)
-        return 0
-
-    jax.lax.fori_loop(0, block, body, 0)
+from repro.kernels.tickfuse import switch_response
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def fingerprint_filter(
-    tables: jax.Array,   # (n_tables, n_slots) int32 — VMEM-resident state
+    tables: jax.Array,   # (n_tables, n_slots) int32 — resident filter state
     req_id: jax.Array,   # (B,) int32
     idx: jax.Array,      # (B,) int32  filter-table index (IDX field)
     clo: jax.Array,      # (B,) int32  CLO field (0 → pass-through)
     *,
     block: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
-    """Returns ``(new_tables, drop)`` with exact switch semantics."""
-    b = req_id.shape[0]
-    if b % block != 0:
-        pad = block - b % block
-        req_id = jnp.pad(req_id, (0, pad))
-        idx = jnp.pad(idx, (0, pad))
-        clo = jnp.pad(clo, (0, pad))          # CLO=0 padding never touches tables
-    bp = req_id.shape[0]
-    grid = (bp // block,)
+    """Returns ``(new_tables, drop)`` with exact switch semantics.
 
-    new_tables, drop = pl.pallas_call(
-        _filter_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),           # req_id
-            pl.BlockSpec((block,), lambda i: (i,)),           # idx
-            pl.BlockSpec((block,), lambda i: (i,)),           # clo
-            pl.BlockSpec(tables.shape, lambda i: (0, 0)),     # tables (whole)
-        ],
-        out_specs=[
-            pl.BlockSpec(tables.shape, lambda i: (0, 0)),     # tables out
-            pl.BlockSpec((block,), lambda i: (i,)),           # drop
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(tables.shape, tables.dtype),
-            jax.ShapeDtypeStruct((bp,), jnp.int32),
-        ],
-        input_output_aliases={3: 0},
-        interpret=interpret,
-    )(req_id.astype(jnp.int32), idx.astype(jnp.int32), clo.astype(jnp.int32),
-      tables)
+    The lanes are padded to a multiple of ``block`` (CLO=0 padding never
+    touches the tables), so callers with a varying batch size reuse a few
+    compiled shapes."""
+    b = req_id.shape[0]
+    lanes = [jnp.pad(a.astype(jnp.int32), (0, -b % block))
+             for a in (req_id, idx, clo)]
+    # no StateT: zero servers, so no sid passes the `sid < n_servers` guard
+    zeros = jnp.zeros_like(lanes[0])
+    _, new_tables, drop = switch_response(
+        zeros[:0], tables, *lanes, zeros, zeros, interpret=interpret)
     return new_tables, drop[:b].astype(bool)

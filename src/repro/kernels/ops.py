@@ -1,11 +1,15 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``impl`` selection: the kernels target TPU; on this CPU container they run in
-``interpret=True`` mode (Python-evaluated kernel bodies — bit-exact semantics,
-not speed).  Model code calls through these wrappers with ``impl="auto"``,
-which picks the real kernel on TPU backends and the pure-XLA reference
-otherwise, so the 512-device dry-run lowers plain XLA HLO while the kernels
-stay the TPU hot-spot implementation.
+The kernels target TPU.  On a CPU backend (the test suite) they run in
+Pallas ``interpret`` mode — Python-evaluated kernel bodies, bit-exact
+semantics, not speed; on any other non-TPU backend they refuse to run
+(:func:`interpret_mode`), so a kernel is never silently interpreted where a
+compiled one was expected.  Model code calls through these wrappers with
+``impl="auto"``, which picks the real kernel on TPU backends and the
+pure-XLA reference otherwise, so the 512-device dry-run lowers plain XLA HLO
+while the kernels stay the TPU hot-spot implementation.  The switch kernels
+(fingerprint filter, TickFuse) have no XLA twin here — the FleetSim engine
+selects them explicitly through ``FleetConfig.filter_backend``.
 """
 
 from __future__ import annotations
@@ -24,6 +28,20 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run interpreted on this process's backend:
+    False on TPU (compiled), True on CPU, and an error anywhere else."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels compile for TPU and are interpreted only on "
+        f"CPU; backend {backend!r} has neither (run with JAX_PLATFORMS=cpu "
+        f"or pick a non-kernel implementation)")
+
+
 def attention(q, k, v, *, causal=True, window=None, sm_scale=None,
               impl: str = "auto", block_q: int = 256, block_k: int = 256):
     """Multi-head attention; q (B,H,S,D), k/v (B,Hkv,S,D)."""
@@ -34,27 +52,22 @@ def attention(q, k, v, *, causal=True, window=None, sm_scale=None,
                                   sm_scale=sm_scale)
     return _flash_attention(q, k, v, causal=causal, window=window,
                             sm_scale=sm_scale, block_q=block_q,
-                            block_k=block_k, interpret=not _on_tpu())
+                            block_k=block_k, interpret=interpret_mode())
 
 
-def fingerprint_filter(tables, req_id, idx, clo, *, impl: str = "auto",
-                       block: int = 256):
+def fingerprint_filter(tables, req_id, idx, clo, *, block: int = 256):
     """NetClone response filter tick; returns (new_tables, drop_mask)."""
-    if impl == "auto":
-        impl = "pallas"  # the data-structure kernel runs fine interpreted
     return _fingerprint_filter(tables, req_id, idx, clo, block=block,
-                               interpret=not _on_tpu())
+                               interpret=interpret_mode())
 
 
-def tickfuse_response_path(server_state, tables, req_id, idx, clo, sid, qlen,
-                           *, impl: str = "auto", block: int = 128):
+def tickfuse_response_path(server_state, tables, req_id, idx, clo, sid,
+                           qlen):
     """Fused FleetSim switch response path (StateT write + fingerprint
-    filter, both VMEM-resident); returns (new_server_state, new_tables,
+    filter in one launch); returns (new_server_state, new_tables,
     drop_mask)."""
-    if impl == "auto":
-        impl = "pallas"  # the data-structure kernel runs fine interpreted
     return _tickfuse(server_state, tables, req_id, idx, clo, sid, qlen,
-                     block=block, interpret=not _on_tpu())
+                     interpret=interpret_mode())
 
 
 def ssd_scan(x, a, b_mat, c_mat, h0=None, *, impl: str = "auto",
@@ -65,7 +78,7 @@ def ssd_scan(x, a, b_mat, c_mat, h0=None, *, impl: str = "auto",
     if impl == "xla":
         return _ref.ssd_scan_ref(x, a, b_mat, c_mat, h0)
     return _ssd_scan(x, a, b_mat, c_mat, h0, chunk=chunk,
-                     interpret=not _on_tpu())
+                     interpret=interpret_mode())
 
 
 def lru_scan(x, a, h0=None, *, impl: str = "auto", chunk: int = 256,
@@ -76,4 +89,4 @@ def lru_scan(x, a, h0=None, *, impl: str = "auto", chunk: int = 256,
     if impl == "xla":
         return _ref.lru_scan_ref(x, a, h0)
     return _lru_scan(x, a, h0, chunk=chunk, block_d=block_d,
-                     interpret=not _on_tpu())
+                     interpret=interpret_mode())

@@ -16,18 +16,11 @@ from __future__ import annotations
 import jax
 
 
-def _axis_types_kw(n_axes: int) -> dict:
-    # jax.sharding.AxisType landed after 0.4.37; older jax uses Auto implicitly.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int | None = None, model: int = 1):
@@ -35,4 +28,4 @@ def make_host_mesh(data: int | None = None, model: int = 1):
     n = jax.device_count()
     data = data or max(1, n // model)
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_types_kw(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

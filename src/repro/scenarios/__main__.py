@@ -163,6 +163,9 @@ def main(argv: list[str] | None = None) -> int:
         return 0
     if not args.file:
         ap.error("need a scenario file (or --list)")
+    from repro import compile_cache
+
+    compile_cache.enable()
     run_file(args)
     return 0
 

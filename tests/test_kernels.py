@@ -6,16 +6,19 @@ naive recurrences.
 """
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core.switch_jax import filter_tick_oracle
-from repro.kernels import ref
+from repro.fleetsim import FleetConfig
+from repro.kernels import ops, ref
 from repro.kernels.fingerprint_filter import fingerprint_filter
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.lru_scan import lru_scan
 from repro.kernels.ssd_scan import ssd_scan
+from repro.kernels.tickfuse import tickfuse_response_path
 
 
 # ========================================================= flash attention ===
@@ -89,7 +92,8 @@ def test_fingerprint_filter_property(data, block):
     tables = np.zeros((2, 128), np.int32)
     got_t, got_d = fingerprint_filter(
         jnp.asarray(tables), jnp.asarray(rid, jnp.int32),
-        jnp.asarray(idx, jnp.int32), jnp.asarray(clo, jnp.int32), block=block)
+        jnp.asarray(idx, jnp.int32), jnp.asarray(clo, jnp.int32), block=block,
+        interpret=True)
     want_t, _, want_d = filter_tick_oracle(
         tables.astype(np.int64), np.zeros(1, np.int64), rid, idx, clo,
         np.zeros(len(rid), int), np.zeros(len(rid), int))
@@ -102,17 +106,81 @@ def test_fingerprint_filter_table_sizes():
     for n_slots in (1024, 4096):
         tables = jnp.zeros((2, n_slots), jnp.int32)
         rid = jnp.arange(1, 129, dtype=jnp.int32)
-        t, d = fingerprint_filter(tables, rid, rid % 2, jnp.ones(128, jnp.int32))
+        t, d = fingerprint_filter(tables, rid, rid % 2, jnp.ones(128, jnp.int32),
+                                  interpret=True)
         assert not bool(d.any())          # fresh ids are never dropped
-        t2, d2 = fingerprint_filter(t, rid, rid % 2, jnp.ones(128, jnp.int32))
+        t2, d2 = fingerprint_filter(t, rid, rid % 2, jnp.ones(128, jnp.int32),
+                                    interpret=True)
         assert bool(d2.all())             # every twin is dropped
     # tiny table: collisions overwrite — some twins escape, none misfire
     tables = jnp.zeros((2, 64), jnp.int32)
     rid = jnp.arange(1, 129, dtype=jnp.int32)
-    t, d = fingerprint_filter(tables, rid, rid % 2, jnp.ones(128, jnp.int32))
+    t, d = fingerprint_filter(tables, rid, rid % 2, jnp.ones(128, jnp.int32),
+                              interpret=True)
     assert not bool(d.any())
-    t2, d2 = fingerprint_filter(t, rid, rid % 2, jnp.ones(128, jnp.int32))
+    t2, d2 = fingerprint_filter(t, rid, rid % 2, jnp.ones(128, jnp.int32),
+                                interpret=True)
     assert bool(d2.any()) and not bool(d2.all())
+
+
+@pytest.mark.parametrize("vmapped", [False, True], ids=["once", "vmapped"])
+def test_tickfuse_matches_sequential_oracle(vmapped):
+    """StateT + filter in lane order, duplicates and same-tick slot
+    collisions included; under vmap the lanes are per fabric while the
+    resident tables start shared (the custom_vmap rule broadcasts them)."""
+    rng = np.random.default_rng(4)
+    n_fab, lanes, n_srv = 3, 40, 5
+    tables = np.zeros((3, 16), np.int32)       # 16 slots: collisions
+    rid = rng.integers(1, 25, (n_fab, lanes))  # repeats: twin responses
+    idx = rng.integers(0, 3, (n_fab, lanes))
+    clo = rng.integers(0, 3, (n_fab, lanes))
+    sid = rng.integers(0, n_srv + 1, (n_fab, lanes))  # n_srv: inactive
+    qlen = rng.integers(0, 9, (n_fab, lanes))
+    state = np.zeros(n_srv, np.int32)
+
+    def run(r, i, c, s, q):
+        return tickfuse_response_path(
+            jnp.asarray(state), jnp.asarray(tables), r, i, c, s, q,
+            interpret=True)
+
+    args = [jnp.asarray(a, jnp.int32) for a in (rid, idx, clo, sid, qlen)]
+    if vmapped:
+        got = jax.vmap(run)(*args)
+    else:
+        got = jax.tree.map(lambda *a: jnp.stack(a),
+                           *[run(*(a[f] for a in args))
+                             for f in range(n_fab)])
+    for f in range(n_fab):
+        # the oracle has a slot for the inactive sid; drop it afterwards
+        want_t, want_s, want_d = filter_tick_oracle(
+            tables.astype(np.int64), np.zeros(n_srv + 1, np.int64),
+            rid[f], idx[f], clo[f], sid[f], qlen[f])
+        assert np.array_equal(np.asarray(got[0][f]), want_s[:n_srv])
+        assert np.array_equal(np.asarray(got[1][f]), want_t)
+        assert np.array_equal(np.asarray(got[2][f]), want_d)
+
+
+def test_switch_kernels_refuse_tables_past_smem():
+    """A fabric whose filter tables cannot fit the kernels' SMEM is refused
+    at config construction, naming the limit — never run on another
+    backend in silence."""
+    assert FleetConfig(n_racks=123, filter_backend="tickfuse").n_racks == 123
+    with pytest.raises(ValueError, match="SMEM_LIMIT_BYTES"):
+        FleetConfig(n_racks=124, filter_backend="tickfuse")
+    with pytest.raises(ValueError, match="SMEM_LIMIT_BYTES"):
+        FleetConfig(n_filter_slots=2 ** 17, filter_backend="pallas")
+    # the vectorized filter keeps its tables in HBM: no such bound
+    assert FleetConfig(n_filter_slots=2 ** 17).n_filter_slots == 2 ** 17
+
+
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    """Kernels interpret on CPU, compile on TPU, and refuse elsewhere."""
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert ops.interpret_mode() is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.interpret_mode()
 
 
 # ================================================================ SSD scan ===
