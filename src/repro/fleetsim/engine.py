@@ -196,19 +196,23 @@ def make_params(cfg: FleetConfig, policy_id: int, rate_per_us: float,
 
 # ------------------------------------------------------------------ runner --
 def _simulate_core(cfg: FleetConfig, params: RunParams) -> FleetState:
-    gp = group_pairs_array(cfg.n_servers)
-    k_pois, k0 = jax.random.split(jax.random.PRNGKey(params.seed))
-    state = init_fleet_state(cfg, k0)
-    step = build_step(cfg, params, gp)
-    ticks = jnp.arange(cfg.n_ticks, dtype=jnp.int32)
-    if cfg.arrival == "trace":
-        # replayed per-tick arrival counts ride in as the scan xs
-        n_raw = params.arrival_counts.astype(jnp.int32)
-    else:
-        # per-tick Poisson arrival counts, drawn once outside the scan
-        n_raw = jax.random.poisson(
-            k_pois, params.rate_per_us * cfg.dt_us, (cfg.n_ticks,)
-        ).astype(jnp.int32)
+    with jax.named_scope("fleetsim.init"):
+        gp = group_pairs_array(cfg.n_servers)
+        k_pois, k0 = jax.random.split(jax.random.PRNGKey(params.seed))
+        state = init_fleet_state(cfg, k0)
+        # the tick's per-run constants; the tick itself is traced inside
+        # the scan, under its own stage scopes
+        step = build_step(cfg, params, gp)
+    with jax.named_scope("fleetsim.draw"):
+        ticks = jnp.arange(cfg.n_ticks, dtype=jnp.int32)
+        if cfg.arrival == "trace":
+            # replayed per-tick arrival counts ride in as the scan xs
+            n_raw = params.arrival_counts.astype(jnp.int32)
+        else:
+            # per-tick Poisson arrival counts, drawn once outside the scan
+            n_raw = jax.random.poisson(
+                k_pois, params.rate_per_us * cfg.dt_us, (cfg.n_ticks,)
+            ).astype(jnp.int32)
     state, _ = jax.lax.scan(step, state, (ticks, n_raw))
     return state
 

@@ -124,31 +124,38 @@ def fused_core(cfg: FleetConfig, params,
             "coordinator/hedge_timer/telemetry configs run staged "
             "(EngineOptions(backend='auto') routes them automatically)")
     k = resolve_chunk(cfg, ticks_per_chunk)
-    gp = group_pairs_array(cfg.n_servers)
-    k_pois, k0 = jax.random.split(jax.random.PRNGKey(params.seed))
-    state = init_fleet_state(cfg, k0)
-    step = build_step(cfg, params, gp)
-    ticks = jnp.arange(cfg.n_ticks, dtype=jnp.int32)
-    if cfg.arrival == "trace":
-        n_raw = params.arrival_counts.astype(jnp.int32)
-    else:
-        n_raw = jax.random.poisson(
-            k_pois, params.rate_per_us * cfg.dt_us, (cfg.n_ticks,)
-        ).astype(jnp.int32)
+    with jax.named_scope("fleetsim.init"):
+        gp = group_pairs_array(cfg.n_servers)
+        k_pois, k0 = jax.random.split(jax.random.PRNGKey(params.seed))
+        state = init_fleet_state(cfg, k0)
+        step = build_step(cfg, params, gp)
+    with jax.named_scope("fleetsim.draw"):
+        ticks = jnp.arange(cfg.n_ticks, dtype=jnp.int32)
+        if cfg.arrival == "trace":
+            n_raw = params.arrival_counts.astype(jnp.int32)
+        else:
+            n_raw = jax.random.poisson(
+                k_pois, params.rate_per_us * cfg.dt_us, (cfg.n_ticks,)
+            ).astype(jnp.int32)
 
     n_chunks, n_tail = divmod(cfg.n_ticks, k)
 
     def chunk(packed, xs):
-        st = unpack_state(packed)
+        with jax.named_scope("fleetsim.pack"):
+            st = unpack_state(packed)
         st, _ = jax.lax.scan(step, st, xs)
-        return pack_state(cfg, st), None
+        with jax.named_scope("fleetsim.pack"):
+            return pack_state(cfg, st), None
 
     n_main = n_chunks * k
+    with jax.named_scope("fleetsim.pack"):
+        packed = pack_state(cfg, state)
     packed, _ = jax.lax.scan(
-        chunk, pack_state(cfg, state),
+        chunk, packed,
         (ticks[:n_main].reshape(n_chunks, k),
          n_raw[:n_main].reshape(n_chunks, k)))
-    state = unpack_state(packed)
+    with jax.named_scope("fleetsim.pack"):
+        state = unpack_state(packed)
     if n_tail:
         state, _ = jax.lax.scan(step, state,
                                 (ticks[n_main:], n_raw[n_main:]))

@@ -943,6 +943,11 @@ def build_step(cfg: FleetConfig, params, group_pairs: jax.Array):
     behaviour plugs into a stage through the registry (route branch, spine
     placement, coordinator rule, hedge destination) instead of forking
     this function.
+
+    Each stage runs under a ``jax.named_scope`` (``tick.<stage>``), so
+    every op the tick compiles to carries its stage in its ``op_name``
+    metadata and a profiler trace attributes device time per stage.  The
+    scopes are metadata only: the compiled ops and results do not change.
     """
     # in-network constants added to every recorded latency (client TX + four
     # link hops + two pipeline passes + the spine tier's round trip when the
@@ -964,26 +969,38 @@ def build_step(cfg: FleetConfig, params, group_pairs: jax.Array):
     xhop = jnp.float32(cfg.interrack_extra_us)
 
     def step(state: FleetState, xs):
-        state, arr = stage_arrival(cfg, params, state, xs)
-        state, arr, routed, lanes = stage_route(cfg, params, state, arr,
-                                                group_pairs, xhop)
-        state, lanes = stage_coordinator(cfg, params, state, arr, routed,
-                                         lanes)
-        state, lanes = stage_hedge_timer(cfg, params, state, arr, routed,
-                                         lanes)
+        with jax.named_scope("tick.arrival"):
+            state, arr = stage_arrival(cfg, params, state, xs)
+        with jax.named_scope("tick.route"):
+            state, arr, routed, lanes = stage_route(cfg, params, state, arr,
+                                                    group_pairs, xhop)
+        with jax.named_scope("tick.coordinator"):
+            state, lanes = stage_coordinator(cfg, params, state, arr,
+                                             routed, lanes)
+        with jax.named_scope("tick.hedge_timer"):
+            state, lanes = stage_hedge_timer(cfg, params, state, arr,
+                                             routed, lanes)
         # ChaosFuzz link failures (repro.fleetsim.chaos): copies onto a
         # dead link vanish before the servers, responses from partitioned
         # servers vanish before the filter switch.  Inert windows keep
         # both stages value-identical to the pre-chaos pipeline.
-        state, lanes = stage_link_failure(cfg, params, state, arr, lanes)
-        state, resp = stage_server(cfg, params, state, arr, lanes)
-        state, resp = stage_link_response(cfg, params, state, arr, resp)
-        state, drop = stage_response_filter(cfg, params, state, arr, resp)
-        state = stage_client(cfg, params, state, arr, resp, drop, const_lat)
+        with jax.named_scope("tick.link"):
+            state, lanes = stage_link_failure(cfg, params, state, arr, lanes)
+        with jax.named_scope("tick.server"):
+            state, resp = stage_server(cfg, params, state, arr, lanes)
+        with jax.named_scope("tick.link"):
+            state, resp = stage_link_response(cfg, params, state, arr, resp)
+        with jax.named_scope("tick.filter"):
+            state, drop = stage_response_filter(cfg, params, state, arr,
+                                                resp)
+        with jax.named_scope("tick.client"):
+            state = stage_client(cfg, params, state, arr, resp, drop,
+                                 const_lat)
         if cfg.telemetry:
-            state = state._replace(series=series_tick(
-                cfg, state.series, state.metrics, state.queues.count,
-                arr.tick))
+            with jax.named_scope("tick.telemetry"):
+                state = state._replace(series=series_tick(
+                    cfg, state.series, state.metrics, state.queues.count,
+                    arr.tick))
         return state, None
 
     return step

@@ -13,7 +13,6 @@ one program), and ``shard`` lays the grid out over a device mesh
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 
 import jax
@@ -36,6 +35,7 @@ from repro.fleetsim.shard import (
     lower_sharded,
     plan_grid,
 )
+from repro.fleetsim.spans import compile_counts, compile_events, phase
 from repro.fleetsim.state import Metrics
 from repro.fleetsim.telemetry import RunTelemetry, decode_run
 from repro.fleetsim.telemetry.device import SeriesState, TraceBuffer
@@ -71,6 +71,13 @@ class SweepResult:
     # the raw host Metrics the results were summarized from (leading axis:
     # grid rows, padding stripped) — for bit-identity checks across runs
     metrics: Metrics | None = field(default=None, repr=False)
+    # host seconds per phase of the call (repro.fleetsim.spans):
+    # params, lower, compile, device, fetch, summarize.  compile_s is
+    # lower + compile and wall_clock_s is device
+    phases: dict[str, float] = field(default_factory=dict)
+    # jaxpr traces, MLIR lowerings, backend compiles (count and seconds)
+    # and persistent-cache hits/misses during the call (spans.COUNTERS)
+    compile_events: dict[str, float] = field(default_factory=dict)
 
     @property
     def simulated_mrps(self) -> float:
@@ -131,56 +138,12 @@ def rack_skew(cfg: FleetConfig, hot_rack_weight: float = 1.0,
     return weights, slowdown.reshape(-1)
 
 
-def sweep_grid(
-    service,
-    policies: list[str],
-    loads: list[float],
-    seeds: list[int],
-    cfg: FleetConfig | None = None,
-    slowdown: np.ndarray | None = None,
-    rack_weights: np.ndarray | None = None,
-    fail_window_ticks: tuple[int, int] | None = None,
-    link_failure=None,
-    resize_arrival_lanes: bool = True,
-    hedge_delays: list[float] | None = None,
-    shard: ShardSpec | int | None = None,
-    engine: EngineOptions | None = None,
-    **cfg_kw,
-) -> SweepResult:
-    """Run every (policy, load, seed[, hedge delay]) combination in one
-    jitted program.
-
-    ``slowdown`` (shape ``(n_racks * n_servers,)`` or ``(n_racks,
-    n_servers)``) injects stragglers into every run; ``rack_weights``
-    (shape ``(n_racks,)``) skews the arrival mix toward hot racks (see
-    :func:`rack_skew` for the canonical one-hot-rack / one-straggler-rack
-    scenario); ``fail_window_ticks`` darkens the fabric over ``[t0, t1)``
-    ticks and wipes its soft state at recovery, for all runs;
-    ``link_failure`` (a :class:`repro.fleetsim.chaos.LinkFailure`) kills
-    the named server/rack links over its window, for all runs.
-    ``resize_arrival_lanes=False`` keeps ``cfg.max_arrivals`` exactly as
-    given (pinned array shapes — e.g. golden scenarios) instead of applying
-    Poisson headroom for the hottest load.
-
-    ``hedge_delays`` adds a *traced* hedge-delay axis
-    (``RunParams.hedge_delay_ticks``): at least one policy in the set must
-    use the ``hedge_timer`` stage, the timer wheel is deepened to the
-    largest delay automatically, and every hedge-policy result row records
-    its ``hedge_delay_us``.  The axis only multiplies policies that
-    actually read the delay — a policy without the ``hedge_timer`` hook
-    keeps its single row (reported with ``hedge_delay_us=0``) instead of
-    running per-delay duplicates.  ``shard`` (``None`` | device count |
-    ``ShardSpec``)
-    spreads the grid over a device mesh via :mod:`repro.fleetsim.shard`;
-    ``None`` compiles the exact single-device program.  ``engine``
-    (:class:`~repro.fleetsim.options.EngineOptions`) selects the execution
-    backend — staged or fused (TickFuse) — and may carry the shard layout
-    itself; passing a shard both ways is an error.
-
-    Returns host-side results plus wall-clock accounting (compile time
-    reported separately so sweep cost is judged on the steady-state
-    number).
-    """
+def _grid_inputs(service, policies, loads, seeds, cfg, slowdown,
+                 rack_weights, fail_window_ticks, link_failure,
+                 resize_arrival_lanes, hedge_delays, shard, engine, cfg_kw):
+    """Check a sweep's arguments and build its inputs: the stage-complete
+    config, the grid rows, the rate per load, the batched ``RunParams``,
+    the resolved backend, the shard layout and the engine options."""
     spec = _as_spec(service)
     if cfg is None:
         cfg = FleetConfig(service=spec, **cfg_kw)
@@ -259,63 +222,131 @@ def sweep_grid(
     # fused request fails here with the options-layer error when the
     # policy set compiled in a staged-only stage; 'auto' falls back
     backend = opts.resolve_backend(cfg)
-    tel_state = None
-    t0 = time.perf_counter()
-    if shard_spec is None:
-        run_opts = EngineOptions(backend=backend,
-                                 telemetry=cfg.telemetry,
-                                 ticks_per_chunk=opts.ticks_per_chunk)
-        compiled = lower(cfg, params, options=run_opts).compile()
-        t_compile = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        if cfg.telemetry:
-            metrics, trace, series = jax.block_until_ready(compiled(params))
-            tel_state = (trace, series)
-        else:
-            metrics = jax.block_until_ready(compiled(params))
-        wall = time.perf_counter() - t0
-        n_devices, n_pad, grid_hist = 1, 0, None
-    else:
-        plan = plan_grid(params, shard_spec)
-        compiled = lower_sharded(cfg, plan, backend=backend,
-                                 ticks_per_chunk=opts.ticks_per_chunk
-                                 ).compile()
-        t_compile = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        metrics, grid_hist = jax.block_until_ready(
-            compiled(plan.params, plan.mask))
-        wall = time.perf_counter() - t0
-        metrics = jax.tree.map(lambda a: a[:g], metrics)
-        n_devices, n_pad = plan.mesh.size, plan.n_pad
-        grid_hist = np.asarray(jax.device_get(grid_hist))
+    return cfg, grid, rates, params, backend, shard_spec, opts
 
-    cost_flops, cost_bytes = compiled_cost(compiled)
-    metrics = jax.device_get(metrics)
-    telemetry = None
-    if tel_state is not None:
-        trace, series = jax.device_get(tel_state)
-        telemetry = [
-            decode_run(cfg,
-                       TraceBuffer(count=trace.count[i], data=trace.data[i]),
-                       SeriesState(*(np.asarray(a)[i] for a in series)))
-            for i in range(g)]
-    if grid_hist is None:
-        # unsharded fallback: same aggregate, reduced on host (the device
-        # program stays the exact pre-shard one)
-        grid_hist = np.asarray(metrics.hist).sum(axis=0)
-    results = []
-    for i, (p, ld, s, hd) in enumerate(grid):
-        one = jax.tree.map(lambda a: a[i], metrics)
-        # policies that never arm the wheel report delay 0, not the
-        # config default a hedge co-policy happened to compile in
-        hd_report = hd if registry.needs_hedge_timer(p) else 0.0
-        results.append(summarize(cfg, one, policy=p, load=ld,
-                                 rate_per_us=rates[ld], seed=s,
-                                 hedge_delay_us=hd_report))
+
+def sweep_grid(
+    service,
+    policies: list[str],
+    loads: list[float],
+    seeds: list[int],
+    cfg: FleetConfig | None = None,
+    slowdown: np.ndarray | None = None,
+    rack_weights: np.ndarray | None = None,
+    fail_window_ticks: tuple[int, int] | None = None,
+    link_failure=None,
+    resize_arrival_lanes: bool = True,
+    hedge_delays: list[float] | None = None,
+    shard: ShardSpec | int | None = None,
+    engine: EngineOptions | None = None,
+    **cfg_kw,
+) -> SweepResult:
+    """Run every (policy, load, seed[, hedge delay]) combination in one
+    jitted program.
+
+    ``slowdown`` (shape ``(n_racks * n_servers,)`` or ``(n_racks,
+    n_servers)``) injects stragglers into every run; ``rack_weights``
+    (shape ``(n_racks,)``) skews the arrival mix toward hot racks (see
+    :func:`rack_skew` for the canonical one-hot-rack / one-straggler-rack
+    scenario); ``fail_window_ticks`` darkens the fabric over ``[t0, t1)``
+    ticks and wipes its soft state at recovery, for all runs;
+    ``link_failure`` (a :class:`repro.fleetsim.chaos.LinkFailure`) kills
+    the named server/rack links over its window, for all runs.
+    ``resize_arrival_lanes=False`` keeps ``cfg.max_arrivals`` exactly as
+    given (pinned array shapes — e.g. golden scenarios) instead of applying
+    Poisson headroom for the hottest load.
+
+    ``hedge_delays`` adds a *traced* hedge-delay axis
+    (``RunParams.hedge_delay_ticks``): at least one policy in the set must
+    use the ``hedge_timer`` stage, the timer wheel is deepened to the
+    largest delay automatically, and every hedge-policy result row records
+    its ``hedge_delay_us``.  The axis only multiplies policies that
+    actually read the delay — a policy without the ``hedge_timer`` hook
+    keeps its single row (reported with ``hedge_delay_us=0``) instead of
+    running per-delay duplicates.  ``shard`` (``None`` | device count |
+    ``ShardSpec``)
+    spreads the grid over a device mesh via :mod:`repro.fleetsim.shard`;
+    ``None`` compiles the exact single-device program.  ``engine``
+    (:class:`~repro.fleetsim.options.EngineOptions`) selects the execution
+    backend — staged or fused (TickFuse) — and may carry the shard layout
+    itself; passing a shard both ways is an error.
+
+    Returns host-side results plus wall-clock accounting (compile time
+    reported separately so sweep cost is judged on the steady-state
+    number): ``phases`` holds the host seconds of the call's ``params``,
+    ``lower``, ``compile``, ``device``, ``fetch`` and ``summarize`` phases,
+    each also a ``fleetsim.<phase>`` profiler span, and ``compile_events``
+    the compile counters the call added (:mod:`repro.fleetsim.spans`).
+    """
+    phases: dict[str, float] = {}
+    counts_before = compile_counts()
+    with phase(phases, "params"):
+        cfg, grid, rates, params, backend, shard_spec, opts = _grid_inputs(
+            service, policies, loads, seeds, cfg, slowdown, rack_weights,
+            fail_window_ticks, link_failure, resize_arrival_lanes,
+            hedge_delays, shard, engine, cfg_kw)
+    g = len(grid)
+    with phase(phases, "lower"):
+        if shard_spec is None:
+            args = (params,)
+            lowered = lower(cfg, params, options=EngineOptions(
+                backend=backend, telemetry=cfg.telemetry,
+                ticks_per_chunk=opts.ticks_per_chunk))
+        else:
+            plan = plan_grid(params, shard_spec)
+            args = (plan.params, plan.mask)
+            lowered = lower_sharded(cfg, plan, backend=backend,
+                                    ticks_per_chunk=opts.ticks_per_chunk)
+    with phase(phases, "compile"):
+        compiled = lowered.compile()
+    with phase(phases, "device"):
+        out = jax.block_until_ready(compiled(*args))
+    grid_hist = tel_state = None
+    if shard_spec is not None:
+        metrics, grid_hist = out
+        n_devices, n_pad = plan.mesh.size, plan.n_pad
+    else:
+        n_devices, n_pad = 1, 0
+        if cfg.telemetry:
+            metrics, *tel_state = out
+        else:
+            metrics = out
+
+    with phase(phases, "fetch"):
+        if grid_hist is not None:
+            metrics = jax.tree.map(lambda a: a[:g], metrics)
+            grid_hist = np.asarray(jax.device_get(grid_hist))
+        metrics = jax.device_get(metrics)
+        if tel_state is not None:
+            tel_state = jax.device_get(tel_state)
+    with phase(phases, "summarize"):
+        cost_flops, cost_bytes = compiled_cost(compiled)
+        telemetry = None
+        if tel_state is not None:
+            trace, series = tel_state
+            telemetry = [
+                decode_run(cfg,
+                           TraceBuffer(count=trace.count[i],
+                                       data=trace.data[i]),
+                           SeriesState(*(np.asarray(a)[i] for a in series)))
+                for i in range(g)]
+        if grid_hist is None:
+            # unsharded fallback: same aggregate, reduced on host (the
+            # device program stays the exact pre-shard one)
+            grid_hist = np.asarray(metrics.hist).sum(axis=0)
+        results = []
+        for i, (p, ld, s, hd) in enumerate(grid):
+            one = jax.tree.map(lambda a: a[i], metrics)
+            # policies that never arm the wheel report delay 0, not the
+            # config default a hedge co-policy happened to compile in
+            hd_report = hd if registry.needs_hedge_timer(p) else 0.0
+            results.append(summarize(cfg, one, policy=p, load=ld,
+                                     rate_per_us=rates[ld], seed=s,
+                                     hedge_delay_us=hd_report))
     return SweepResult(
         results=results,
-        wall_clock_s=wall,
-        compile_s=t_compile,
+        wall_clock_s=phases["device"],
+        compile_s=phases["lower"] + phases["compile"],
         n_configs=g,
         simulated_requests=sum(r.n_arrivals for r in results),
         n_devices=n_devices,
@@ -327,4 +358,6 @@ def sweep_grid(
         cost_flops=cost_flops,
         cost_bytes=cost_bytes,
         metrics=metrics,
+        phases=phases,
+        compile_events=compile_events(counts_before),
     )

@@ -22,7 +22,6 @@ library scenario reproduces the PR-2 single-ToR golden run bit-identically
 from __future__ import annotations
 
 import json
-import time
 from dataclasses import dataclass, replace
 from pathlib import Path
 
@@ -36,6 +35,7 @@ from repro.fleetsim.engine import make_params, simulate
 from repro.fleetsim.metrics import FleetResult, summarize
 from repro.fleetsim.options import EngineOptions
 from repro.fleetsim.shard import ShardSpec
+from repro.fleetsim.spans import compile_counts, compile_events, phase
 from repro.fleetsim.sweep import SweepResult, rack_skew, sweep_grid
 from repro.fleetsim.telemetry import RunTelemetry, TelemetrySpec, decode_run
 from repro.scenarios import registry
@@ -484,33 +484,48 @@ def run_scenarios(scenarios: list[Scenario], **cfg_overrides) -> SweepResult:
     Scenarios sharing a static config reuse one compiled program, and
     compilation is timed separately from the steady-state runs (matching
     ``sweep_grid``'s accounting, so MRPS numbers are comparable between
-    Poisson grids and trace replays)."""
+    Poisson grids and trace replays).  The call's host phases are
+    ``sweep_grid``'s, summed over scenarios: ``compile_s`` is ``lower`` +
+    ``compile`` and ``wall_clock_s`` is ``device``."""
     from repro.fleetsim.engine import lower
 
-    prepared = [(sc, sc.fleet_config(**cfg_overrides)) for sc in scenarios]
+    phases: dict[str, float] = {}
+    counts_before = compile_counts()
+    with phase(phases, "params"):
+        prepared = [(sc, sc.fleet_config(**cfg_overrides))
+                    for sc in scenarios]
     compiled: dict = {}
-    compile_s = 0.0
     # scenarios sharing a (static config, engine options) pair reuse one
     # compiled program — EngineOptions is frozen/hashable by design
     for sc, cfg in prepared:
         key = (cfg, sc.engine)
         if key not in compiled:
-            t0 = time.perf_counter()
-            compiled[key] = lower(cfg, sc.run_params(cfg),
-                                  options=sc.engine).compile()
-            compile_s += time.perf_counter() - t0
+            with phase(phases, "params"):
+                params = sc.run_params(cfg)
+            with phase(phases, "lower"):
+                lowered = lower(cfg, params, options=sc.engine)
+            with phase(phases, "compile"):
+                compiled[key] = lowered.compile()
     results = []
-    t0 = time.perf_counter()
     for sc, cfg in prepared:
-        m = jax.block_until_ready(compiled[cfg, sc.engine](sc.run_params(cfg)))
-        results.append(summarize(
-            cfg, jax.device_get(m), policy=sc.policy,
-            load=sc.effective_load(cfg.n_ticks),
-            rate_per_us=sc.rate_per_us(cfg.n_ticks), seed=sc.seed))
-    wall = time.perf_counter() - t0
-    return SweepResult(results=results, wall_clock_s=wall,
-                       compile_s=compile_s, n_configs=len(scenarios),
-                       simulated_requests=sum(r.n_arrivals for r in results))
+        with phase(phases, "params"):
+            params = sc.run_params(cfg)
+        with phase(phases, "device"):
+            m = jax.block_until_ready(compiled[cfg, sc.engine](params))
+        with phase(phases, "fetch"):
+            m = jax.device_get(m)
+        with phase(phases, "summarize"):
+            results.append(summarize(
+                cfg, m, policy=sc.policy,
+                load=sc.effective_load(cfg.n_ticks),
+                rate_per_us=sc.rate_per_us(cfg.n_ticks), seed=sc.seed))
+    return SweepResult(results=results, wall_clock_s=phases.get("device", 0.0),
+                       compile_s=(phases.get("lower", 0.0)
+                                  + phases.get("compile", 0.0)),
+                       n_configs=len(scenarios),
+                       simulated_requests=sum(r.n_arrivals for r in results),
+                       phases=phases,
+                       compile_events=compile_events(counts_before))
 
 
 # ------------------------------------------------------------------ library --

@@ -32,6 +32,7 @@ FLEETSIM_MODULES = [
     "repro.fleetsim.metrics",
     "repro.fleetsim.policies",
     "repro.fleetsim.shard",
+    "repro.fleetsim.spans",
     "repro.fleetsim.stages",
     "repro.fleetsim.state",
     "repro.fleetsim.sweep",
@@ -84,6 +85,7 @@ def test_pydoc_renders_fleetsim_module(modname):
 
 @pytest.mark.parametrize("modname", ["repro.fleetsim.stages",
                                      "repro.fleetsim.shard",
+                                     "repro.fleetsim.spans",
                                      "repro.fleetsim.sweep"])
 def test_public_api_is_docstringed(modname):
     pytest.importorskip("jax")
