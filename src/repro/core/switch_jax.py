@@ -150,9 +150,7 @@ def filter_tick_vectorized(state: SwitchState, req_id: jax.Array,
     """
     if active is None:
         active = jnp.ones(req_id.shape, bool)
-    req_id = req_id.astype(jnp.int32)
-    idx = idx.astype(jnp.int32)
-    n_tables, n_slots = state.filter_tables.shape
+    n_slots = state.filter_tables.shape[1]
 
     # lines 15-16: last write wins per server, in lane order (masked lanes
     # scatter out of bounds and are dropped)
@@ -161,10 +159,39 @@ def filter_tick_vectorized(state: SwitchState, req_id: jax.Array,
     server_state = state.server_state.at[sid_m].set(
         qlen.astype(jnp.int32), mode="drop")
 
+    tables, drop = filter_rows(state.filter_tables, n_slots, req_id, idx, clo,
+                               active)
+    new_state = state._replace(server_state=server_state, filter_tables=tables)
+    return new_state, FilterResult(drop=drop)
+
+
+def slot_cell(idx: jax.Array, slot: jax.Array, n_slots: int,
+              row_len: int) -> tuple[jax.Array, jax.Array]:
+    """Row and column of table ``idx``'s ``slot`` in a table stack laid out
+    row-major in rows of ``row_len`` slots: flat index
+    ``idx·n_slots + slot``.  ``row_len == n_slots`` is the plain
+    ``(n_tables, n_slots)`` stack."""
+    at = idx * n_slots + slot
+    return at // row_len, at % row_len
+
+
+def filter_rows(tables: jax.Array, n_slots: int, req_id: jax.Array,
+                idx: jax.Array, clo: jax.Array, active: jax.Array,
+                ) -> tuple[jax.Array, jax.Array]:
+    """The fingerprint filter of :func:`filter_tick_vectorized` over a
+    table stack of ``n_slots``-slot tables laid out row-major in rows of
+    ``tables.shape[1]`` slots, a divisor of ``n_slots`` (:func:`slot_cell`).
+
+    The layout changes which cell a lane reads and writes, never the
+    lanes' drops or the tables' contents.  Returns ``(tables, drop)``;
+    masked or CLO=0 lanes write one row past the end and are dropped.
+    """
+    req_id = req_id.astype(jnp.int32)
+    idx = idx.astype(jnp.int32)
     part = active & (clo > 0)                     # lanes touching FilterT
-    slot = fingerprint_hash_jax(req_id, n_slots)
-    occupant = state.filter_tables[idx, slot]
-    parked = occupant == req_id                   # fingerprint already there
+    row, col = slot_cell(idx, fingerprint_hash_jax(req_id, n_slots), n_slots,
+                         tables.shape[1])
+    parked = tables[row, col] == req_id           # fingerprint already there
     lane = jnp.arange(req_id.shape[0])
     same = (part[:, None] & part[None, :]
             & (req_id[:, None] == req_id[None, :])
@@ -177,10 +204,8 @@ def filter_tick_vectorized(state: SwitchState, req_id: jax.Array,
     # slot value after the whole group: parked0 XOR (group size odd)
     parked_final = jnp.where(n % 2 == 0, parked, ~parked)
     value = jnp.where(parked_final, req_id, jnp.int32(0))
-    idx_m = jnp.where(part, idx, jnp.int32(n_tables))
-    tables = state.filter_tables.at[idx_m, slot].set(value, mode="drop")
-    new_state = state._replace(server_state=server_state, filter_tables=tables)
-    return new_state, FilterResult(drop=drop)
+    row_m = jnp.where(part, row, jnp.int32(tables.shape[0]))
+    return tables.at[row_m, col].set(value, mode="drop"), drop
 
 
 @jax.jit

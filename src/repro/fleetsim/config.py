@@ -207,6 +207,11 @@ class FleetConfig:
         if self.filter_backend not in ("vectorized", "scan", "pallas",
                                        "tickfuse"):
             raise ValueError(f"unknown filter_backend {self.filter_backend!r}")
+        if self.filter_table_size > 2 ** 31 - 1:
+            raise ValueError(
+                f"{self.n_racks + 1} filter groups x {self.n_filter_tables} "
+                f"x {self.n_filter_slots} slots = {self.filter_table_size} "
+                "do not fit int32 flat indices (at most 2^31 - 1)")
         if self.filter_backend in ("pallas", "tickfuse"):
             self._check_kernel_smem()
         if self.arrival not in ("poisson", "trace"):
@@ -267,6 +272,19 @@ class FleetConfig:
     def n_groups(self) -> int:
         """GrpT entries per rack switch (ordered pairs of local servers)."""
         return self.n_servers * (self.n_servers - 1)
+
+    @property
+    def filter_table_size(self) -> int:
+        """Slots of the fabric's filter tables: every rack's group and the
+        spine's (``FabricSwitch.filter_tables``)."""
+        return (self.n_racks + 1) * self.n_filter_tables * self.n_filter_slots
+
+    @property
+    def filter_table_shape(self) -> tuple[int, int]:
+        """Shape of ``FabricSwitch.filter_tables``: the flat tables in rows
+        of the TPU's 128 lanes (of one table, where tables are smaller)."""
+        row = min(128, self.n_filter_slots)
+        return self.filter_table_size // row, row
 
     @property
     def n_servers_total(self) -> int:

@@ -61,10 +61,10 @@ import numpy as np
 
 from repro.core.header import CLO_CLONE, CLO_ORIG
 from repro.core.switch_jax import (
-    SwitchState,
     _filter_step,
-    filter_tick_vectorized,
+    filter_rows,
     fingerprint_hash_jax,
+    slot_cell,
 )
 from repro.fleetsim.config import (
     SERVICE_BIMODAL,
@@ -191,7 +191,7 @@ class Arrivals(NamedTuple):
     k_exec: jax.Array      # PRNG key for the server stage's execution draws
     k_stage: jax.Array     # PRNG key for optional-stage randomness
     sstate: jax.Array      # (ST,) flat tracked queue lengths
-    tables: jax.Array      # ((RK+1)·T, slots) flat filter-table stack
+    tables: jax.Array      # the carried filter tables, FabricSwitch layout
     active: jax.Array      # (A,) admitted arrival lanes
     grp: jax.Array         # (A,) GrpT index
     fidx: jax.Array        # (A,) filter-table index within a group
@@ -282,10 +282,9 @@ def stage_arrival(cfg: FleetConfig, params, state: FleetState, xs):
     # the coordinator node is NOT wiped: it is a server-side CPU box, not
     # switch soft state (matching the DES, whose coordinator queue and
     # outstanding counts survive a switch failure)
-    # flat views of the rack-major state (reshape is free and keeps every
-    # per-server op identical to the single-ToR engine)
+    # flat view of the rack-major StateT, so every per-server op is the
+    # one of the single-ToR engine (the filter tables keep their layout)
     sstate = switch.server_state.reshape(ST)
-    tables = switch.filter_tables.reshape((RK + 1) * T, cfg.n_filter_slots)
 
     key, k_arr, k_exec = jax.random.split(state.key, 3)
     k_stage = jax.random.fold_in(k_arr, 1)
@@ -326,8 +325,8 @@ def stage_arrival(cfg: FleetConfig, params, state: FleetState, xs):
                            metrics=m, wheel=wheel)
     return state, Arrivals(
         tick=tick, t_us=t_us, down=down, k_exec=k_exec, k_stage=k_stage,
-        sstate=sstate, tables=tables, active=arr_active, grp=grp, fidx=fidx,
-        client=client, base=base, home=home,
+        sstate=sstate, tables=switch.filter_tables, active=arr_active,
+        grp=grp, fidx=fidx, client=client, base=base, home=home,
         pair=None,               # GrpT lookup happens in stage_route
         r1=off + r1, r2=off + r2, r2_local=r2)
 
@@ -544,6 +543,15 @@ def wheel_fire(wheel: HedgeWheel, tick):
     return wheel._replace(count=wheel.count.at[slot].set(0)), due, entries
 
 
+def fingerprint_parked(cfg: FleetConfig, tables: jax.Array, idx: jax.Array,
+                       rid: jax.Array) -> jax.Array:
+    """Whether each ``rid``'s fingerprint is parked in filter table ``idx``
+    (numbered over (rack | spine) × table) of the carried tables."""
+    slot = fingerprint_hash_jax(rid, cfg.n_filter_slots)
+    return tables[slot_cell(idx, slot, cfg.n_filter_slots,
+                            tables.shape[1])] == rid
+
+
 def stage_hedge_timer(cfg: FleetConfig, params, state: FleetState,
                       arr: Arrivals, routed: Routed, lanes: Lanes):
     """Delayed hedging (compiled out unless ``cfg.hedge_timer``).
@@ -564,8 +572,7 @@ def stage_hedge_timer(cfg: FleetConfig, params, state: FleetState,
     rid = entries[:, WHEEL_RID].astype(jnp.int32)
     fidx = entries[:, WHEEL_IDX].astype(jnp.int32)
     frack = entries[:, WHEEL_FRACK].astype(jnp.int32)
-    slot_f = fingerprint_hash_jax(rid, cfg.n_filter_slots)
-    parked = arr.tables[frack * T + fidx, slot_f] == rid
+    parked = fingerprint_parked(cfg, arr.tables, frack * T + fidx, rid)
     fire = due & ~parked & ~arr.down     # a dark fabric loses the hedge
     cancelled = due & ~fire
     HW = fire.shape[0]
@@ -790,21 +797,20 @@ def stage_server(cfg: FleetConfig, params, state: FleetState,
 def stage_response_filter(cfg: FleetConfig, params, state: FleetState,
                           arr: Arrivals, resp: Responses):
     """Switch response path: per-rack StateT update + the fingerprint
-    filter at each pair's filter switch (one flattened-table call for the
-    whole fabric), plus the coordinator's response-side bookkeeping."""
+    filter at each pair's filter switch (one call for the whole fabric),
+    plus the coordinator's response-side bookkeeping."""
     RK, S = cfg.n_racks, cfg.n_servers
     T = cfg.n_filter_tables
     m = state.metrics
     # each response updates its own rack switch's StateT and runs the
-    # fingerprint filter at the pair's filter switch; flattening the
-    # (rack | spine) × table axes lets one call serve the whole fabric
+    # fingerprint filter at the pair's filter switch; numbering the
+    # (rack | spine) × table rows lets one call serve the whole fabric
     idx_flat = resp.frack * T + resp.idx
     sstate, tables, drop = _filter_responses(
         cfg, arr.sstate, arr.tables, resp.rid, idx_flat, resp.clo, resp.sid,
         resp.qlen, resp.active)
-    switch = state.switch._replace(
-        server_state=sstate.reshape(RK, S),
-        filter_tables=tables.reshape(RK + 1, T, cfg.n_filter_slots))
+    switch = state.switch._replace(server_state=sstate.reshape(RK, S),
+                                   filter_tables=tables)
     m = m._replace(
         n_filtered=m.n_filtered + (drop & resp.active).sum(),
         n_spine_filtered=m.n_spine_filtered
@@ -897,42 +903,47 @@ def _filter_responses(cfg, server_state, tables, rid, idx, clo, sid, qlen,
     fingerprint filter, with the backend chosen at compile time.
 
     ``server_state`` is the flat ``(n_racks·S,)`` tracked view, ``tables``
-    the flat ``((n_racks+1)·n_tables, n_slots)`` stack of every rack's
-    filter group plus the spine's, and ``idx`` pre-offset into it — so a
-    lane's (req_id, idx) group is unique per filter switch and the one-call
-    semantics match per-switch sequential filtering exactly.
+    the carried tables of every rack's filter group plus the spine's
+    (``FabricSwitch.filter_tables``), and ``idx`` the table pre-offset
+    into their ``(n_racks+1)·n_tables`` — so a lane's (req_id, idx) group is
+    unique per filter switch and the one-call semantics match per-switch
+    sequential filtering exactly.
     """
+    # inactive lanes never touch StateT: an out-of-range sid is dropped
+    sid_m = jnp.where(active, sid.astype(jnp.int32),
+                      jnp.int32(server_state.shape[0]))
     if cfg.filter_backend == "vectorized":
-        st = SwitchState(seq=jnp.zeros((), jnp.int32),
-                         server_state=server_state, filter_tables=tables)
-        new_st, res = filter_tick_vectorized(st, rid, idx, clo, sid, qlen,
-                                             active)
-        return new_st.server_state, new_st.filter_tables, res.drop
-    # scan / pallas / tickfuse: inactive lanes neutralised up front (CLO=0
-    # never touches the filter; an out-of-range sid never touches StateT)
-    sid_m = jnp.where(active, sid, jnp.int32(server_state.shape[0]))
+        server_state = server_state.at[sid_m].set(
+            qlen.astype(jnp.int32), mode="drop")
+        tables, drop = filter_rows(tables, cfg.n_filter_slots, rid, idx,
+                                   clo, active)
+        return server_state, tables, drop
+    # scan / pallas / tickfuse take the (table, slot) stack: CLO=0 lanes
+    # never touch the filter
     clo_m = jnp.where(active, clo, 0).astype(jnp.int32)
+    stack = tables.reshape(-1, cfg.n_filter_slots)
     if cfg.filter_backend == "tickfuse":
         # the fused megakernel: StateT write + fingerprint filter in one
         # launch, both tables resident (TickFuse, kernels/tickfuse.py)
         from repro.kernels.ops import tickfuse_response_path
 
-        return tickfuse_response_path(
-            server_state, tables, rid.astype(jnp.int32),
+        server_state, stack, drop = tickfuse_response_path(
+            server_state, stack, rid.astype(jnp.int32),
             idx.astype(jnp.int32), clo_m, sid_m, qlen.astype(jnp.int32))
+        return server_state, stack.reshape(tables.shape), drop
     # scan / pallas: StateT via a masked scatter, then the table update
     server_state = server_state.at[sid_m].set(
         qlen.astype(jnp.int32), mode="drop")
     if cfg.filter_backend == "scan":
-        tables, drop = jax.lax.scan(
-            _filter_step, tables,
+        stack, drop = jax.lax.scan(
+            _filter_step, stack,
             (rid.astype(jnp.int32), idx.astype(jnp.int32), clo_m))
     else:  # pallas — the VMEM-resident fingerprint kernel
         from repro.kernels.ops import fingerprint_filter
 
-        tables, drop = fingerprint_filter(
-            tables, rid.astype(jnp.int32), idx.astype(jnp.int32), clo_m)
-    return server_state, tables, drop
+        stack, drop = fingerprint_filter(
+            stack, rid.astype(jnp.int32), idx.astype(jnp.int32), clo_m)
+    return server_state, stack.reshape(tables.shape), drop
 
 
 # ---------------------------------------------------------------- pipeline --

@@ -81,15 +81,23 @@ class FabricSwitch(NamedTuple):
     dedup table and the filter fingerprints key on REQ_ID alone).  Each rack
     switch tracks only its own rack's piggybacked queue lengths; the spine's
     aggregated per-rack view used for inter-rack placement is derived from
-    the same array.  ``filter_tables`` stacks the per-rack table groups plus
+    the same array.  ``filter_tables`` holds the per-rack table groups plus
     one extra group (index ``n_racks``) for the spine, which filters the
     clone pairs whose copies span racks — the only point both responses of
-    such a pair traverse.
+    such a pair traverse.  The groups are laid out group-major as one
+    flat vector, slot ``s`` of table ``t`` in group ``g`` (a rack, or
+    ``n_racks`` for the spine) at flat index ``i = (g·n_tables + t)·n_slots
+    + s``, and carried in rows of ``row = min(128, n_slots)`` slots: at
+    ``[i // row, i % row]`` (``FleetConfig.filter_table_shape``).  The
+    tick reads and writes that cell and takes no view of another shape: on
+    the TPU each shape has its own tiled layout, so a view copies every
+    table, while rows of 128 int32 tile in plain row-major order, the
+    order the batched scatter's flattened operand needs.
     """
 
     seq: jax.Array            # () int32 — spine-global REQ_ID sequence
     server_state: jax.Array   # (n_racks, S) int32 — per-rack StateT/ShadowT
-    filter_tables: jax.Array  # (n_racks + 1, n_tables, n_slots) int32
+    filter_tables: jax.Array  # FleetConfig.filter_table_shape int32
 
 
 class RingQueues(NamedTuple):
@@ -206,9 +214,7 @@ def init_fabric_switch(cfg: FleetConfig) -> FabricSwitch:
     return FabricSwitch(
         seq=jnp.zeros((), jnp.int32),
         server_state=jnp.zeros((cfg.n_racks, cfg.n_servers), jnp.int32),
-        filter_tables=jnp.zeros(
-            (cfg.n_racks + 1, cfg.n_filter_tables, cfg.n_filter_slots),
-            jnp.int32),
+        filter_tables=jnp.zeros(cfg.filter_table_shape, jnp.int32),
     )
 
 

@@ -22,9 +22,9 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core.switch_jax import (
-    SwitchState,
+    filter_rows,
+    filter_tick_oracle,
     fingerprint_hash_jax,
-    filter_tick_vectorized,
 )
 from repro.core.workloads import ExponentialService, load_to_rate
 from repro.fleetsim import (
@@ -36,6 +36,7 @@ from repro.fleetsim import (
     simulate,
     summarize,
 )
+from repro.fleetsim.stages import fingerprint_parked
 from repro.fleetsim.sweep import sweep_grid
 
 SVC = ExponentialService(25.0)
@@ -85,23 +86,21 @@ def test_nracks1_bit_identical_to_single_tor_engine(case_i):
 
 # ------------------------------------------- exactly-once inter-rack filter --
 N_RACKS, N_TABLES, N_SLOTS = 2, 2, 1024
+FABRIC = FleetConfig(n_racks=N_RACKS, n_filter_tables=N_TABLES,
+                     n_filter_slots=N_SLOTS)
 
 
 def _fabric_filter(tables, rid, idx, active=None):
-    """One response tick through the flattened fabric filter, exactly as the
-    engine runs it (rack table groups + the spine group in one stack)."""
+    """One response tick through the fabric filter, exactly as the engine
+    runs it (rack table groups + the spine group in the carried layout)."""
     rid = jnp.asarray(rid, jnp.int32)
     if active is None:
         active = jnp.ones(rid.shape, bool)
-    state = SwitchState(seq=jnp.zeros((), jnp.int32),
-                        server_state=jnp.zeros((4,), jnp.int32),
-                        filter_tables=tables)
-    new_state, res = filter_tick_vectorized(
-        state, rid, jnp.asarray(idx, jnp.int32),
+    tables, drop = filter_rows(
+        tables, N_SLOTS, rid, jnp.asarray(idx, jnp.int32),
         jnp.ones(rid.shape, jnp.int32),            # CLO > 0: touches FilterT
-        jnp.zeros(rid.shape, jnp.int32), jnp.zeros(rid.shape, jnp.int32),
         jnp.asarray(active))
-    return new_state.filter_tables, np.asarray(res.drop)
+    return tables, np.asarray(drop)
 
 
 def _slot(rid):
@@ -111,7 +110,7 @@ def _slot(rid):
 def _exactly_once(pairs):
     """Feed each (rid, row, split) pair's two responses through the fabric
     filter — same tick or split across two — and count drops per pair."""
-    tables = jnp.zeros(((N_RACKS + 1) * N_TABLES, N_SLOTS), jnp.int32)
+    tables = jnp.zeros(FABRIC.filter_table_shape, jnp.int32)
     tick1, tick2 = [], []
     for rid, row, split in pairs:
         tick1.append((rid, row))
@@ -162,6 +161,117 @@ def test_interrack_pairs_filtered_exactly_once_property(pairs):
             seen.add(key)
             kept.append((rid, row, split))
     _exactly_once(kept)
+
+
+# ------------------------------------------- the carried filter-table layout --
+def _carried(cfg, tables3d):
+    """(group, table, slot) tables in the layout the engine carries."""
+    return jnp.asarray(np.asarray(tables3d, np.int32)
+                       .reshape(cfg.filter_table_shape))
+
+
+def _random_lanes(rng, cfg, n_keys):
+    """Lanes over every table of the fabric, spine group included: distinct
+    ids on distinct (table, slot) cells, some keys repeated in the same
+    tick, in a shuffled lane order."""
+    n_rows, slots = (cfg.n_racks + 1) * cfg.n_filter_tables, \
+        cfg.n_filter_slots
+    rid, idx, cells = [], [], set()
+    while len(rid) < n_keys:
+        r, i = int(rng.integers(1, 2 ** 20)), int(rng.integers(0, n_rows))
+        cell = (i, int(fingerprint_hash_jax(jnp.int32(r), slots)))
+        if cell not in cells:  # no different-id collision in one tick
+            cells.add(cell)
+            rid.append(r)
+            idx.append(i)
+    dup = list(rng.choice(n_keys, n_keys // 4, replace=False))
+    dup += dup[:3]             # some keys three times
+    rid, idx = np.array(rid + [rid[k] for k in dup]), \
+        np.array(idx + [idx[k] for k in dup])
+    order = rng.permutation(len(rid))
+    return rid[order], idx[order]
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("racks", [1, 4])
+def test_carried_filter_matches_oracle_per_group(racks, seed):
+    """The engine's filter over the carried layout (rows of 128 slots)
+    drops and writes what the sequential switch does, group by group: the
+    spine group of a 4-rack fabric, same-tick duplicate keys, fingerprints
+    already parked, and masked or CLO=0 lanes, which write nothing."""
+    cfg = FleetConfig(n_racks=racks, n_filter_slots=1024)
+    G, T, S = racks + 1, cfg.n_filter_tables, cfg.n_filter_slots
+    rng = np.random.default_rng(seed)
+    rid, idx = _random_lanes(rng, cfg, 64)
+    clo = rng.integers(0, 3, len(rid))
+    active = rng.random(len(rid)) < 0.8
+    tables = np.zeros((G * T, S), np.int64)
+    for k in rng.choice(len(rid), 20):   # parked fingerprints and strangers
+        slot = int(fingerprint_hash_jax(jnp.int32(int(rid[k])), S))
+        tables[idx[k], slot] = rid[k] if k % 2 else rid[k] + 1
+    new, drop = filter_rows(_carried(cfg, tables), S, jnp.asarray(rid),
+                            jnp.asarray(idx), jnp.asarray(clo),
+                            jnp.asarray(active))
+    want, want_drop = tables.reshape(G, T, S).copy(), np.zeros(len(rid), bool)
+    for g in range(G):
+        lanes = np.flatnonzero(active & (idx // T == g))
+        zero = np.zeros(len(lanes), np.int64)
+        want[g], _, want_drop[lanes] = filter_tick_oracle(
+            want[g], np.zeros(1, np.int64), rid[lanes], idx[lanes] % T,
+            clo[lanes], zero, zero)
+    touched = active & (clo > 0)
+    assert (touched & (idx // T == racks)).any()         # the spine group
+    assert np.array_equal(np.asarray(drop), want_drop)
+    assert np.asarray(drop).any() and not np.asarray(drop)[~touched].any()
+    assert np.array_equal(np.asarray(new).reshape(G, T, S), want)
+
+
+def test_masked_lanes_write_nothing():
+    """A tick whose lanes are all masked or CLO=0 leaves every table as it
+    was and drops nothing, even where it would hit a parked fingerprint."""
+    rng = np.random.default_rng(5)
+    rid, idx = _random_lanes(rng, FABRIC, 32)
+    tables = rng.integers(0, 2 ** 20, (FABRIC.filter_table_size,))
+    slot = np.asarray(fingerprint_hash_jax(jnp.asarray(rid, jnp.int32),
+                                           N_SLOTS))
+    tables[idx * N_SLOTS + slot] = rid     # every lane's fingerprint parked
+    carried = _carried(FABRIC, tables)
+    active = np.arange(len(rid)) % 2 == 0
+    clo = np.where(active, 0, 2)           # masked lanes would clone
+    new, drop = filter_rows(carried, N_SLOTS, jnp.asarray(rid),
+                            jnp.asarray(idx), jnp.asarray(clo),
+                            jnp.asarray(active))
+    assert not np.asarray(drop).any()
+    assert np.array_equal(np.asarray(new), np.asarray(carried))
+
+
+@pytest.mark.parametrize("racks", [1, 4])
+def test_hedge_parked_lookup_at_carried_index(racks):
+    """The hedge timer's cancel test reads the fingerprint at (group, table,
+    slot) of the carried tables, spine group included."""
+    cfg = FleetConfig(n_racks=racks, n_filter_slots=1024)
+    G, T, S = racks + 1, cfg.n_filter_tables, cfg.n_filter_slots
+    rng = np.random.default_rng(racks)
+    tables = rng.integers(1, 2 ** 20, (G, T, S))
+    rid, idx = _random_lanes(rng, cfg, 64)
+    frack, fidx = idx // T, idx % T
+    slot = np.asarray(fingerprint_hash_jax(jnp.asarray(rid, jnp.int32), S))
+    tables[frack[:32], fidx[:32], slot[:32]] = rid[:32]
+    want = tables[frack, fidx, slot] == rid
+    got = fingerprint_parked(cfg, _carried(cfg, tables), jnp.asarray(idx),
+                             jnp.asarray(rid))
+    assert want[:32].all() and not want.all()
+    assert (frack == racks).any()                        # the spine group
+    assert np.array_equal(np.asarray(got), want)
+
+
+def test_filter_tables_must_fit_int32_indices():
+    """The carried tables are indexed with int32: a fabric whose tables hold
+    2^31 slots is refused, one just below passes."""
+    ok = FleetConfig(n_racks=2, n_filter_tables=5, n_filter_slots=2 ** 27)
+    assert ok.filter_table_size == 15 * 2 ** 27 < 2 ** 31
+    with pytest.raises(ValueError, match="int32"):
+        FleetConfig(n_racks=3, n_filter_tables=4, n_filter_slots=2 ** 27)
 
 
 # --------------------------------------------------------- fabric behavior --

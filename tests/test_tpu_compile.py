@@ -8,7 +8,9 @@ module fixture, never at import, and the file skips where it cannot be
 described.
 """
 
+import re
 from dataclasses import replace
+from math import prod
 
 import jax
 import jax.numpy as jnp
@@ -116,3 +118,89 @@ def test_fused_tickfuse_sweep_program_compiles(one_chip, monkeypatch):
     vec = lower(replace(cfg, filter_backend="vectorized"), params,
                 options=EngineOptions(backend="fused")).compile()
     assert "tpu_custom_call" not in vec.as_text()
+
+
+# ------------------------------------------------ the filter tables' layout --
+ROWS = 40          # testbed.switch5: 5 policies x 8 loads
+#: async moves between HBM and VMEM (the layout stays): counted apart
+_MOVES = ("copy-start", "copy-done", "slice-start", "slice-done")
+_INSTR = re.compile(r"(ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w-]+)\((.*)$")
+
+
+def _computations(text: str) -> dict[str, list[tuple]]:
+    """Each computation of compiled HLO text: its top-level instructions
+    as ``(root, name, shape, opcode, rest)``."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and (m := _INSTR.match(line.strip())):
+            cur.append(m.groups())
+    return comps
+
+
+def _sizes(shape: str) -> list[int]:
+    """Element counts of the arrays in a (possibly tuple) shape."""
+    return [prod(int(d) for d in dims.split(",") if d)
+            for dims in re.findall(r"[a-z]\w*\[([\d,]*)\]", shape)]
+
+
+def tick_table_ops(text: str, n: int) -> dict[str, list[str]]:
+    """The ops of the tick loop's body whose result holds all ``n`` slots
+    of the filter tables, sorted into ``relayout`` (copy, reshape or
+    transpose, or a fusion whose root is one), ``move`` (an async copy
+    between memory spaces, which keeps the layout) and ``compute``.
+
+    The tick loop is the innermost ``while`` that carries the tables; a
+    fusion counts as its root op; bitcasts, parameters and tuple elements
+    are free and not counted."""
+    comps = _computations(text)
+    root = {c: op for c, insts in comps.items()
+            for is_root, _, _, op, _ in insts if is_root}
+
+    def carries(inst):
+        return inst[3] == "while" and n in _sizes(inst[2])
+
+    bodies = [re.search(r"body=%([\w.-]+)", inst[4]).group(1)
+              for insts in comps.values() for inst in insts if carries(inst)]
+    ticks = [b for b in bodies if not any(carries(i) for i in comps[b])]
+    assert len(ticks) == 1, ticks
+    out = {"relayout": [], "move": [], "compute": []}
+    for _, name, shape, op, rest in comps[ticks[0]]:
+        if n not in _sizes(shape) or shape.startswith("(") or op in (
+                "bitcast", "parameter", "get-tuple-element", "constant"):
+            continue
+        if op == "fusion":
+            op = root[re.search(r"calls=%([\w.-]+)", rest).group(1)]
+        if op in ("copy", "reshape", "transpose"):
+            out["relayout"].append(f"{name} {shape} {op}")
+        elif op in _MOVES or "ConcatBitcast" in rest:
+            out["move"].append(f"{name} {shape} {op}")
+        else:
+            out["compute"].append(f"{name} {shape} {op}")
+    return out
+
+
+@pytest.mark.parametrize("racks", [1, 4])
+def test_tick_keeps_the_filter_tables_in_place(one_chip, racks):
+    """The fused sweep program of the testbed cell (and of a 4-rack fabric)
+    takes no relayout of the filter tables inside the tick: no copy,
+    reshape or transpose has their size, and the only ops over them are
+    the recovery wipe's select and the filter's scatter."""
+    cfg = FleetConfig(n_racks=racks, n_servers=6, n_workers=15, n_clients=2,
+                      n_filter_tables=2, n_filter_slots=2 ** 17,
+                      n_ticks=1024)
+    one = make_params(cfg, 0, 0.5, 0)
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, (ROWS,) + a.shape, a.dtype), one)
+    text = lower(cfg, params,
+                 options=EngineOptions(backend="fused")).compile().as_text()
+    ops_ = tick_table_ops(text, ROWS * cfg.filter_table_size)
+    print(f"{racks} rack(s): {len(ops_['relayout'])} table-sized relayouts "
+          f"in the tick body; ops {ops_}")
+    assert ops_["relayout"] == []
+    assert sorted(o.split()[-1] for o in ops_["compute"]) == [
+        "scatter", "select"]
