@@ -24,6 +24,7 @@ from __future__ import annotations
 
 from repro.core.header import CLO_CLONE, CLO_NONE, CLO_ORIG, Request
 from repro.core.policies import SwitchPolicy, _clone_of
+from repro.core.switch import FILTER_SLOTS
 from repro.core.tables import FilterTables
 
 
@@ -35,7 +36,7 @@ class HedgePolicy(SwitchPolicy):
     uses_groups = True
 
     def __init__(self, n_servers, costs=None, delay_us: float = 75.0,
-                 n_filter_tables: int = 2, n_filter_slots: int = 2 ** 17):
+                 n_filter_tables: int = 2, n_filter_slots: int = FILTER_SLOTS):
         super().__init__(n_servers, costs)
         self.delay_us = float(delay_us)
         self.filter_tables = FilterTables(n_filter_tables, n_filter_slots)

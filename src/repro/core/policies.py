@@ -33,7 +33,7 @@ from collections.abc import Mapping
 import numpy as np
 
 from repro.core.header import CLO_CLONE, CLO_NONE, CLO_ORIG, Request, Response
-from repro.core.switch import NetCloneSwitch, SwitchCosts
+from repro.core.switch import FILTER_SLOTS, NetCloneSwitch, SwitchCosts
 from repro.core.tables import StateTable
 from repro.scenarios import registry
 
@@ -142,7 +142,7 @@ class NetClonePolicy(SwitchPolicy):
     uses_groups = True
 
     def __init__(self, n_servers, costs=None, n_filter_tables: int = 2,
-                 n_filter_slots: int = 2 ** 17, filtering_enabled: bool = True,
+                 n_filter_slots: int = FILTER_SLOTS, filtering_enabled: bool = True,
                  cloning_enabled: bool = True):
         super().__init__(n_servers, costs)
         self.switch = NetCloneSwitch(

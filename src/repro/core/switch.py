@@ -22,6 +22,10 @@ from dataclasses import dataclass
 from repro.core.header import CLO_CLONE, CLO_NONE, CLO_ORIG, Request, Response
 from repro.core.tables import FilterTables, GroupTable, StateTable
 
+#: slots per filter table in the prototype's switch; every DES policy that
+#: filters keeps tables of this size
+FILTER_SLOTS = 2 ** 17
+
 
 @dataclass(slots=True)
 class SwitchCosts:
@@ -43,7 +47,7 @@ class NetCloneSwitch:
         self,
         n_servers: int,
         n_filter_tables: int = 2,
-        n_filter_slots: int = 2 ** 17,
+        n_filter_slots: int = FILTER_SLOTS,
         costs: SwitchCosts | None = None,
         cloning_enabled: bool = True,
         filtering_enabled: bool = True,

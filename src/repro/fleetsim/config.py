@@ -140,13 +140,12 @@ class FleetConfig:
     # hook (registry coordinator / hedge_timer) become runnable.  Scenario
     # / sweep builders flip these automatically from the policy set.
     #
-    # coordinator: LÆDGE-style CPU queue node hanging off the top switch —
-    # a ring buffer of pending requests drained each tick by the policy's
-    # registered dispatch rule, throttled by a coord_cpu_us-per-packet
-    # credit (the paper's coordinator-CPU bottleneck).
+    # coordinator: LÆDGE-style CPU node hanging off the top switch — one
+    # FCFS CPU that spends coord_cpu_us on every request, duplicate
+    # dispatch and response packet (the paper's coordinator bottleneck),
+    # with a ring of requests waiting for an idle server.
     coordinator: bool = False
-    coordinator_cap: int = 2 ** 11      # pending-request ring slots
-    coordinator_drain: int = 0          # max pops per tick (0 → 2×arrivals)
+    coordinator_cap: int = 2 ** 11      # waiting-request ring slots
     coord_cpu_us: float = 1.5           # CPU per packet — matches the DES
     # hedge_timer: fixed-depth timer wheel ((n_slots, wheel_width) entries)
     # firing delayed duplicates hedge_delay_us after arrival unless the
@@ -225,6 +224,8 @@ class FleetConfig:
                              "(REQ_IDs are carried in float32 payloads)")
         if self.coordinator and self.coordinator_cap < 1:
             raise ValueError("coordinator_cap must be >= 1")
+        if self.coord_cpu_us < 0:
+            raise ValueError("coord_cpu_us must be >= 0")
         if self.server_model not in ("fcfs", "batch"):
             raise ValueError(f"unknown server_model {self.server_model!r} "
                              "(expected 'fcfs' or 'batch')")
@@ -339,10 +340,17 @@ class FleetConfig:
         return -(-self.n_ticks // self.window_ticks)
 
     @property
-    def drain_per_tick(self) -> int:
-        """Resolved coordinator drain bound: explicit, or twice the
-        arrival lanes (the backlog can shrink even at full admission)."""
-        return self.coordinator_drain or 2 * self.max_arrivals
+    def coord_sends_per_tick(self) -> int:
+        """Copies the coordinator can deliver to the servers in one tick.
+        Each copy leaves the CPU after a pass of its own, so at most
+        ``floor(dt / coord_cpu_us) + 1`` leave inside one tick; and no
+        tick creates more than two copies per arrival lane plus one per
+        response lane.  The smaller bound is exact: no copy waits past
+        its tick."""
+        made = 2 * self.max_arrivals + self.max_responses
+        if self.coord_cpu_us <= 0:
+            return made
+        return min(made, math.floor(self.dt_us / self.coord_cpu_us) + 1)
 
     @property
     def duration_us(self) -> float:

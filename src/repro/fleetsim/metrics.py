@@ -48,8 +48,11 @@ class FleetResult:
     n_dedup_evicted: int       # live client fingerprints lost to collisions
     empty_queue_fraction: float
     # staged-pipeline counters (nonzero only for coordinator / hedge runs)
-    n_coord_queued: int = 0    # requests parked at the coordinator node
-    n_coord_overflow: int = 0  # … lost to coordinator-ring exhaustion
+    n_coord_queued: int = 0    # requests accepted by the coordinator node
+    n_coord_overflow: int = 0  # … lost to waiting-ring exhaustion
+    n_coord_pending: int = 0   # … that waited for an idle server
+    n_coord_packets: int = 0   # coordinator CPU passes
+    n_coord_resp_waited: int = 0  # responses that found the CPU busy
     n_hedges_armed: int = 0    # timer-wheel entries armed
     n_hedges_cancelled: int = 0  # … cancelled (earlier response / fabric dark)
     n_wheel_dropped: int = 0   # … lost to wheel-slot exhaustion
@@ -90,7 +93,11 @@ class FleetResult:
             "redundant": self.n_redundant_at_client,
             "coord_queued": self.n_coord_queued,
             "coord_overflow": self.n_coord_overflow,
+            "coord_pending": self.n_coord_pending,
+            "coord_packets": self.n_coord_packets,
+            "coord_resp_waited": self.n_coord_resp_waited,
             "hedges_armed": self.n_hedges_armed,
+            "hedges_cancelled": self.n_hedges_cancelled,
             "hedge_delay_us": round(self.hedge_delay_us, 2),
             "slot_occupancy": round(self.mean_slot_occupancy, 3),
             "link_dropped_req": self.n_link_dropped_req,
@@ -170,6 +177,9 @@ def summarize(cfg: FleetConfig, metrics, *, policy: str, load: float,
                               if n_resp else 1.0),
         n_coord_queued=int(metrics.n_coord_queued),
         n_coord_overflow=int(metrics.n_coord_overflow),
+        n_coord_pending=int(metrics.n_coord_pending),
+        n_coord_packets=int(metrics.n_coord_packets),
+        n_coord_resp_waited=int(metrics.n_coord_resp_waited),
         n_hedges_armed=int(metrics.n_hedges_armed),
         n_hedges_cancelled=int(metrics.n_hedges_cancelled),
         n_wheel_dropped=int(metrics.n_wheel_dropped),

@@ -24,17 +24,18 @@ branches, so a flag-off program contains zero ops from them and the
 ``n_racks == 1`` goldens of the always-on policies stay bit-identical:
 
 * ``stage_coordinator`` (``cfg.coordinator``) — the LÆDGE coordinator
-  node: arrival lanes of policies registered with a ``coordinator`` hook
-  are parked in a ring buffer and drained each tick by the hook's rule
-  (clone to two random idle servers iff ≥ 2 are idle, forward to one when
-  exactly one is, queue otherwise), throttled by a CPU-credit model that
-  reproduces the DES coordinator's serialized ``coord_cpu_us``-per-packet
-  bottleneck;
+  node, one FCFS CPU as the DES models it: arrival lanes of policies
+  registered with a ``coordinator`` hook are received (one
+  ``coord_cpu_us`` pass each) and dispatched by the hook's rule (clone to
+  two random idle servers iff ≥ 2 are idle, forward to one when exactly
+  one is, wait in a ring otherwise); each copy leaves when the CPU has
+  served it, and ``stage_coordinator_responses`` charges every response a
+  pass and sends a waiting request onto the slot it frees;
 * ``stage_hedge_timer`` (``cfg.hedge_timer``) — a fixed-depth timer wheel:
   policies registered with a ``hedge_timer`` hook arm a deferred duplicate
   at arrival; one hedge delay later the wheel fires it as a CLO=2 copy
-  unless the original's response already passed the filter switch (the
-  parked fingerprint doubles as the DES's cancel-on-first-response).  The
+  unless ``stage_hedge_cancel`` marked the entry when the original's
+  response passed its switch (the DES's cancel-on-first-response).  The
   delay itself is a *traced* per-run input
   (``RunParams.hedge_delay_ticks``, defaulting to the static
   ``cfg.hedge_delay_us``), so a single vmapped — or mesh-sharded, see
@@ -60,12 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.header import CLO_CLONE, CLO_ORIG
-from repro.core.switch_jax import (
-    _filter_step,
-    filter_rows,
-    fingerprint_hash_jax,
-    slot_cell,
-)
+from repro.core.switch_jax import _filter_step, filter_rows
 from repro.fleetsim.config import (
     SERVICE_BIMODAL,
     SERVICE_EXPONENTIAL,
@@ -122,6 +118,7 @@ from repro.fleetsim.telemetry.events import (
     EV_CLONE,
     EV_COORD_DISPATCH,
     EV_COORD_ENQ,
+    EV_COORD_RESP,
     EV_FILTER_DROP,
     EV_HEDGE_ARMED,
     EV_HEDGE_CANCELLED,
@@ -247,6 +244,9 @@ class Responses(NamedTuple):
     frack: jax.Array
     sid: jax.Array
     qlen: jax.Array
+    # µs at which the coordinator's CPU forwards each response (set by
+    # stage_coordinator_responses; None when that stage is compiled out)
+    t_coord: jax.Array | None = None
 
 
 # ------------------------------------------------------------------- stages --
@@ -396,25 +396,50 @@ def stage_route(cfg: FleetConfig, params, state: FleetState, arr: Arrivals,
     return state, arr, Routed(req_id=req_id, cloned=cloned, frack=frack), lanes
 
 
+def _coord_send(coord, put, dst, rows):
+    """Append the copies ``put`` marks (to ``dst``, ``QF`` rows whose
+    ``QF_HOP`` holds the time they reach their server) to the coordinator's
+    ring of copies on their way, in lane order."""
+    cap = coord.out_dst.shape[0]
+    at = jnp.where(put, (coord.out_head + coord.out_count + _rank(put)) % cap,
+                   cap)
+    return coord._replace(
+        out_count=coord.out_count + put.sum(),
+        out_dst=coord.out_dst.at[at].set(dst.astype(jnp.int32), mode="drop"),
+        out_data=coord.out_data.at[at].set(rows, mode="drop"))
+
+
 def stage_coordinator(cfg: FleetConfig, params, state: FleetState,
                       arr: Arrivals, routed: Routed, lanes: Lanes):
-    """LÆDGE coordinator node (compiled out unless ``cfg.coordinator``).
+    """LÆDGE coordinator node, request side (compiled out unless
+    ``cfg.coordinator``).  The DES's coordinator in array form:
 
-    Arrival lanes of coordinator policies are parked in the ring instead of
-    dispatched; the drain then pops FCFS entries onto servers chosen by the
-    policy's registered rule, spending one CPU credit per transmitted copy.
-    Dispatches join the delivery lanes; the coordinator's ``outstanding``
-    view is decremented by the response stage."""
+    * arrival lanes of coordinator policies never dispatch at the switch;
+      the coordinator receives them in lane order, each for one CPU pass,
+      and decides at once by the policy's registered rule: two random idle
+      servers when ≥ 2 are idle (the duplicate costs a second pass), the
+      idle one when exactly one is, else the request waits in the ring
+      (or is lost when the ring is full, ``n_coord_overflow``; its pass is
+      spent all the same);
+    * a copy leaves when the CPU has served its pass and reaches its
+      server one link later: it waits in the ring of copies on their way
+      and joins the delivery lanes in that tick, its sub-tick remainder
+      kept in its hop term (≤ 0).
+
+    The response side (``stage_coordinator_responses``) frees the
+    outstanding slots and sends waiting requests.  Approximations, each
+    bounded by one tick: a tick's arrivals reach the CPU together at the
+    tick's start; a tick's responses reach it after those arrivals, in
+    lane order, and the next tick's decisions see them."""
     if not cfg.coordinator:
         return state, lanes
-    RK, S, W = cfg.n_racks, cfg.n_servers, cfg.n_workers
-    ST = RK * S
+    RK, W = cfg.n_racks, cfg.n_workers
+    ST = RK * cfg.n_servers
     A = cfg.max_arrivals
     CQ = cfg.coordinator_cap
-    CD = cfg.drain_per_tick
+    P = cfg.coord_sends_per_tick
     cpu = jnp.float32(cfg.coord_cpu_us)
-    dt = jnp.float32(cfg.dt_us)
-    credit_cap = jnp.float32(CD)
+    link = jnp.float32(cfg.link_us)
 
     m = state.metrics
     coord = state.coord
@@ -424,11 +449,53 @@ def stage_coordinator(cfg: FleetConfig, params, state: FleetState,
     # scalar: under vmap each sweep row takes its own value)
     lanes = lanes._replace(act=lanes.act & ~is_coord)
 
-    # -- park this tick's arrivals in the ring -----------------------------
-    enq = arr.active & is_coord
-    rank = _rank(enq)
-    ok = enq & (coord.count + rank < CQ)
-    slot = (coord.head + coord.count + rank) % CQ
+    # -- copies reaching their server this tick (FIFO in arrival time) -----
+    cap = coord.out_dst.shape[0]
+    j = jnp.arange(P)
+    at = (coord.out_head + j) % cap
+    out = coord.out_data[at]
+    due = (j < coord.out_count) & (out[:, QF_HOP] <= arr.t_us)
+    pay = out.at[:, QF_HOP].set(jnp.where(due, out[:, QF_HOP] - arr.t_us,
+                                          0.0))
+    lanes = lanes.extend(coord.out_dst[at], due,
+                         jnp.full(P, CLO_ORIG, jnp.int32), pay)
+    n_due = due.sum()
+    coord = coord._replace(out_head=(coord.out_head + n_due) % cap,
+                           out_count=coord.out_count - n_due)
+
+    # -- receive this tick's arrivals and decide, in lane order ------------
+    got = arr.active & is_coord
+    waiting = coord.count > 0
+    u = jax.random.uniform(arr.k_stage, (A, 2))
+    branches = registry.coordinator_branches()
+
+    def decide(outstanding, x):
+        g, u_j = x
+        idle = outstanding < W
+        n_idle = idle.sum()
+        s1, s2, want_clone = jax.lax.switch(
+            params.policy_id, branches, idle, n_idle, u_j[0], u_j[1])
+        # FCFS: a request never overtakes one already waiting (one waits
+        # only while no server is idle, so this is the DES's order too)
+        send = g & (n_idle >= 1) & ~waiting
+        clone = send & want_clone
+        outstanding = outstanding.at[jnp.where(send, s1, ST)].add(
+            1, mode="drop")
+        outstanding = outstanding.at[jnp.where(clone, s2, ST)].add(
+            1, mode="drop")
+        return outstanding, (send, clone, s1, s2)
+
+    outstanding, (send, clone, s1, s2) = jax.lax.scan(
+        decide, coord.outstanding, (got, u))
+
+    # -- the CPU serves the passes FCFS from max(now, its backlog) ---------
+    clone_f = clone.astype(jnp.float32)
+    cost = jnp.where(got, 1.0 + clone_f, 0.0) * cpu
+    spent = jnp.cumsum(cost)
+    start = jnp.maximum(arr.t_us, coord.cpu_free)
+    t_dup = start + spent                 # the duplicate's own pass ends
+    t_first = t_dup - clone_f * cpu       # the receipt ends: copy 1 leaves
+
     rows = jnp.stack([                               # (A, QF)
         arr.base,
         jnp.full(A, arr.t_us),
@@ -439,77 +506,107 @@ def stage_coordinator(cfg: FleetConfig, params, state: FleetState,
         jnp.zeros(A, jnp.float32),
         jnp.full(A, float(RK), jnp.float32),  # pairs filter at the top tier
     ], axis=1)
+    # copies are CLO_ORIG: ordinary at the servers, paired at the filter
+    sent = jnp.repeat(rows, 2, axis=0).at[:, QF_HOP].set(
+        (jnp.stack([t_first, t_dup], axis=1) + link).reshape(-1))
+    coord = _coord_send(coord, jnp.stack([send, clone], axis=1).reshape(-1),
+                        jnp.stack([s1, s2], axis=1).reshape(-1), sent)
+
+    # -- requests with no idle server wait in the ring ---------------------
+    pend = got & ~send
+    rank = _rank(pend)
+    ok = pend & (coord.count + rank < CQ)
+    slot = (coord.head + coord.count + rank) % CQ
     data = coord.data.at[jnp.where(ok, slot, CQ)].set(rows, mode="drop")
-    count = coord.count + ok.sum()
-    m = m._replace(n_coord_queued=m.n_coord_queued + ok.sum(),
-                   n_coord_overflow=m.n_coord_overflow + (enq & ~ok).sum())
+    m = m._replace(
+        n_cloned=m.n_cloned + clone.sum(),
+        n_coord_queued=m.n_coord_queued + (send | ok).sum(),
+        n_coord_overflow=m.n_coord_overflow + (pend & ~ok).sum(),
+        n_coord_pending=m.n_coord_pending + ok.sum(),
+        n_coord_packets=m.n_coord_packets + got.sum() + clone.sum())
     if cfg.telemetry:
-        state = state._replace(trace=emit(
-            state.trace, ok, tick=arr.tick, kind=EV_COORD_ENQ,
-            rid=routed.req_id, client=arr.client,
-            arg=coord.count + rank))  # arg: ring depth at enqueue
-
-    # -- drain: FCFS pops onto idle servers, CPU-credit throttled ----------
-    credit = jnp.minimum(coord.credit + dt / cpu, credit_cap)
-    u = jax.random.uniform(arr.k_stage, (CD, 2))
-    branches = registry.coordinator_branches()
-
-    def pop(carry, u_j):
-        outstanding, head, cnt, cred, spent = carry
-        idle = outstanding < W
-        n_idle = idle.sum()
-        s1, s2, want_clone = jax.lax.switch(
-            params.policy_id, branches, idle, n_idle, u_j[0], u_j[1])
-        # a backed-up CPU degrades to single-copy dispatch before it
-        # stalls — the same negative feedback the DES coordinator gets
-        # from its pipe-inflated outstanding counts
-        clone_want = want_clone & (cred >= 2.0)
-        cost = 1.0 + clone_want.astype(jnp.float32)
-        can = (cnt > 0) & (n_idle >= 1) & (cred >= cost) & is_coord
-        do_clone = can & clone_want
-        outstanding = outstanding.at[jnp.where(can, s1, ST)].add(
-            1, mode="drop")
-        outstanding = outstanding.at[jnp.where(do_clone, s2, ST)].add(
-            1, mode="drop")
-        row = data[head]
-        # CPU serialization inside the tick: the j-th transmitted copy
-        # waits for the copies before it
-        hop1 = (spent + 1.0) * cpu
-        hop2 = (spent + cost) * cpu
-        head = jnp.where(can, (head + 1) % CQ, head)
-        cnt = cnt - can.astype(jnp.int32)
-        spent = spent + jnp.where(can, cost, 0.0)
-        cred = cred - jnp.where(can, cost, 0.0)
-        return ((outstanding, head, cnt, cred, spent),
-                (can, do_clone, s1, s2, row, hop1, hop2))
-
-    (outstanding, head, count, credit, _spent), out = jax.lax.scan(
-        pop, (coord.outstanding, coord.head, count, credit,
-              jnp.float32(0.0)), u)
-    can, do_clone, s1, s2, row, hop1, hop2 = out
-    m = m._replace(n_cloned=m.n_cloned + do_clone.sum())
-    if cfg.telemetry:
-        rid_pop = row[:, QF_RID].astype(jnp.int32)
-        cli_pop = row[:, QF_CLIENT].astype(jnp.int32)
-        tr = emit(state.trace, can, tick=arr.tick, kind=EV_COORD_DISPATCH,
-                  rid=rid_pop, server=s1, client=cli_pop,
-                  arg=do_clone.astype(jnp.int32))
-        tr = emit(tr, do_clone, tick=arr.tick, kind=EV_CLONE,
-                  rid=rid_pop, server=s2, client=cli_pop,
+        tr = emit(state.trace, send | ok, tick=arr.tick, kind=EV_COORD_ENQ,
+                  rid=routed.req_id, client=arr.client,
+                  arg=jnp.where(ok, coord.count + rank, 0))  # ring depth
+        tr = emit(tr, send, tick=arr.tick, kind=EV_COORD_DISPATCH,
+                  rid=routed.req_id, server=s1, client=arr.client,
+                  arg=clone.astype(jnp.int32))
+        tr = emit(tr, clone, tick=arr.tick, kind=EV_CLONE,
+                  rid=routed.req_id, server=s2, client=arr.client,
                   arg=CLONE_SRC_COORD)
         state = state._replace(trace=tr)
 
-    pay1 = row.at[:, QF_HOP].set(jnp.where(can, hop1, 0.0))
-    pay2 = row.at[:, QF_HOP].set(jnp.where(do_clone, hop2, 0.0))
-    clo = jnp.full(CD, CLO_ORIG, jnp.int32)  # ordinary copies: never
-    lanes = lanes.extend(s1, can, clo, pay1)  # server-dropped, filter-paired
-    lanes = lanes.extend(s2, do_clone, clo, pay2)
-
     state = state._replace(
         metrics=m,
-        coord=coord._replace(outstanding=outstanding, head=head,
-                             count=count, data=data, credit=credit))
+        coord=coord._replace(outstanding=outstanding, data=data,
+                             count=coord.count + ok.sum(),
+                             cpu_free=start + spent[-1]))
     return state, lanes
+
+
+def stage_coordinator_responses(cfg: FleetConfig, params, state: FleetState,
+                                arr: Arrivals, resp: Responses):
+    """LÆDGE coordinator node, response side (compiled out unless
+    ``cfg.coordinator``).
+
+    Every response of a coordinator policy — the slower copy of a pair
+    too, which the filter then absorbs — reaches the coordinator one link,
+    a pipeline pass and a link after its server finished, frees its
+    server's outstanding slot at once (the DES counts it on receipt) and
+    waits for one CPU pass.  A response that frees a slot while requests
+    wait sends the oldest of them there, for a pass more.  Returns the
+    responses with ``t_coord``, when each leaves the CPU for its client."""
+    if not cfg.coordinator:
+        return state, resp
+    ST = cfg.n_servers_total
+    CQ = cfg.coordinator_cap
+    cpu = jnp.float32(cfg.coord_cpu_us)
+    link = jnp.float32(cfg.link_us)
+    m = state.metrics
+    coord = state.coord
+    is_coord = id_mask(params.policy_id, registry.coordinator_ids())
+
+    got = resp.active & is_coord
+    tau = arr.t_us + resp.hop + (2.0 * link + cfg.pipeline_pass_us)
+    rank = _rank(got)
+    # one waits only while every server holds n_workers copies, so the
+    # slot a response frees is the only idle one: it takes the next
+    drain = got & (rank < coord.count)
+    cost = jnp.where(got, 1.0 + drain.astype(jnp.float32), 0.0) * cpu
+    after = jnp.cumsum(cost)
+    before = after - cost
+    # FCFS from max(arrival, backlog): the CPU is free after lane k at
+    # after_k + max(cpu_free, max_{j<=k} tau_j - before_j)
+    late = jax.lax.cummax(jnp.where(got, tau - before, -jnp.inf))
+    base = jnp.maximum(coord.cpu_free, late)
+    t_resp = base + before + cpu          # the response leaves the CPU
+    t_send = base + after                 # … and the request it frees
+    prev_base = jnp.maximum(
+        coord.cpu_free, jnp.concatenate([jnp.full(1, -jnp.inf), late[:-1]]))
+    waited = got & (prev_base + before > tau)
+
+    outstanding = coord.outstanding.at[
+        jnp.where(got & ~drain, resp.sid, ST)].add(-1, mode="drop")
+    rows = coord.data[(coord.head + rank) % CQ]
+    n_drain = drain.sum()
+    coord = _coord_send(coord._replace(
+        outstanding=outstanding, head=(coord.head + n_drain) % CQ,
+        count=coord.count - n_drain,
+        cpu_free=base[-1] + after[-1]), drain, resp.sid,
+        rows.at[:, QF_HOP].set(t_send + link))
+    m = m._replace(
+        n_coord_packets=m.n_coord_packets + got.sum() + n_drain,
+        n_coord_resp_waited=m.n_coord_resp_waited + waited.sum())
+    state = state._replace(metrics=m, coord=coord)
+    if cfg.telemetry:
+        tr = emit(state.trace, got, tick=arr.tick, kind=EV_COORD_RESP,
+                  rid=resp.rid, server=resp.sid, client=resp.client,
+                  arg=jnp.round(t_resp - cpu - tau))  # arg: wait (µs)
+        tr = emit(tr, drain, tick=arr.tick, kind=EV_COORD_DISPATCH,
+                  rid=rows[:, QF_RID].astype(jnp.int32), server=resp.sid,
+                  client=rows[:, QF_CLIENT].astype(jnp.int32), arg=0)
+        state = state._replace(trace=tr)
+    return state, resp._replace(t_coord=t_resp)
 
 
 def wheel_arm(wheel: HedgeWheel, tick, delay_ticks, arm_mask,
@@ -529,27 +626,20 @@ def wheel_arm(wheel: HedgeWheel, tick, delay_ticks, arm_mask,
     data = wheel.data.at[slot, jnp.where(ok, pos, width)].set(
         entries, mode="drop")
     count = wheel.count.at[slot].add(ok.sum())
-    return HedgeWheel(count=count, data=data), ok, arm_mask & ~ok
+    return wheel._replace(count=count, data=data), ok, arm_mask & ~ok
 
 
 def wheel_fire(wheel: HedgeWheel, tick):
     """Pop every entry due at ``tick`` (the wheel is deeper than the delay
     horizon, so everything in the slot is due).  Returns ``(wheel,
-    due_mask, entries)`` with the slot cleared."""
+    due_mask, entries)`` with the slot cleared (its cancel marks too)."""
     n_slots, width, _ = wheel.data.shape
     slot = tick % n_slots
     due = jnp.arange(width) < wheel.count[slot]
     entries = wheel.data[slot]
-    return wheel._replace(count=wheel.count.at[slot].set(0)), due, entries
-
-
-def fingerprint_parked(cfg: FleetConfig, tables: jax.Array, idx: jax.Array,
-                       rid: jax.Array) -> jax.Array:
-    """Whether each ``rid``'s fingerprint is parked in filter table ``idx``
-    (numbered over (rack | spine) × table) of the carried tables."""
-    slot = fingerprint_hash_jax(rid, cfg.n_filter_slots)
-    return tables[slot_cell(idx, slot, cfg.n_filter_slots,
-                            tables.shape[1])] == rid
+    return wheel._replace(count=wheel.count.at[slot].set(0),
+                          cancelled=wheel.cancelled.at[slot].set(False)), \
+        due, entries
 
 
 def stage_hedge_timer(cfg: FleetConfig, params, state: FleetState,
@@ -557,23 +647,21 @@ def stage_hedge_timer(cfg: FleetConfig, params, state: FleetState,
     """Delayed hedging (compiled out unless ``cfg.hedge_timer``).
 
     Fires this tick's due duplicates as CLO=2 delivery lanes — unless the
-    original's response already parked its fingerprint at the lane's filter
-    switch, which is the array form of the DES's cancel-on-first-response —
-    then arms a wheel entry for every hedge-policy arrival."""
+    original's response already passed its switch, which
+    ``stage_hedge_cancel`` marked on the entry (the DES's
+    cancel-on-first-response) — then arms a wheel entry for every
+    hedge-policy arrival."""
     if not cfg.hedge_timer:
         return state, lanes
-    T = cfg.n_filter_tables
     A = cfg.max_arrivals
     m = state.metrics
     is_hedge = id_mask(params.policy_id, registry.hedge_timer_ids())
 
     # -- fire due entries --------------------------------------------------
+    gone = state.wheel.cancelled[arr.tick % state.wheel.count.shape[0]]
     wheel, due, entries = wheel_fire(state.wheel, arr.tick)
     rid = entries[:, WHEEL_RID].astype(jnp.int32)
-    fidx = entries[:, WHEEL_IDX].astype(jnp.int32)
-    frack = entries[:, WHEEL_FRACK].astype(jnp.int32)
-    parked = fingerprint_parked(cfg, arr.tables, frack * T + fidx, rid)
-    fire = due & ~parked & ~arr.down     # a dark fabric loses the hedge
+    fire = due & ~gone & ~arr.down       # a dark fabric loses the hedge
     cancelled = due & ~fire
     HW = fire.shape[0]
     pay = jnp.stack([                                # (HW, QF)
@@ -628,6 +716,33 @@ def stage_hedge_timer(cfg: FleetConfig, params, state: FleetState,
             rid=routed.req_id, server=dst2, client=arr.client,
             arg=params.hedge_delay_ticks))  # arg: delay (ticks)
     return state, lanes
+
+
+def stage_hedge_cancel(cfg: FleetConfig, params, state: FleetState,
+                       arr: Arrivals, resp: Responses) -> FleetState:
+    """Cancel the pending hedge of every request whose response passes its
+    switch this tick (compiled out unless ``cfg.hedge_timer``): the DES
+    drops the request's timer on its first response, before filtering.
+
+    The entry sits in the slot its arrival tick armed, found by REQ_ID, so
+    the cancel is exact.  At tick granularity a response cancels the
+    hedges due from the next tick on (the DES: those due 1.4 µs — a link,
+    a pipeline pass and a link — after it reached its switch)."""
+    if not cfg.hedge_timer:
+        return state
+    wheel = state.wheel
+    n_slots, width, _ = wheel.data.shape
+    is_hedge = id_mask(params.policy_id, registry.hedge_timer_ids())
+    armed_at = jnp.round(resp.tarr / cfg.dt_us).astype(jnp.int32)
+    slot = (armed_at + params.hedge_delay_ticks) % n_slots   # (K,)
+    col = jnp.arange(width)
+    hit = ((resp.active & is_hedge)[:, None]
+           & (col[None, :] < wheel.count[slot][:, None])
+           & (wheel.data[slot, :, WHEEL_RID]
+              == resp.rid.astype(jnp.float32)[:, None]))    # (K, width)
+    cancelled = wheel.cancelled.at[jnp.where(hit, slot[:, None], n_slots),
+                                   col[None, :]].set(True, mode="drop")
+    return state._replace(wheel=wheel._replace(cancelled=cancelled))
 
 
 def stage_server(cfg: FleetConfig, params, state: FleetState,
@@ -797,8 +912,7 @@ def stage_server(cfg: FleetConfig, params, state: FleetState,
 def stage_response_filter(cfg: FleetConfig, params, state: FleetState,
                           arr: Arrivals, resp: Responses):
     """Switch response path: per-rack StateT update + the fingerprint
-    filter at each pair's filter switch (one call for the whole fabric),
-    plus the coordinator's response-side bookkeeping."""
+    filter at each pair's filter switch (one call for the whole fabric)."""
     RK, S = cfg.n_racks, cfg.n_servers
     T = cfg.n_filter_tables
     m = state.metrics
@@ -822,20 +936,6 @@ def stage_response_filter(cfg: FleetConfig, params, state: FleetState,
             kind=EV_FILTER_DROP, rid=resp.rid, server=resp.sid,
             client=resp.client, arg=resp.frack))  # arg: filter switch
 
-    if cfg.coordinator:
-        # every response of a coordinator policy passes back through the
-        # coordinator CPU: it costs a credit and frees an outstanding slot
-        # (the idleness signal the next tick's drain reads)
-        coord = state.coord
-        is_coord = id_mask(params.policy_id, registry.coordinator_ids())
-        dec = resp.active & is_coord
-        ST = RK * S
-        outstanding = coord.outstanding.at[
-            jnp.where(dec, resp.sid, ST)].add(-1, mode="drop")
-        credit = coord.credit - dec.sum().astype(jnp.float32)
-        state = state._replace(coord=coord._replace(
-            outstanding=outstanding,
-            credit=jnp.maximum(credit, -jnp.float32(cfg.drain_per_tick))))
     return state, drop
 
 
@@ -866,14 +966,20 @@ def stage_client(cfg: FleetConfig, params, state: FleetState,
     wait = backlog_pre[resp.client] + (pos + 1) * cfg.client_rx_us
     backlog = backlog_pre + cli_onehot.sum(axis=1) * cfg.client_rx_us
     t_fin = arr.t_us + wait
-    if cfg.coordinator:
-        # coordinator responses serialize through its CPU before reaching
-        # the client (same rank model as the receiver threads)
-        is_coord = id_mask(params.policy_id, registry.coordinator_ids())
-        crank = _rank(deliver)
-        t_fin = t_fin + jnp.where(is_coord & deliver,
-                                  (crank + 1.0) * cfg.coord_cpu_us, 0.0)
     lat = t_fin - resp.tarr + const_lat + resp.hop
+    if cfg.coordinator:
+        # a coordinator's response leaves its CPU at t_coord and reaches
+        # the client a link later; the CPU spaces its responses by
+        # coord_cpu_us ≥ client_rx_us, so none waits for the receiver.
+        # The request reached the coordinator at tarr, a client TX, two
+        # links and a pipeline pass after it was sent
+        is_coord = id_mask(params.policy_id, registry.coordinator_ids())
+        t_coord = resp.t_coord + cfg.link_us + cfg.client_rx_us
+        t_fin = jnp.where(is_coord, t_coord, t_fin)
+        lat = jnp.where(is_coord, t_coord - resp.tarr
+                        + (cfg.client_tx_us + 2 * cfg.link_us
+                           + cfg.pipeline_pass_us + cfg.spine_extra_us),
+                        lat)
     rec = first & (t_fin >= t0_us) & (t_fin <= t1_us)
     bins = jnp.clip((jnp.log(jnp.maximum(lat, cfg.hist_lo_us)
                              / cfg.hist_lo_us) / log_g),
@@ -969,14 +1075,6 @@ def build_step(cfg: FleetConfig, params, group_pairs: jax.Array):
                  + jnp.where(id_mask(params.policy_id,
                                      registry.client_dup_ids()),
                              cfg.client_tx_us, 0.0))
-    if cfg.coordinator:
-        # coordinator policies detour switch → coordinator → switch: one
-        # extra link hop each way plus the request-processing CPU pass
-        # (the dispatch and response CPU passes are charged by the rank
-        # model inside the stages, where their serialization is visible)
-        const_lat = const_lat + jnp.where(
-            id_mask(params.policy_id, registry.coordinator_ids()),
-            2.0 * cfg.link_us + cfg.coord_cpu_us, 0.0)
     xhop = jnp.float32(cfg.interrack_extra_us)
 
     def step(state: FleetState, xs):
@@ -1004,6 +1102,11 @@ def build_step(cfg: FleetConfig, params, group_pairs: jax.Array):
         with jax.named_scope("tick.filter"):
             state, drop = stage_response_filter(cfg, params, state, arr,
                                                 resp)
+        with jax.named_scope("tick.coordinator"):
+            state, resp = stage_coordinator_responses(cfg, params, state,
+                                                      arr, resp)
+        with jax.named_scope("tick.hedge_timer"):
+            state = stage_hedge_cancel(cfg, params, state, arr, resp)
         with jax.named_scope("tick.client"):
             state = stage_client(cfg, params, state, arr, resp, drop,
                                  const_lat)

@@ -113,25 +113,33 @@ class Workers(NamedTuple):
 
 
 class CoordState(NamedTuple):
-    """Array-form coordinator node (LÆDGE, §2.2) — a CPU queue hanging off
-    the top switch.
+    """Array-form coordinator node (LÆDGE, §2.2) — one CPU hanging off the
+    top switch, as the DES models it.
 
-    Pending requests wait in a ring buffer of ``QF``-format rows; each tick
-    the drain pops up to ``FleetConfig.drain_per_tick`` of them onto servers
-    chosen by the policy's registered ``coordinator`` rule, spending one
-    CPU *credit* per transmitted copy (credits accrue at
-    ``dt / coord_cpu_us`` per tick, go negative when responses flood the
-    CPU, and gate dispatch — reproducing the DES coordinator's serialized
-    CPU bottleneck).  ``outstanding`` is the coordinator's own
-    dispatched-minus-responded view per server, the idleness signal of the
-    LÆDGE rule (idle ⇔ outstanding < n_workers).
+    The CPU serves packets FCFS at ``coord_cpu_us`` each: every request it
+    receives, every duplicate it sends, every waiting request it later
+    sends, and every response.  ``cpu_free`` is the time its queued work
+    ends (µs, unbounded: a CPU that receives more than it can serve falls
+    further behind every tick).  Decisions are taken in arrival order, as
+    the DES takes them; only the packets wait for the CPU.  A dispatched
+    copy therefore sits in the ``out_*`` ring (FIFO by the time it reaches
+    its server, held in its ``QF_HOP`` field) until that tick, and holds
+    its server's ``outstanding`` slot all the while.  ``outstanding`` is
+    the coordinator's own dispatched-minus-responded view per server,
+    the idleness signal of the LÆDGE rule (idle ⇔ outstanding <
+    n_workers); requests that find no idle server wait in the ``head`` /
+    ``count`` / ``data`` ring until a response frees one.
     """
 
     outstanding: jax.Array  # (n_racks · S,) int32
-    head: jax.Array         # () int32 — oldest occupied ring slot
-    count: jax.Array        # () int32 — pending requests
+    head: jax.Array         # () int32 — oldest waiting request's slot
+    count: jax.Array        # () int32 — waiting requests
     data: jax.Array         # (coordinator_cap, QF) float32 payload rows
-    credit: jax.Array       # () float32 — CPU packet budget
+    cpu_free: jax.Array     # () float32 — µs at which the CPU is idle
+    out_head: jax.Array     # () int32 — oldest copy on its way
+    out_count: jax.Array    # () int32 — copies on their way
+    out_dst: jax.Array      # (n_racks · S · W,) int32 destination server
+    out_data: jax.Array     # (n_racks · S · W, QF) float32; QF_HOP: arrival
 
 
 class HedgeWheel(NamedTuple):
@@ -142,11 +150,14 @@ class HedgeWheel(NamedTuple):
     ``delay`` ticks later, because the wheel is deeper than the delay
     horizon (enforced by ``FleetConfig``).  Per-slot occupancy beyond
     ``wheel_width`` drops the *latest* lanes deterministically (counted in
-    ``Metrics.n_wheel_dropped``).
+    ``Metrics.n_wheel_dropped``).  ``cancelled`` marks the entries whose
+    original's response passed its switch before the timer fired — the
+    DES's cancel-on-first-response, keyed by REQ_ID.
     """
 
-    count: jax.Array    # (n_slots,) int32 — armed entries per slot
-    data: jax.Array     # (n_slots, width, WH) float32 entries
+    count: jax.Array      # (n_slots,) int32 — armed entries per slot
+    data: jax.Array       # (n_slots, width, WH) float32 entries
+    cancelled: jax.Array  # (n_slots, width) bool
 
 
 class Metrics(NamedTuple):
@@ -172,8 +183,11 @@ class Metrics(NamedTuple):
     lost_down_resp: jax.Array   # responses lost while the fabric was dark
     # staged-pipeline counters (always present; only the coordinator /
     # hedge_timer stages ever move them off zero)
-    n_coord_queued: jax.Array   # requests parked at the coordinator node
-    n_coord_overflow: jax.Array  # … lost to coordinator-ring exhaustion
+    n_coord_queued: jax.Array   # requests accepted by the coordinator node
+    n_coord_overflow: jax.Array  # … lost to waiting-ring exhaustion
+    n_coord_pending: jax.Array  # … that waited there for an idle server
+    n_coord_packets: jax.Array  # CPU passes (× coord_cpu_us = busy time)
+    n_coord_resp_waited: jax.Array  # responses that found the CPU busy
     n_hedges_armed: jax.Array   # timer-wheel entries armed
     # … cancelled by an earlier response, or lost with a dark fabric (the
     # DES likewise silently drops a hedge firing into a down switch)
@@ -230,18 +244,27 @@ def init_metrics(cfg: FleetConfig) -> Metrics:
                    n_completed_win=z, n_resp=z, n_resp_empty=z,
                    lost_down_resp=z,
                    n_coord_queued=z, n_coord_overflow=z,
-                   n_hedges_armed=z, n_hedges_cancelled=z, n_wheel_dropped=z,
+                   n_coord_pending=z, n_coord_packets=z,
+                   n_coord_resp_waited=z,
+                   n_hedges_armed=z,
+                   n_hedges_cancelled=z, n_wheel_dropped=z,
                    n_slot_busy=z,
                    n_link_dropped_req=z, n_link_dropped_resp=z)
 
 
 def init_coord_state(cfg: FleetConfig) -> CoordState:
+    # a copy on its way holds an outstanding slot of its server, and no
+    # server takes more than n_workers: that many copies fill the ring
+    out = cfg.n_servers_total * cfg.n_workers
+    z = jnp.zeros((), jnp.int32)
     return CoordState(
         outstanding=jnp.zeros((cfg.n_servers_total,), jnp.int32),
-        head=jnp.zeros((), jnp.int32),
-        count=jnp.zeros((), jnp.int32),
+        head=z, count=z,
         data=jnp.zeros((cfg.coordinator_cap, QF), jnp.float32),
-        credit=jnp.zeros((), jnp.float32),
+        cpu_free=jnp.zeros((), jnp.float32),
+        out_head=z, out_count=z,
+        out_dst=jnp.zeros((out,), jnp.int32),
+        out_data=jnp.zeros((out, QF), jnp.float32),
     )
 
 
@@ -249,6 +272,7 @@ def init_hedge_wheel(cfg: FleetConfig) -> HedgeWheel:
     return HedgeWheel(
         count=jnp.zeros((cfg.wheel_slots,), jnp.int32),
         data=jnp.zeros((cfg.wheel_slots, cfg.wheel_width, WH), jnp.float32),
+        cancelled=jnp.zeros((cfg.wheel_slots, cfg.wheel_width), bool),
     )
 
 

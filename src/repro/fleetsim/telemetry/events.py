@@ -49,6 +49,7 @@ EV_SERVER_FINISH = 9    # worker completion             arg = queue depth left
 EV_FILTER_DROP = 10     # redundant copy filtered       arg = filter switch
 EV_CLIENT_COMPLETE = 11  # first response delivered     arg = latency (µs)
 EV_CLIENT_REDUNDANT = 12  # redundant absorbed at client arg = 0
+EV_COORD_RESP = 13      # response through coordinator  arg = CPU wait (µs)
 
 EVENT_NAMES = {
     EV_ARRIVAL: "arrival",
@@ -63,6 +64,7 @@ EVENT_NAMES = {
     EV_FILTER_DROP: "filter_drop",
     EV_CLIENT_COMPLETE: "client_complete",
     EV_CLIENT_REDUNDANT: "client_redundant",
+    EV_COORD_RESP: "coord_resp",
 }
 
 # EV_CLONE arg values — where the copy came from
@@ -80,6 +82,7 @@ EVENT_ARG = {
     EV_SERVER_FINISH: "queue_depth",
     EV_FILTER_DROP: "filter_switch",
     EV_CLIENT_COMPLETE: "latency_us",
+    EV_COORD_RESP: "cpu_wait_us",
 }
 
 # -------------------------------------------- windowed series counters -----
@@ -98,4 +101,7 @@ SERIES_COUNTERS = (
     "n_hedges_armed",
     "n_hedges_cancelled",
     "n_coord_queued",
+    "n_coord_pending",
+    "n_coord_packets",
+    "n_coord_resp_waited",
 )

@@ -45,6 +45,7 @@ from dataclasses import dataclass, fields
 import numpy as np
 
 from repro.core.simulator import Simulator
+from repro.core.switch import FILTER_SLOTS
 from repro.core.workloads import ServiceProcess
 from repro.fleetsim.config import FleetConfig, ServiceSpec
 from repro.fleetsim.llmserve.oracle import (  # noqa: F401  (re-export: the
@@ -93,19 +94,18 @@ COORD_PACKETS_PER_CLONE = 4.0
 # Coordinator-policy (LÆDGE) modelling notes feeding the tolerances above:
 # the coordinator CPU (≈1.5 µs per packet, 4 packets per cloned request)
 # saturates far below server capacity.  Once the *full-cloning* CPU demand
-# (rate × 4 × coord_cpu) crosses UTIL_CRITICAL the coordinator enters a
-# clone-throttling regime with no clean steady state: the DES oscillates
-# between cloning (idle servers visible) and not (its outstanding counts
-# are inflated by the CPU pipe's standing backlog), while FleetSim's
-# credit model degrades smoothly to single-copy dispatch — so such points
-# are classified *saturated* and, like every saturated point, checked only
-# for agreement on the collapse itself.  Past genuine collapse the
-# clone/filter fractions are run-length artifacts in both engines (the DES
-# drains its whole backlog after the arrival window; FleetSim counts a
-# fixed tick window), hence `clone_ok`/`filter_ok` are, like the latency
-# checks, only enforced on stationary points.  FleetSim-side collapse
-# shows up as goodput loss, server-queue overflow, or coordinator-ring
-# overflow (all three accepted as the collapse signature).
+# (rate × 4 × coord_cpu) crosses UTIL_CRITICAL both engines' CPUs fall
+# behind: copies and responses wait in its backlog, the outstanding counts
+# it dispatches on stay inflated, and throughput collapses with run length
+# — so such points are classified *saturated* and, like every saturated
+# point, checked only for agreement on the collapse itself.  Past genuine
+# collapse the clone/filter fractions are run-length artifacts in both
+# engines (the DES drains its whole backlog after the arrival window;
+# FleetSim counts a fixed tick window), hence `clone_ok`/`filter_ok` are,
+# like the latency checks, only enforced on stationary points.
+# FleetSim-side collapse shows up as goodput loss, server-queue overflow,
+# or coordinator-ring overflow (all three accepted as the collapse
+# signature).
 
 
 @dataclass
@@ -226,8 +226,10 @@ def cross_check_scenario(scenario, n_requests: int | None = None,
                          n_ticks: int | None = None) -> CrossCheck:
     """Cross-validate one :class:`repro.scenarios.Scenario` — the same
     frozen object drives both engines (comparison-by-construction), so this
-    covers trace-replay scenarios too."""
-    fr = scenario.run_fleetsim(**({"n_ticks": n_ticks} if n_ticks else {}))
+    covers trace-replay scenarios too.  The program runs at the DES's
+    filter-table size, so both see the same fingerprint collisions."""
+    fr = scenario.run_fleetsim(n_filter_slots=FILTER_SLOTS,
+                               **({"n_ticks": n_ticks} if n_ticks else {}))
     des = scenario.run_des(n_requests=n_requests, n_ticks=n_ticks)
     nt = n_ticks or scenario.n_ticks
     return _check_from(scenario.policy, scenario.effective_load(nt), des, fr)
@@ -240,7 +242,10 @@ def cross_validate_spec(spec, n_requests: int = 20_000,
     The whole Poisson grid runs through one vmapped device program; each
     cell's DES replay uses the *same scenario seed*, so the comparison is
     knob-for-knob.  ``n_ticks`` defaults to admitting ``n_requests`` at the
-    sweep's lowest load.
+    sweep's lowest load.  The program runs at the DES's filter-table size
+    (``FILTER_SLOTS``): a pair that stays open for long, as a hedge's does
+    for its delay and its straggler's run, is overwritten far more often in
+    FleetConfig's default 2^10-slot tables.
     """
     from repro.core.workloads import load_to_rate
 
@@ -265,7 +270,7 @@ def cross_validate_spec(spec, n_requests: int = 20_000,
                                     base.workers)
                        for ld in spec.resolved_loads())
         n_ticks = int(n_requests / min_rate) + 1
-    fleet = spec.run_fleetsim(n_ticks=n_ticks)
+    fleet = spec.run_fleetsim(n_ticks=n_ticks, n_filter_slots=FILTER_SLOTS)
     checks = []
     for sc in spec.scenarios():
         des = sc.run_des(n_requests=n_requests, n_ticks=n_ticks)
@@ -299,7 +304,7 @@ def cross_validate(
     if cfg is None:
         n_ticks = int(n_requests / min_rate / 1.0) + 1
         cfg = FleetConfig(n_servers=n_servers, n_workers=n_workers,
-                          n_ticks=n_ticks,
+                          n_ticks=n_ticks, n_filter_slots=FILTER_SLOTS,
                           service=ServiceSpec.from_process(service))
     if cfg.n_racks != 1:
         # the DES models one ToR; the fabric's n_racks == 1 path is

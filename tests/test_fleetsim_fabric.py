@@ -36,7 +36,6 @@ from repro.fleetsim import (
     simulate,
     summarize,
 )
-from repro.fleetsim.stages import fingerprint_parked
 from repro.fleetsim.sweep import sweep_grid
 
 SVC = ExponentialService(25.0)
@@ -243,26 +242,6 @@ def test_masked_lanes_write_nothing():
                             jnp.asarray(active))
     assert not np.asarray(drop).any()
     assert np.array_equal(np.asarray(new), np.asarray(carried))
-
-
-@pytest.mark.parametrize("racks", [1, 4])
-def test_hedge_parked_lookup_at_carried_index(racks):
-    """The hedge timer's cancel test reads the fingerprint at (group, table,
-    slot) of the carried tables, spine group included."""
-    cfg = FleetConfig(n_racks=racks, n_filter_slots=1024)
-    G, T, S = racks + 1, cfg.n_filter_tables, cfg.n_filter_slots
-    rng = np.random.default_rng(racks)
-    tables = rng.integers(1, 2 ** 20, (G, T, S))
-    rid, idx = _random_lanes(rng, cfg, 64)
-    frack, fidx = idx // T, idx % T
-    slot = np.asarray(fingerprint_hash_jax(jnp.asarray(rid, jnp.int32), S))
-    tables[frack[:32], fidx[:32], slot[:32]] = rid[:32]
-    want = tables[frack, fidx, slot] == rid
-    got = fingerprint_parked(cfg, _carried(cfg, tables), jnp.asarray(idx),
-                             jnp.asarray(rid))
-    assert want[:32].all() and not want.all()
-    assert (frack == racks).any()                        # the spine group
-    assert np.array_equal(np.asarray(got), want)
 
 
 def test_filter_tables_must_fit_int32_indices():

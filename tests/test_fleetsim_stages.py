@@ -10,7 +10,7 @@ Contracts of the stage refactor (PR 4):
 * the timer wheel never drops an armed hedge while its slot has room, and
   drops deterministically (latest lanes first) when it is full;
 * the coordinator implements LÆDGE's clone-iff-≥2-idle / queue-otherwise
-  rule and its CPU credit reproduces the coordinator-CPU bottleneck.
+  rule, and its one FCFS CPU collapses the rack where the DES's does.
 """
 
 import json
@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
+from repro.core.simulator import Simulator
 from repro.core.workloads import ExponentialService, load_to_rate
 from repro.fleetsim import (
     POLICY_IDS,
@@ -67,7 +68,13 @@ def test_stage_flags_resolve_and_validate():
     assert cfg.hedge_delay_ticks == 75
     assert cfg.wheel_slots == 76
     assert cfg.wheel_width == cfg.max_arrivals
-    assert cfg.drain_per_tick == 2 * cfg.max_arrivals
+    # one 1.5 µs CPU pass per copy: at most one copy leaves per 1 µs tick;
+    # a CPU that costs nothing sends all a tick can make
+    assert cfg.coord_sends_per_tick == 1
+    assert replace(cfg, coord_cpu_us=0.0).coord_sends_per_tick \
+        == 2 * cfg.max_arrivals + cfg.max_responses
+    with pytest.raises(ValueError, match="coord_cpu_us"):
+        small_cfg(coordinator=True, coord_cpu_us=-1.0)
     with pytest.raises(ValueError, match="delay horizon"):
         small_cfg(hedge_timer=True, hedge_wheel_slots=10)
     with pytest.raises(ValueError, match="coordinator_cap"):
@@ -216,19 +223,25 @@ def test_laedge_queues_everything_and_clones_when_idle():
 def test_laedge_coordinator_cpu_bottleneck():
     """The paper's §2.2 argument in one assertion: the coordinator CPU
     (not the servers) caps LÆDGE throughput, far below what the same
-    cluster serves under switch-based policies."""
+    cluster serves under switch-based policies.  Every request costs the
+    CPU a pass on receipt, so once receipts alone need more than the CPU
+    has, its backlog grows all run long, copies and responses wait behind
+    it, and the rack delivers almost nothing — as in the DES."""
     cfg = small_cfg(n_ticks=20_000)
     la = result("laedge", load=0.6, cfg=cfg)
     nc = result("netclone", load=0.6, cfg=cfg)
-    # netclone delivers the offered load; laedge collapses to ~1/coord_cpu
-    # per *pair of CPU passes* (≈0.33 req/µs for 1.5 µs per packet)
     assert nc.throughput_mrps > 0.9 * nc.offered_rate_mrps
-    assert la.throughput_mrps < 0.6 * la.offered_rate_mrps
-    assert la.throughput_mrps == pytest.approx(
-        1.0 / (2 * cfg.coord_cpu_us), rel=0.15)
-    # the backlog is visible: every arrival was parked or shed at the ring
+    assert la.offered_rate_mrps * cfg.coord_cpu_us > 1.0
+    des = Simulator("laedge", SVC, n_servers=S, n_workers=W, seed=0).run(
+        offered_load=0.6, n_requests=13_500)
+    assert des.throughput_mrps < 0.1 * des.offered_rate_mrps
+    assert la.throughput_mrps < 0.1 * la.offered_rate_mrps
+    assert la.throughput_mrps == pytest.approx(des.throughput_mrps, rel=0.3)
+    # the backlog is visible: every arrival was accepted or shed at the
+    # ring, and the CPU was busy the whole run
     assert la.n_coord_queued + la.n_coord_overflow == la.n_arrivals
     assert la.n_coord_overflow > 0 or la.n_coord_queued > la.n_completed
+    assert la.n_coord_packets * cfg.coord_cpu_us > cfg.duration_us
 
 
 def test_laedge_multirack_runs_and_filters_at_top_tier():

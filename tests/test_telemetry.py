@@ -44,7 +44,12 @@ from repro.fleetsim.telemetry.events import (
     EV_ARRIVAL,
     EV_CLIENT_COMPLETE,
     EV_CLONE,
+    EV_COORD_DISPATCH,
+    EV_COORD_ENQ,
+    EV_COORD_RESP,
     EV_FILTER_DROP,
+    EV_HEDGE_ARMED,
+    EV_HEDGE_CANCELLED,
     EV_SERVER_FINISH,
 )
 from repro.fleetsim.telemetry.export import PID_CLONES, PID_REQUESTS
@@ -111,6 +116,49 @@ def test_event_counts_reconcile_with_run_counters():
     for kind, counter in want.items():
         assert len(ev.select(kind)) == int(counter), kind
     assert int(m.n_cloned) > 0 and int(m.n_filtered) > 0  # non-vacuous
+
+
+@pytest.mark.parametrize("policy,load", [("laedge", 0.1), ("laedge", 0.5),
+                                         ("hedge", 0.6)])
+def test_stage_event_counts_reconcile_with_run_counters(policy, load):
+    """The coordinator's and the hedge timer's events reconcile with their
+    counters: requests accepted, sent and waiting, the CPU's passes
+    (received + duplicates + waiting requests sent + responses), and
+    hedges armed, fired and cancelled."""
+    cfg, m, trace, series = run_tel(policy, load=load)
+    ev = decode_run(cfg, trace, series).events
+    assert ev.n_lost == 0
+    n = {k: len(ev.select(k)) for k in (
+        EV_CLONE, EV_COORD_ENQ, EV_COORD_DISPATCH, EV_COORD_RESP,
+        EV_HEDGE_ARMED, EV_HEDGE_CANCELLED, EV_FILTER_DROP)}
+    assert n[EV_CLONE] == int(m.n_cloned) > 0
+    assert n[EV_FILTER_DROP] == int(m.n_filtered)
+    if policy == "laedge":
+        queued, pending = int(m.n_coord_queued), int(m.n_coord_pending)
+        assert n[EV_COORD_ENQ] == queued
+        sent_waiting = n[EV_COORD_DISPATCH] - (queued - pending)
+        assert 0 <= sent_waiting <= pending
+        assert int(m.n_coord_packets) == (
+            queued + int(m.n_coord_overflow) + int(m.n_cloned)
+            + sent_waiting + n[EV_COORD_RESP])
+        waits = ev.arg[ev.kind == EV_COORD_RESP]
+        assert (waits >= 0).all()
+        assert int(m.n_coord_resp_waited) >= (waits > 0).sum() > 0
+    else:
+        assert n[EV_HEDGE_ARMED] == int(m.n_hedges_armed)
+        assert n[EV_HEDGE_CANCELLED] == int(m.n_hedges_cancelled)
+        assert n[EV_COORD_RESP] == n[EV_COORD_ENQ] == 0
+
+
+@pytest.mark.parametrize("policy", ["laedge", "hedge"])
+def test_stage_series_decompose_counters_exactly(policy):
+    cfg, m, trace, series = run_tel(policy, load=0.3, window_ticks=500)
+    ts = decode_run(cfg, trace, series).series
+    for f in SERIES_COUNTERS:
+        assert int(ts.rates[f].sum()) == int(getattr(m, f)), f
+    moved = ("n_coord_packets", "n_coord_pending") if policy == "laedge" \
+        else ("n_hedges_armed", "n_hedges_cancelled")
+    assert all(int(ts.rates[f].sum()) > 0 for f in moved)
 
 
 def test_ring_wrap_flight_recorder():

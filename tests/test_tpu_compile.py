@@ -204,3 +204,27 @@ def test_tick_keeps_the_filter_tables_in_place(one_chip, racks):
     assert ops_["relayout"] == []
     assert sorted(o.split()[-1] for o in ops_["compute"]) == [
         "scatter", "select"]
+
+
+def test_staged_tick_keeps_the_filter_tables_in_place(one_chip):
+    """The staged sweep program of the seven-policy testbed grid (the
+    coordinator and hedge-timer stages compiled in, 7 policies x 8 loads)
+    takes no relayout of the filter tables inside
+    the tick either: the hedge timer cancels by REQ_ID in its own wheel
+    and reads no table, so the only ops over them stay the recovery
+    wipe's select and the filter's scatter."""
+    cfg = FleetConfig(n_servers=6, n_workers=15, n_clients=2,
+                      n_filter_tables=2, n_filter_slots=2 ** 17,
+                      n_ticks=1024).with_policy_stages(["laedge", "hedge"])
+    assert cfg.coordinator and cfg.hedge_timer
+    one = make_params(cfg, 0, 0.5, 0)
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, (GRID,) + a.shape, a.dtype), one)
+    assert EngineOptions().resolve_backend(cfg) == "staged"
+    text = lower(cfg, params).compile().as_text()
+    ops_ = tick_table_ops(text, GRID * cfg.filter_table_size)
+    print(f"staged: {len(ops_['relayout'])} table-sized relayouts in the "
+          f"tick body; ops {ops_}")
+    assert ops_["relayout"] == []
+    assert sorted(o.split()[-1] for o in ops_["compute"]) == [
+        "scatter", "select"]
