@@ -49,7 +49,7 @@ import numpy as np
 
 from repro.core.switch_jax import group_pairs_array
 from repro.fleetsim.config import FleetConfig
-from repro.fleetsim.stages import build_step
+from repro.fleetsim.stages import build_step, with_recovery_wipe
 from repro.fleetsim.state import FleetState, Metrics, init_fleet_state
 from repro.fleetsim.telemetry.device import SeriesState, TraceBuffer
 from repro.scenarios import registry
@@ -194,8 +194,24 @@ def make_params(cfg: FleetConfig, policy_id: int, rate_per_us: float,
                      link_mask=jnp.asarray(link_mask, bool))
 
 
+def needs_recovery_wipe(cfg: FleetConfig, params: RunParams) -> bool:
+    """Whether the program needs the §3.6 recovery wipe: some run's switch
+    recovers (``tick == fail_until_tick``) on a tick of the horizon,
+    ``0 … n_ticks − 1``.  Without a window (``make_params`` and
+    ``sweep_grid`` put it at ``n_ticks + 1``), or with one that recovers
+    past the horizon, the wipe is an identity and the program leaves it
+    out.  Abstract params (a caller's outer ``jit``, ``ShapeDtypeStruct``
+    inputs) cannot tell, and keep it."""
+    until = params.fail_until_tick
+    if isinstance(until, (jax.core.Tracer, jax.ShapeDtypeStruct)):
+        return True
+    until = np.asarray(until)
+    return bool(np.any((until >= 0) & (until < cfg.n_ticks)))
+
+
 # ------------------------------------------------------------------ runner --
-def _simulate_core(cfg: FleetConfig, params: RunParams) -> FleetState:
+def _simulate_core(cfg: FleetConfig, params: RunParams,
+                   recovery_wipe: bool = True) -> FleetState:
     with jax.named_scope("fleetsim.init"):
         gp = group_pairs_array(cfg.n_servers)
         k_pois, k0 = jax.random.split(jax.random.PRNGKey(params.seed))
@@ -203,6 +219,8 @@ def _simulate_core(cfg: FleetConfig, params: RunParams) -> FleetState:
         # the tick's per-run constants; the tick itself is traced inside
         # the scan, under its own stage scopes
         step = build_step(cfg, params, gp)
+        if recovery_wipe:
+            step = with_recovery_wipe(cfg, params, step)
     with jax.named_scope("fleetsim.draw"):
         ticks = jnp.arange(cfg.n_ticks, dtype=jnp.int32)
         if cfg.arrival == "trace":
@@ -217,9 +235,10 @@ def _simulate_core(cfg: FleetConfig, params: RunParams) -> FleetState:
     return state
 
 
-def _core_telemetry(cfg: FleetConfig, params: RunParams
+def _core_telemetry(cfg: FleetConfig, params: RunParams,
+                    recovery_wipe: bool = True
                     ) -> tuple[Metrics, TraceBuffer, SeriesState]:
-    state = _simulate_core(cfg, params)
+    state = _simulate_core(cfg, params, recovery_wipe)
     return state.metrics, state.trace, state.series
 
 
@@ -253,31 +272,34 @@ class PinnedPrng:
 # bake in the registry's branch tables, so each entry is additionally
 # keyed on registry.version(): registering a policy after a compile forces
 # a retrace with the grown lax.switch table instead of silently reusing a
-# stale executable.
+# stale executable.  The static recovery_wipe is needs_recovery_wipe of
+# the concrete params the caller passes.
 @functools.lru_cache(maxsize=None)
 def _entry(backend: str, batch: bool, telemetry: bool, donate: bool,
            ticks_per_chunk: int):
     if backend == "fused":
         from repro.fleetsim.fused import fused_core
 
-        def core(cfg, p):
-            return fused_core(cfg, p, ticks_per_chunk).metrics
+        def core(cfg, p, wipe):
+            return fused_core(cfg, p, ticks_per_chunk, wipe).metrics
     elif telemetry:
         # FleetScope: the trace ring + series accumulators ride out of the
         # program alongside the metrics.  A separate entry, so a
         # metrics-only caller never pays the telemetry transfer.
         core = _core_telemetry
     else:
-        def core(cfg, p):
-            return _simulate_core(cfg, p).metrics
+        def core(cfg, p, wipe):
+            return _simulate_core(cfg, p, wipe).metrics
 
-    def run(cfg: FleetConfig, registry_version: int, params: RunParams):
+    def run(cfg: FleetConfig, registry_version: int, recovery_wipe: bool,
+            params: RunParams):
         if batch:
-            return jax.vmap(lambda p: core(cfg, p))(params)
-        return core(cfg, params)
+            return jax.vmap(lambda p: core(cfg, p, recovery_wipe))(params)
+        return core(cfg, params, recovery_wipe)
 
-    return PinnedPrng(jax.jit(run, static_argnames=("cfg", "registry_version"),
-                              donate_argnums=(2,) if donate else ()))
+    return PinnedPrng(jax.jit(
+        run, static_argnames=("cfg", "registry_version", "recovery_wipe"),
+        donate_argnums=(3,) if donate else ()))
 
 
 def _check_telemetry(cfg: FleetConfig) -> None:
@@ -355,7 +377,8 @@ def simulate(cfg: FleetConfig, params: RunParams, *, options=None):
         return run_sharded(cfg, params, opts.shard, backend=backend,
                            ticks_per_chunk=k)
     entry = _entry(backend, batched, opts.telemetry, opts.donate, k)
-    return entry(cfg, registry.version(), params)
+    return entry(cfg, registry.version(), needs_recovery_wipe(cfg, params),
+                 params)
 
 
 def lower(cfg: FleetConfig, params: RunParams, *, options=None):
@@ -370,26 +393,27 @@ def lower(cfg: FleetConfig, params: RunParams, *, options=None):
                          "use repro.fleetsim.shard.lower_sharded")
     entry = _entry(backend, _is_batched(params), opts.telemetry,
                    opts.donate, k)
-    return entry.lower(cfg, registry.version(), params)
+    return entry.lower(cfg, registry.version(),
+                       needs_recovery_wipe(cfg, params), params)
 
 
 def lower_run(cfg: FleetConfig, params: RunParams):
     """``jit(...).lower`` for a single staged run (scenario runners)."""
     return _entry("staged", False, False, False, 0).lower(
-        cfg, registry.version(), params)
+        cfg, registry.version(), needs_recovery_wipe(cfg, params), params)
 
 
 def lower_batch(cfg: FleetConfig, params: RunParams):
     """``jit(...).lower`` for the staged batch runner."""
     return _entry("staged", True, False, False, 0).lower(
-        cfg, registry.version(), params)
+        cfg, registry.version(), needs_recovery_wipe(cfg, params), params)
 
 
 def lower_batch_telemetry(cfg: FleetConfig, params: RunParams):
     """``jit(...).lower`` for the staged telemetry batch runner."""
     _check_telemetry(cfg)
     return _entry("staged", True, True, False, 0).lower(
-        cfg, registry.version(), params)
+        cfg, registry.version(), needs_recovery_wipe(cfg, params), params)
 
 
 # ------------------------------------------------------- deprecated shims --
@@ -413,7 +437,7 @@ def simulate_batch(cfg: FleetConfig, params: RunParams) -> Metrics:
                      "simulate(cfg, params) — the leading sweep axis "
                      "selects the batched program")
     return _entry("staged", True, False, False, 0)(
-        cfg, registry.version(), params)
+        cfg, registry.version(), needs_recovery_wipe(cfg, params), params)
 
 
 def simulate_telemetry(cfg: FleetConfig, params: RunParams
@@ -425,7 +449,7 @@ def simulate_telemetry(cfg: FleetConfig, params: RunParams
                      "EngineOptions(telemetry=True))")
     _check_telemetry(cfg)
     return _entry("staged", False, True, False, 0)(
-        cfg, registry.version(), params)
+        cfg, registry.version(), needs_recovery_wipe(cfg, params), params)
 
 
 def simulate_batch_telemetry(cfg: FleetConfig, params: RunParams
@@ -438,4 +462,4 @@ def simulate_batch_telemetry(cfg: FleetConfig, params: RunParams
                      "axis selects the batched program")
     _check_telemetry(cfg)
     return _entry("staged", True, True, False, 0)(
-        cfg, registry.version(), params)
+        cfg, registry.version(), needs_recovery_wipe(cfg, params), params)

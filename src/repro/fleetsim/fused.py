@@ -40,7 +40,7 @@ import jax.numpy as jnp
 
 from repro.core.switch_jax import group_pairs_array
 from repro.fleetsim.config import FleetConfig
-from repro.fleetsim.stages import build_step
+from repro.fleetsim.stages import build_step, with_recovery_wipe
 from repro.fleetsim.state import FleetState, init_fleet_state
 
 #: default K — ticks advanced per outer scan step (0/auto in EngineOptions)
@@ -108,8 +108,8 @@ def resolve_chunk(cfg: FleetConfig, ticks_per_chunk: int = 0) -> int:
     return max(1, min(k, cfg.n_ticks))
 
 
-def fused_core(cfg: FleetConfig, params,
-               ticks_per_chunk: int = 0) -> FleetState:
+def fused_core(cfg: FleetConfig, params, ticks_per_chunk: int = 0,
+               recovery_wipe: bool = True) -> FleetState:
     """Advance one fabric for ``cfg.n_ticks`` ticks on the fused backend.
 
     Chunks of ``K`` ticks ride an outer ``lax.scan`` whose carry is the
@@ -117,6 +117,8 @@ def fused_core(cfg: FleetConfig, params,
     ``K`` times (an inner scan over :func:`stages.build_step`), and
     repacks.  A remainder ``n_ticks mod K`` runs as a staged tail — so any
     K yields bit-identical results, K only moves the pack points.
+    ``recovery_wipe`` opens each tick with the §3.6 wipe, as in the staged
+    engine (``engine.needs_recovery_wipe``).
     """
     if cfg.coordinator or cfg.hedge_timer or cfg.telemetry:
         raise ValueError(
@@ -129,6 +131,8 @@ def fused_core(cfg: FleetConfig, params,
         k_pois, k0 = jax.random.split(jax.random.PRNGKey(params.seed))
         state = init_fleet_state(cfg, k0)
         step = build_step(cfg, params, gp)
+        if recovery_wipe:
+            step = with_recovery_wipe(cfg, params, step)
     with jax.named_scope("fleetsim.draw"):
         ticks = jnp.arange(cfg.n_ticks, dtype=jnp.int32)
         if cfg.arrival == "trace":

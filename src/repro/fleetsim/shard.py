@@ -54,6 +54,7 @@ from repro.fleetsim.engine import (
     RunParams,
     _entry,
     _simulate_core,
+    needs_recovery_wipe,
 )
 from repro.fleetsim.state import Metrics
 from repro.scenarios import registry
@@ -192,19 +193,21 @@ def plan_grid(params: RunParams, spec: ShardSpec) -> GridPlan:
 # Like engine._entry's programs, the cache is keyed on registry.version()
 # (post-compile policy registrations must retrace the grown switch tables)
 # and additionally on the mesh + backend, so layout and backend changes
-# each get their own executable.
+# each get their own executable, and on whether the §3.6 recovery wipe is
+# compiled in (engine.needs_recovery_wipe of the padded grid).
 def _simulate_sharded(cfg: FleetConfig, registry_version: int,
                       mesh: Mesh, params: RunParams, mask: jax.Array,
-                      backend: str = "staged", ticks_per_chunk: int = 0):
+                      backend: str = "staged", ticks_per_chunk: int = 0,
+                      recovery_wipe: bool = True):
     axis = mesh.axis_names[0]
     if backend == "fused":
         from repro.fleetsim.fused import fused_core
 
         def core(q):
-            return fused_core(cfg, q, ticks_per_chunk).metrics
+            return fused_core(cfg, q, ticks_per_chunk, recovery_wipe).metrics
     else:
         def core(q):
-            return _simulate_core(cfg, q).metrics
+            return _simulate_core(cfg, q, recovery_wipe).metrics
 
     def slab(p: RunParams, m: jax.Array):
         # each device advances its contiguous slab with the per-config
@@ -228,7 +231,8 @@ def _simulate_sharded(cfg: FleetConfig, registry_version: int,
 
 _simulate_sharded_jit = PinnedPrng(jax.jit(
     _simulate_sharded, static_argnames=("cfg", "registry_version", "mesh",
-                                        "backend", "ticks_per_chunk")))
+                                        "backend", "ticks_per_chunk",
+                                        "recovery_wipe")))
 
 
 def lower_sharded(cfg: FleetConfig, plan: GridPlan,
@@ -238,7 +242,9 @@ def lower_sharded(cfg: FleetConfig, plan: GridPlan,
     return _simulate_sharded_jit.lower(cfg, registry.version(), plan.mesh,
                                        plan.params, plan.mask,
                                        backend=backend,
-                                       ticks_per_chunk=ticks_per_chunk)
+                                       ticks_per_chunk=ticks_per_chunk,
+                                       recovery_wipe=needs_recovery_wipe(
+                                           cfg, plan.params))
 
 
 def _strip_pad(plan: GridPlan, metrics: Metrics) -> Metrics:
@@ -266,7 +272,9 @@ def run_sharded(cfg: FleetConfig, params: RunParams, spec: ShardSpec, *,
     met, grid_hist = _simulate_sharded_jit(cfg, registry.version(),
                                            plan.mesh, plan.params, plan.mask,
                                            backend=backend,
-                                           ticks_per_chunk=ticks_per_chunk)
+                                           ticks_per_chunk=ticks_per_chunk,
+                                           recovery_wipe=needs_recovery_wipe(
+                                               cfg, plan.params))
     return ShardedMetrics(metrics=_strip_pad(plan, met), grid_hist=grid_hist)
 
 
@@ -295,6 +303,6 @@ def simulate_batch_sharded(cfg: FleetConfig, params: RunParams,
             "unsharded, or drop cfg.telemetry for the sharded sweep")
     if spec is None:
         met = _entry("staged", True, False, False, 0)(
-            cfg, registry.version(), params)
+            cfg, registry.version(), needs_recovery_wipe(cfg, params), params)
         return ShardedMetrics(metrics=met, grid_hist=met.hist.sum(axis=0))
     return run_sharded(cfg, params, spec)

@@ -250,11 +250,36 @@ class Responses(NamedTuple):
 
 
 # ------------------------------------------------------------------- stages --
+def stage_recovery_wipe(cfg: FleetConfig, params, state: FleetState,
+                        tick) -> FleetState:
+    """§3.6 recovery: all switch soft state lost, REQ_IDs restart from 1;
+    the clients' pending-request fingerprints of lost requests go with it.
+
+    Runs first in the tick (:func:`with_recovery_wipe`), and only in a
+    program whose runs can recover inside the horizon
+    (``engine.needs_recovery_wipe``): elsewhere ``tick ==
+    params.fail_until_tick`` never holds and each select is an identity."""
+    recover = tick == params.fail_until_tick
+    switch = jax.tree.map(
+        lambda b: jnp.where(recover, jnp.zeros_like(b), b), state.switch)
+    dedup = jnp.where(recover, jnp.zeros_like(state.dedup), state.dedup)
+    wheel = state.wheel
+    if cfg.hedge_timer:
+        # pending hedge timers are switch soft state too (the DES wipes the
+        # policy's outstanding map on failure)
+        wheel = jax.tree.map(
+            lambda b: jnp.where(recover, jnp.zeros_like(b), b), wheel)
+    # the coordinator node is NOT wiped: it is a server-side CPU box, not
+    # switch soft state (matching the DES, whose coordinator queue and
+    # outstanding counts survive a switch failure)
+    return state._replace(switch=switch, dedup=dedup, wheel=wheel)
+
+
 def stage_arrival(cfg: FleetConfig, params, state: FleetState, xs):
-    """Admission + attribute draws: recovery wipe, Poisson/trace lane
-    masking, and the one uniform block covering every per-lane attribute
-    (the ``n_racks == 1`` column layout matches the single-ToR engine draw
-    for draw)."""
+    """Admission + attribute draws: Poisson/trace lane masking while the
+    fabric is down, and the one uniform block covering every per-lane
+    attribute (the ``n_racks == 1`` column layout matches the single-ToR
+    engine draw for draw)."""
     RK, S, C = cfg.n_racks, cfg.n_servers, cfg.n_clients
     ST = RK * S
     T = cfg.n_filter_tables
@@ -266,22 +291,6 @@ def stage_arrival(cfg: FleetConfig, params, state: FleetState, xs):
     t_us = tick.astype(jnp.float32) * dt
     down = (tick >= params.fail_from_tick) & (tick < params.fail_until_tick)
     switch = state.switch
-    dedup = state.dedup
-    # §3.6 recovery: all soft state lost, REQ_IDs restart from 1; the
-    # clients' pending-request fingerprints of lost requests go with it
-    recover = tick == params.fail_until_tick
-    switch = jax.tree.map(
-        lambda b: jnp.where(recover, jnp.zeros_like(b), b), switch)
-    dedup = jnp.where(recover, jnp.zeros_like(dedup), dedup)
-    wheel = state.wheel
-    if cfg.hedge_timer:
-        # pending hedge timers are switch soft state too (the DES wipes the
-        # policy's outstanding map on failure)
-        wheel = jax.tree.map(
-            lambda b: jnp.where(recover, jnp.zeros_like(b), b), wheel)
-    # the coordinator node is NOT wiped: it is a server-side CPU box, not
-    # switch soft state (matching the DES, whose coordinator queue and
-    # outstanding counts survive a switch failure)
     # flat view of the rack-major StateT, so every per-server op is the
     # one of the single-ToR engine (the filter tables keep their layout)
     sstate = switch.server_state.reshape(ST)
@@ -321,8 +330,7 @@ def stage_arrival(cfg: FleetConfig, params, state: FleetState, xs):
     else:
         home = jnp.zeros(A, jnp.int32)
     off = home * S               # local → fabric-global server ids
-    state = state._replace(switch=switch, dedup=dedup, key=key,
-                           metrics=m, wheel=wheel)
+    state = state._replace(key=key, metrics=m)
     return state, Arrivals(
         tick=tick, t_us=t_us, down=down, k_exec=k_exec, k_stage=k_stage,
         sstate=sstate, tables=switch.filter_tables, active=arr_active,
@@ -1118,3 +1126,17 @@ def build_step(cfg: FleetConfig, params, group_pairs: jax.Array):
         return state, None
 
     return step
+
+
+def with_recovery_wipe(cfg: FleetConfig, params, step):
+    """``step`` opened by :func:`stage_recovery_wipe`, under
+    ``tick.arrival/tick.wipe``: the tick of a program in which some run's
+    switch recovers inside the horizon.  The engines wrap
+    :func:`build_step` in it only then; a window-free program carries no
+    select over the switch tables."""
+    def wiped(state: FleetState, xs):
+        with jax.named_scope("tick.arrival"), jax.named_scope("tick.wipe"):
+            state = stage_recovery_wipe(cfg, params, state, xs[0])
+        return step(state, xs)
+
+    return wiped

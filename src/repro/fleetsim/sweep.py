@@ -26,6 +26,7 @@ from repro.fleetsim.engine import (
     check_fabric_arrays,
     check_hedge_delay,
     lower,
+    needs_recovery_wipe,
 )
 from repro.fleetsim.metrics import FleetResult, summarize
 from repro.fleetsim.options import EngineOptions
@@ -78,6 +79,10 @@ class SweepResult:
     # jaxpr traces, MLIR lowerings, backend compiles (count and seconds)
     # and persistent-cache hits/misses during the call (spans.COUNTERS)
     compile_events: dict[str, float] = field(default_factory=dict)
+    # whether the program was lowered with the §3.6 recovery wipe: only
+    # when some row's switch recovers inside the horizon
+    # (engine.needs_recovery_wipe)
+    recovery_wipe: bool = True
 
     @property
     def simulated_mrps(self) -> float:
@@ -360,4 +365,5 @@ def sweep_grid(
         metrics=metrics,
         phases=phases,
         compile_events=compile_events(counts_before),
+        recovery_wipe=needs_recovery_wipe(cfg, params),
     )

@@ -486,30 +486,34 @@ def run_scenarios(scenarios: list[Scenario], **cfg_overrides) -> SweepResult:
     ``sweep_grid``'s accounting, so MRPS numbers are comparable between
     Poisson grids and trace replays).  The call's host phases are
     ``sweep_grid``'s, summed over scenarios: ``compile_s`` is ``lower`` +
-    ``compile`` and ``wall_clock_s`` is ``device``."""
-    from repro.fleetsim.engine import lower
+    ``compile`` and ``wall_clock_s`` is ``device``.  A program shared by
+    scenarios keeps the §3.6 recovery wipe if any of them recovers inside
+    the horizon (``SweepResult.recovery_wipe``: any program did)."""
+    from repro.fleetsim.engine import lower, needs_recovery_wipe
 
     phases: dict[str, float] = {}
     counts_before = compile_counts()
     with phase(phases, "params"):
-        prepared = [(sc, sc.fleet_config(**cfg_overrides))
-                    for sc in scenarios]
-    compiled: dict = {}
+        prepared = []
+        for sc in scenarios:
+            cfg = sc.fleet_config(**cfg_overrides)
+            prepared.append((sc, cfg, sc.run_params(cfg)))
     # scenarios sharing a (static config, engine options) pair reuse one
-    # compiled program — EngineOptions is frozen/hashable by design
-    for sc, cfg in prepared:
-        key = (cfg, sc.engine)
-        if key not in compiled:
-            with phase(phases, "params"):
-                params = sc.run_params(cfg)
-            with phase(phases, "lower"):
-                lowered = lower(cfg, params, options=sc.engine)
-            with phase(phases, "compile"):
-                compiled[key] = lowered.compile()
+    # compiled program — EngineOptions is frozen/hashable by design —
+    # lowered on the params of one that needs the wipe, if any does
+    lowering: dict = {}
+    for sc, cfg, params in prepared:
+        key, wipe = (cfg, sc.engine), needs_recovery_wipe(cfg, params)
+        if key not in lowering or (wipe and not lowering[key][1]):
+            lowering[key] = (params, wipe)
+    compiled: dict = {}
+    for (cfg, engine), (params, _) in lowering.items():
+        with phase(phases, "lower"):
+            lowered = lower(cfg, params, options=engine)
+        with phase(phases, "compile"):
+            compiled[cfg, engine] = lowered.compile()
     results = []
-    for sc, cfg in prepared:
-        with phase(phases, "params"):
-            params = sc.run_params(cfg)
+    for sc, cfg, params in prepared:
         with phase(phases, "device"):
             m = jax.block_until_ready(compiled[cfg, sc.engine](params))
         with phase(phases, "fetch"):
@@ -525,7 +529,8 @@ def run_scenarios(scenarios: list[Scenario], **cfg_overrides) -> SweepResult:
                        n_configs=len(scenarios),
                        simulated_requests=sum(r.n_arrivals for r in results),
                        phases=phases,
-                       compile_events=compile_events(counts_before))
+                       compile_events=compile_events(counts_before),
+                       recovery_wipe=any(w for _, w in lowering.values()))
 
 
 # ------------------------------------------------------------------ library --

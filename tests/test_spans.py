@@ -38,11 +38,12 @@ OPTIONAL_SCOPES = {"tick.coordinator", "tick.hedge_timer"}
 PHASES = {"params", "lower", "compile", "device", "fetch", "summarize"}
 
 
-def compiled_scopes(cfg, policies, options):
+def compiled_scopes(cfg, policies, options, fail_window=None):
     """Every ``tick.*``/``fleetsim.*`` scope named in the ``op_name``
     metadata of the compiled batch program of ``policies``."""
     rate = load_to_rate(0.5, SVC, cfg.n_servers_total, cfg.n_workers)
-    rows = [make_params(cfg, POLICY_IDS[p], rate, 0) for p in policies]
+    rows = [make_params(cfg, POLICY_IDS[p], rate, 0, fail_window=fail_window)
+            for p in policies]
     params = jax.tree.map(lambda *a: jnp.stack(a), *rows)
     text = lower(cfg, params, options=options).compile().as_text()
     return {m.group(0) for op in re.findall(r'op_name="([^"]*)"', text)
@@ -74,6 +75,19 @@ def test_optional_stage_scopes_appear_when_compiled_in():
     scopes = compiled_scopes(cfg, policies,
                              EngineOptions(backend="staged", telemetry=True))
     assert DEFAULT_SCOPES | OPTIONAL_SCOPES | {"tick.telemetry"} <= scopes
+
+
+@pytest.mark.parametrize("backend", ["staged", "fused"])
+def test_wipe_scope_only_where_a_run_recovers(backend):
+    """The §3.6 wipe runs under ``tick.arrival/tick.wipe`` in a program
+    whose window recovers inside the horizon, and a window-free program
+    holds no op of it."""
+    opts = EngineOptions(backend=backend)
+    plain = compiled_scopes(tiny_cfg(), ["netclone"], opts)
+    windowed = compiled_scopes(tiny_cfg(), ["netclone"], opts,
+                               fail_window=(16, 32))
+    assert "tick.wipe" not in plain
+    assert DEFAULT_SCOPES | {"tick.wipe"} <= windowed
 
 
 def _sweep_unsharded():

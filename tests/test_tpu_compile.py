@@ -14,6 +14,7 @@ from math import prod
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.fleetsim import EngineOptions, FleetConfig, lower, make_params
@@ -189,7 +190,8 @@ def test_tick_keeps_the_filter_tables_in_place(one_chip, racks):
     """The fused sweep program of the testbed cell (and of a 4-rack fabric)
     takes no relayout of the filter tables inside the tick: no copy,
     reshape or transpose has their size, and the only ops over them are
-    the recovery wipe's select and the filter's scatter."""
+    the recovery wipe's select (abstract params keep it) and the filter's
+    scatter."""
     cfg = FleetConfig(n_racks=racks, n_servers=6, n_workers=15, n_clients=2,
                       n_filter_tables=2, n_filter_slots=2 ** 17,
                       n_ticks=1024)
@@ -212,7 +214,7 @@ def test_staged_tick_keeps_the_filter_tables_in_place(one_chip):
     takes no relayout of the filter tables inside
     the tick either: the hedge timer cancels by REQ_ID in its own wheel
     and reads no table, so the only ops over them stay the recovery
-    wipe's select and the filter's scatter."""
+    wipe's select (abstract params keep it) and the filter's scatter."""
     cfg = FleetConfig(n_servers=6, n_workers=15, n_clients=2,
                       n_filter_tables=2, n_filter_slots=2 ** 17,
                       n_ticks=1024).with_policy_stages(["laedge", "hedge"])
@@ -228,3 +230,40 @@ def test_staged_tick_keeps_the_filter_tables_in_place(one_chip):
     assert ops_["relayout"] == []
     assert sorted(o.split()[-1] for o in ops_["compute"]) == [
         "scatter", "select"]
+
+
+def _testbed_grid(one_chip, staged: bool, window):
+    """The testbed cell's grid for a described v5e: ``switch5``'s fused
+    40 rows, or ``all7``'s staged 56 with the coordinator and hedge timer.
+    Every input is abstract but the failure windows' recovery ticks, which
+    the engine entry reads to decide on the §3.6 wipe."""
+    cfg = FleetConfig(n_servers=6, n_workers=15, n_clients=2,
+                      n_filter_tables=2, n_filter_slots=2 ** 17,
+                      n_ticks=1024)
+    rows = ROWS
+    if staged:
+        cfg, rows = cfg.with_policy_stages(["laedge", "hedge"]), GRID
+    one = make_params(cfg, 0, 0.5, 0, fail_window=window)
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, (rows,) + a.shape, a.dtype), one)
+    params = params._replace(
+        fail_until_tick=np.full(rows, one.fail_until_tick, np.int32))
+    return cfg, params, rows
+
+
+@pytest.mark.parametrize("window", [None, (100, 200)],
+                         ids=["no-window", "window"])
+@pytest.mark.parametrize("staged", [False, True], ids=["fused", "staged"])
+def test_tick_wipes_the_tables_only_with_a_window(one_chip, staged, window):
+    """Without a failure window no switch recovers inside the horizon, so
+    the tick holds no select over the filter tables (the §3.6 wipe is not
+    compiled in) and only the filter's scatter is table-sized compute; a
+    window recovering inside the horizon compiles the wipe's select in."""
+    cfg, params, rows = _testbed_grid(one_chip, staged, window)
+    opts = EngineOptions(backend="staged" if staged else "fused")
+    text = lower(cfg, params, options=opts).compile().as_text()
+    ops_ = tick_table_ops(text, rows * cfg.filter_table_size)
+    print(f"{opts.backend}, window {window}: ops {ops_}")
+    assert ops_["relayout"] == []
+    assert sorted(o.split()[-1] for o in ops_["compute"]) == (
+        ["scatter"] if window is None else ["scatter", "select"])
