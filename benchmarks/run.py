@@ -104,7 +104,6 @@ def run_fleetsim(args) -> None:
 
     import jax
 
-    from repro.fleetsim.options import EngineOptions
     from repro.fleetsim.shard import ShardSpec
     from repro.fleetsim.validate import cross_validate_spec
     from repro.scenarios import Scenario, ServiceSpec, SweepSpec, registry
@@ -120,29 +119,10 @@ def run_fleetsim(args) -> None:
         hot_rack_weight=args.hot_rack_weight,
         straggler_rack_mult=args.straggler_mult,
         service=ServiceSpec.exponential(25.0))
-    # a sweep is ONE compiled program, so the fused backend can only take
-    # grids without the staged-only optional stages: drop stage policies
-    # (they keep their staged rows on the trajectory) instead of failing
-    pols: str | tuple = "registered"
-    if args.backend == "fused" and delays:
-        raise SystemExit("--hedge-delays sweeps the hedge_timer stage, "
-                         "which is staged-only; drop it or use "
-                         "--backend staged/auto")
-    if args.backend == "fused":
-        kept = [p for p in registry.two_engine_names()
-                if not (registry.needs_coordinator(p)
-                        or registry.needs_hedge_timer(p))]
-        dropped = sorted(set(registry.two_engine_names()) - set(kept))
-        if dropped:
-            print(f"== fused backend: stage policies {dropped} excluded "
-                  "(staged-only stages; they stay on the staged "
-                  "trajectory) ==")
-        pols = tuple(kept)
-    spec = SweepSpec(base=base, policies=pols, loads=tuple(loads),
+    spec = SweepSpec(base=base, loads=tuple(loads),
                      seeds=tuple(range(args.seeds)),
                      hedge_delays=delays,
-                     shard=ShardSpec() if args.shard else None,
-                     engine=EngineOptions(backend=args.backend))
+                     shard=ShardSpec() if args.shard else None)
     policies = spec.resolved_policies()
 
     # the delay axis only multiplies hedge-timer policies
@@ -287,12 +267,6 @@ def main() -> None:
                     help="shard the fleetsim sweep grid over every visible "
                          "device (repro.fleetsim.shard); without it the "
                          "grid vmaps onto one device")
-    ap.add_argument("--backend", choices=["auto", "staged", "fused"],
-                    default="auto",
-                    help="fleetsim engine backend (EngineOptions.backend): "
-                         "'fused' runs the TickFuse chunked/packed engine "
-                         "on the non-stage policy matrix; 'auto' picks per "
-                         "platform")
     ap.add_argument("--hedge-delays", default="",
                     help="comma-separated hedge delays (µs) added as a "
                          "traced grid axis, e.g. 50,75,100 (fleetsim)")

@@ -8,16 +8,19 @@ Every phase runs through the entry points a user calls (``SweepSpec`` /
 testbed's size — 6 servers x 15 workers per rack, exponential 25 us
 service, 1 us ticks, 50,000 ticks, one seed:
 
-(a) the paper grid on the staged backend — every registered two-engine
-    policy x the 8 benchmark loads — and the plain reference: the DES
+(a) the paper grid — every registered two-engine policy x the 8
+    benchmark loads, which the LÆDGE and hedge policies' optional stages
+    put on the staged core (``engine.select_core``) — and the plain
+    reference: the DES
     (``validate.cross_validate_spec``) at baseline / netclone / c-clone x
     0.2 / 0.5 / 0.8, every point within the tolerances of
     ``repro/fleetsim/validate.py``;
-(b) the non-stage policies of the same grid on ``backend="auto"``, which
-    must resolve to the fused backend on the chip, with Metrics
-    bit-identical to (a)'s staged rows;
-(c) a 4-rack fabric (hot rack x3, straggler rack x2) on the fused backend
-    with the TickFuse switch kernel, bit-identical to the exact
+(b) the non-stage policies of the same grid, for which the engine must
+    select the fused core on the chip, with Metrics bit-identical to (a)'s
+    staged rows;
+(c) a 4-rack fabric (hot rack x3, straggler rack x2) on the core the
+    engine selects (fused on the chip) with the TickFuse switch kernel,
+    bit-identical to the exact
     lane-sequential filter (``filter_backend="scan"``), and the kernel's
     program must hold a compiled ``tpu_custom_call`` (not an interpreted
     kernel).  The default ``vectorized`` filter runs too and its differing
@@ -74,7 +77,7 @@ def _base(racks: int = 1, n_ticks: int = N_TICKS, **kw):
 
 def non_stage_policies() -> tuple[str, ...]:
     """The registered two-engine policies that need no optional stage —
-    the fused backend's policy matrix."""
+    the fused core's policy matrix."""
     from repro.scenarios import registry
 
     return tuple(p for p in registry.two_engine_names()
@@ -117,21 +120,19 @@ def _sweep_report(phase: str, spec, sw) -> None:
 def phase_paper_grid(policies=None, loads=PAPER_LOADS, n_ticks=N_TICKS,
                      xval_policies=XVAL_POLICIES, xval_loads=XVAL_LOADS,
                      n_requests=20_000):
-    """(a) The paper grid on the staged backend, then the DES reference.
-    Returns the sweep (phase (b) compares against its Metrics)."""
-    from repro.fleetsim import EngineOptions
+    """(a) The paper grid, then the DES reference.  Returns the sweep
+    (phase (b) compares against its Metrics)."""
     from repro.fleetsim.validate import cross_validate_spec
     from repro.scenarios import SweepSpec
 
-    staged = EngineOptions(backend="staged")
     spec = SweepSpec(base=_base(n_ticks=n_ticks),
                      policies=tuple(policies) if policies else "registered",
-                     loads=tuple(loads), seeds=(0,), engine=staged)
+                     loads=tuple(loads), seeds=(0,))
     sw = spec.run_fleetsim()
     _sweep_report("a", spec, sw)
     vspec = SweepSpec(base=_base(n_ticks=n_ticks),
                       policies=tuple(xval_policies), loads=tuple(xval_loads),
-                      seeds=(0,), engine=staged)
+                      seeds=(0,))
     t0 = time.perf_counter()
     checks = cross_validate_spec(vspec, n_requests=n_requests,
                                  n_ticks=n_ticks)
@@ -146,23 +147,22 @@ def phase_paper_grid(policies=None, loads=PAPER_LOADS, n_ticks=N_TICKS,
     return sw
 
 
-def phase_fused(paper, policies=None, loads=PAPER_LOADS, n_ticks=N_TICKS,
-                backend="auto"):
-    """(b) The non-stage policies on ``backend`` (must resolve to fused),
-    bit-identical to the matching rows of ``paper``."""
-    from repro.fleetsim import EngineOptions
+def phase_fused(paper, policies=None, loads=PAPER_LOADS, n_ticks=N_TICKS):
+    """(b) The non-stage policies, on the fused core the engine must select
+    for them, bit-identical to the matching staged rows of ``paper``."""
     from repro.scenarios import SweepSpec
 
+    if paper.backend != "staged":
+        raise PhaseFailed(f"(b) compares against staged rows; (a) ran "
+                          f"{paper.backend!r}")
     spec = SweepSpec(base=_base(n_ticks=n_ticks),
                      policies=tuple(policies or non_stage_policies()),
-                     loads=tuple(loads), seeds=(0,),
-                     engine=EngineOptions(backend=backend))
+                     loads=tuple(loads), seeds=(0,))
     sw = spec.run_fleetsim()
     _sweep_report("b", spec, sw)
-    _report("b", resolution=f"{backend!r} -> {sw.backend!r}")
     if sw.backend != "fused":
-        raise PhaseFailed(f"(b) backend={backend!r} resolved to "
-                          f"{sw.backend!r}, not 'fused'")
+        raise PhaseFailed(f"(b) the engine selected {sw.backend!r}, not "
+                          "'fused'")
     row_of = {(r.policy, r.offered_load): i
               for i, r in enumerate(paper.results)}
     bad = {}
@@ -180,12 +180,13 @@ def phase_fused(paper, policies=None, loads=PAPER_LOADS, n_ticks=N_TICKS,
 
 def phase_tickfuse(racks=4, policies=None, loads=FABRIC_LOADS,
                    n_ticks=N_TICKS, expect_custom_call=True):
-    """(c) The skewed fabric on the fused backend: the TickFuse kernel
-    bit-identical to the exact sequential filter and (on the chip) compiled
-    into the program; the vectorized filter's deviation reported."""
+    """(c) The skewed fabric on the core the engine selects (fused on the
+    chip): the TickFuse kernel bit-identical to the exact sequential filter
+    and (on the chip) compiled into the program; the vectorized filter's
+    deviation reported."""
     import jax
 
-    from repro.fleetsim import EngineOptions, lower
+    from repro.fleetsim import lower, select_core
 
     base = _base(racks, n_ticks, hot_rack_weight=3.0,
                  straggler_rack_mult=2.0)
@@ -193,18 +194,18 @@ def phase_tickfuse(racks=4, policies=None, loads=FABRIC_LOADS,
              for p in (policies or non_stage_policies()) for ld in loads]
     # one static config for the grid, with arrival lanes for its hottest load
     hot = replace(base, load=max(loads))
-    opts = EngineOptions(backend="fused")
     runs = {}
     for fb in ("tickfuse", "scan", "vectorized"):
         cfg = hot.fleet_config(filter_backend=fb)
         params = _stack_params(cfg, cells)
         t0 = time.perf_counter()
-        compiled = lower(cfg, params, options=opts).compile()
+        compiled = lower(cfg, params).compile()
         t1 = time.perf_counter()
         metrics = jax.block_until_ready(compiled(params))
         t2 = time.perf_counter()
         runs[fb] = (compiled, jax.device_get(metrics))
-        _report("c", configs=len(cells), racks=racks, backend="fused",
+        _report("c", configs=len(cells), racks=racks,
+                backend=select_core(cfg),
                 filter_backend=fb, compile_s=f"{t1 - t0:.3f}",
                 run_s=f"{t2 - t1:.3f}",
                 n_truncated=int(runs[fb][1].n_truncated.sum()),

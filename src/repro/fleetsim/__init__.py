@@ -11,13 +11,13 @@ lays the sweep grid out over a device mesh so each device owns a contiguous
 slab of configurations (``shard_map`` over the ``'grid'`` axis, with an
 honest single-device fallback).
 
-The one entry point is ``simulate(cfg, params, *, options=EngineOptions())``
-— single run or vmapped batch (inferred from the params leading axis),
-staged or fused (TickFuse, ``repro.fleetsim.fused``) backend, sharded or
-not, telemetry on or off, all selected by
-:class:`~repro.fleetsim.options.EngineOptions`.  The old per-shape names
-(``simulate_batch`` & co.) are deprecated shims — see ``docs/api.md``.
-The NetClone data-plane semantics are
+The one entry point is ``simulate(cfg, params, *, shard=None)`` — single
+run or vmapped batch (inferred from the params leading axis), sharded over
+a device mesh or not.  The core is chosen from what the code can see
+(``engine.select_core``: fused TickFuse, ``repro.fleetsim.fused``, on an
+accelerator for configs without an optional stage; staged otherwise), and
+telemetry is on exactly when ``cfg.telemetry`` compiles it in — see
+``docs/api.md``.  The NetClone data-plane semantics are
 shared with ``repro.core.switch_jax`` (the same state layout and filter
 rules), and results are cross-validated against the DES in
 ``repro.fleetsim.validate`` / ``tests/test_fleetsim.py``.
@@ -48,13 +48,10 @@ from repro.fleetsim.engine import (
     RunParams,
     lower,
     make_params,
+    select_core,
     simulate,
-    simulate_batch,
-    simulate_batch_telemetry,
-    simulate_telemetry,
 )
 from repro.fleetsim.metrics import FleetResult, summarize
-from repro.fleetsim.options import EngineOptions
 from repro.fleetsim.state import (
     CoordState,
     FabricSwitch,
@@ -68,7 +65,6 @@ from repro.fleetsim.shard import (
     ShardedMetrics,
     ShardSpec,
     plan_grid,
-    simulate_batch_sharded,
 )
 from repro.fleetsim.sweep import SweepResult, rack_skew, sweep_grid
 from repro.fleetsim.telemetry import (
@@ -101,13 +97,10 @@ __all__ = [
     "POLICY_IDS",
     "POLICY_NAMES",
     "RunParams",
-    "EngineOptions",
     "make_params",
     "simulate",
     "lower",
-    "simulate_batch",
-    "simulate_telemetry",
-    "simulate_batch_telemetry",
+    "select_core",
     "RunTelemetry",
     "TelemetrySpec",
     "TickSeries",
@@ -129,7 +122,6 @@ __all__ = [
     "GridPlan",
     "ShardedMetrics",
     "plan_grid",
-    "simulate_batch_sharded",
     "CrossCheck",
     "ServeCheck",
     "ShardCheck",

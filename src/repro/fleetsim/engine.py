@@ -50,8 +50,7 @@ import numpy as np
 from repro.core.switch_jax import group_pairs_array
 from repro.fleetsim.config import FleetConfig
 from repro.fleetsim.stages import build_step, with_recovery_wipe
-from repro.fleetsim.state import FleetState, Metrics, init_fleet_state
-from repro.fleetsim.telemetry.device import SeriesState, TraceBuffer
+from repro.fleetsim.state import FleetState, init_fleet_state
 from repro.scenarios import registry
 
 
@@ -235,13 +234,6 @@ def _simulate_core(cfg: FleetConfig, params: RunParams,
     return state
 
 
-def _core_telemetry(cfg: FleetConfig, params: RunParams,
-                    recovery_wipe: bool = True
-                    ) -> tuple[Metrics, TraceBuffer, SeriesState]:
-    state = _simulate_core(cfg, params, recovery_wipe)
-    return state.metrics, state.trace, state.series
-
-
 #: the threefry PRNG mode every engine program traces and lowers under —
 #: the mode the checked-in goldens were captured with (JAX's default before
 #: it flipped ``jax_threefry_partitionable`` to True), pinned so results do
@@ -266,48 +258,58 @@ class PinnedPrng:
             return self._jitted.lower(*args, **kwargs)
 
 
-# One jitted entry per execution shape (backend × batch × telemetry ×
-# donation × fused chunk length), built on demand and cached so every
-# caller of the same shape shares one jit cache.  The compiled programs
-# bake in the registry's branch tables, so each entry is additionally
-# keyed on registry.version(): registering a policy after a compile forces
-# a retrace with the grown lax.switch table instead of silently reusing a
-# stale executable.  The static recovery_wipe is needs_recovery_wipe of
-# the concrete params the caller passes.
-@functools.lru_cache(maxsize=None)
-def _entry(backend: str, batch: bool, telemetry: bool, donate: bool,
-           ticks_per_chunk: int):
-    if backend == "fused":
+def select_core(cfg: FleetConfig) -> str:
+    """The core that runs ``cfg`` on this process's platform.
+
+    ``'fused'`` (:mod:`repro.fleetsim.fused`: the staged tick replayed in
+    chunks over a dtype-packed carry, bit-identical to ``'staged'``) on an
+    accelerator (TPU or GPU) when the config compiles in no optional
+    stage: no coordinator, no hedge timer, no telemetry and no batch
+    server.  ``'staged'`` (one ``lax.scan`` over ticks) everywhere else,
+    CPU included.  The one place the engine decides its execution path."""
+    if jax.default_backend() not in ("tpu", "gpu"):
+        return "staged"
+    if (cfg.coordinator or cfg.hedge_timer or cfg.telemetry
+            or cfg.server_model == "batch"):
+        return "staged"
+    return "fused"
+
+
+def run_core(core: str, cfg: FleetConfig, params: RunParams,
+             recovery_wipe: bool) -> FleetState:
+    """Advance one fabric on ``core`` (:func:`select_core`)."""
+    if core == "fused":
         from repro.fleetsim.fused import fused_core
 
-        def core(cfg, p, wipe):
-            return fused_core(cfg, p, ticks_per_chunk, wipe).metrics
-    elif telemetry:
-        # FleetScope: the trace ring + series accumulators ride out of the
-        # program alongside the metrics.  A separate entry, so a
-        # metrics-only caller never pays the telemetry transfer.
-        core = _core_telemetry
-    else:
-        def core(cfg, p, wipe):
-            return _simulate_core(cfg, p, wipe).metrics
+        return fused_core(cfg, params, recovery_wipe)
+    return _simulate_core(cfg, params, recovery_wipe)
+
+
+# One jitted entry per execution shape (core × batch × telemetry), built on
+# demand and cached so every caller of the same shape shares one jit cache.
+# The compiled programs bake in the registry's branch tables, so each entry
+# is additionally keyed on registry.version(): registering a policy after a
+# compile forces a retrace with the grown lax.switch table instead of
+# silently reusing a stale executable.  The static recovery_wipe is
+# needs_recovery_wipe of the concrete params the caller passes.
+@functools.lru_cache(maxsize=None)
+def _entry(core: str, batch: bool, telemetry: bool):
+    def one(cfg, p, wipe):
+        state = run_core(core, cfg, p, wipe)
+        if telemetry:
+            # FleetScope: the trace ring + series accumulators ride out of
+            # the program alongside the metrics
+            return state.metrics, state.trace, state.series
+        return state.metrics
 
     def run(cfg: FleetConfig, registry_version: int, recovery_wipe: bool,
             params: RunParams):
         if batch:
-            return jax.vmap(lambda p: core(cfg, p, recovery_wipe))(params)
-        return core(cfg, params, recovery_wipe)
+            return jax.vmap(lambda p: one(cfg, p, recovery_wipe))(params)
+        return one(cfg, params, recovery_wipe)
 
     return PinnedPrng(jax.jit(
-        run, static_argnames=("cfg", "registry_version", "recovery_wipe"),
-        donate_argnums=(3,) if donate else ()))
-
-
-def _check_telemetry(cfg: FleetConfig) -> None:
-    if not cfg.telemetry:
-        raise ValueError(
-            "telemetry entry points need cfg.telemetry=True (the trace "
-            "ring and series stages are compile-time optional; rebuild the "
-            "config, or use TelemetrySpec.apply)")
+        run, static_argnames=("cfg", "registry_version", "recovery_wipe")))
 
 
 def _is_batched(params: RunParams) -> bool:
@@ -319,147 +321,41 @@ def _is_batched(params: RunParams) -> bool:
     return ndim == 1
 
 
-def _resolve(cfg: FleetConfig, options):
-    """Normalize ``options`` and resolve the concrete execution path."""
-    from repro.fleetsim.options import EngineOptions
-
-    opts = EngineOptions() if options is None else options
-    if not isinstance(opts, EngineOptions):
-        raise TypeError(f"options must be an EngineOptions, got "
-                        f"{type(opts).__name__}")
-    backend = opts.resolve_backend(cfg)
-    if opts.telemetry:
-        _check_telemetry(cfg)
-    k = 0
-    if backend == "fused":
-        from repro.fleetsim.fused import resolve_chunk
-
-        k = resolve_chunk(cfg, opts.ticks_per_chunk)
-    return opts, backend, k
-
-
-def simulate(cfg: FleetConfig, params: RunParams, *, options=None):
+def simulate(cfg: FleetConfig, params: RunParams, *, shard=None):
     """THE FleetSim entry point: run ``params`` on ``cfg``, fully jitted.
 
     ``params`` with scalar fields runs one fabric; a leading sweep axis
-    runs the whole batch in one vmapped device program.  Everything else
-    is an :class:`~repro.fleetsim.options.EngineOptions`:
+    runs the whole batch in one vmapped device program.  The core is
+    :func:`select_core`'s.  Returns device :class:`Metrics`, or
+    ``(metrics, trace, series)`` when ``cfg.telemetry`` compiled FleetScope
+    in (decode with :func:`repro.fleetsim.telemetry.decode_run`; the
+    metrics are bit-identical either way — telemetry observes, it never
+    feeds back).
 
-    * ``options=None`` / default — staged-or-fused automatically
-      (``backend='auto'``), single device, metrics only; on the default
-      options this is exactly the program the repo always compiled.
-    * ``EngineOptions(backend='fused')`` — the TickFuse backend
-      (:mod:`repro.fleetsim.fused`), bit-identical on non-stage policies.
-    * ``EngineOptions(telemetry=True)`` — returns ``(metrics, trace,
-      series)``; decode with :func:`repro.fleetsim.telemetry.decode_run`.
-      Metrics stay bit-identical — telemetry observes, it never feeds back.
-    * ``EngineOptions(shard=...)`` — lays a *batched* run over a device
-      mesh and returns a :class:`~repro.fleetsim.shard.ShardedMetrics`.
-    * ``EngineOptions(donate=True)`` — donates the ``params`` buffers to
-      the compiled call (the caller's arrays are consumed).
-
-    Returns device :class:`Metrics` (or the telemetry triple / sharded
-    wrapper as selected).  The deprecated ``simulate_batch`` /
-    ``simulate_telemetry`` / ``simulate_batch_telemetry`` /
-    ``simulate_batch_sharded`` names are thin shims over this function —
-    see ``docs/api.md`` for the migration table.
+    ``shard`` (``None`` | device count | :class:`~repro.fleetsim.shard.
+    ShardSpec`) lays a *batched* run over a device mesh and returns a
+    :class:`~repro.fleetsim.shard.ShardedMetrics`.
     """
-    opts, backend, k = _resolve(cfg, options)
+    from repro.fleetsim.shard import as_shard, run_sharded
+
+    shard = as_shard(shard)
     batched = _is_batched(params)
-    if opts.shard is not None:
+    if shard is not None:
         if not batched:
             raise ValueError(
-                "EngineOptions.shard lays a sweep grid over a device mesh; "
-                "params must carry a leading sweep axis (got scalar "
-                "RunParams)")
-        from repro.fleetsim.shard import run_sharded
-
-        return run_sharded(cfg, params, opts.shard, backend=backend,
-                           ticks_per_chunk=k)
-    entry = _entry(backend, batched, opts.telemetry, opts.donate, k)
+                "shard lays a sweep grid over a device mesh; params must "
+                "carry a leading sweep axis (got scalar RunParams)")
+        return run_sharded(cfg, params, shard)
+    entry = _entry(select_core(cfg), batched, cfg.telemetry)
     return entry(cfg, registry.version(), needs_recovery_wipe(cfg, params),
                  params)
 
 
-def lower(cfg: FleetConfig, params: RunParams, *, options=None):
-    """``jit(...).lower`` for :func:`simulate` (any single-device execution
-    shape) — sweep harnesses report compile time separately from
-    steady-state wall clock.  Sharded lowering lives in
-    :func:`repro.fleetsim.shard.lower_sharded` (it needs the padded grid
-    plan, not just params)."""
-    opts, backend, k = _resolve(cfg, options)
-    if opts.shard is not None:
-        raise ValueError("lower() is single-device; build a GridPlan and "
-                         "use repro.fleetsim.shard.lower_sharded")
-    entry = _entry(backend, _is_batched(params), opts.telemetry,
-                   opts.donate, k)
+def lower(cfg: FleetConfig, params: RunParams):
+    """``jit(...).lower`` for :func:`simulate` on one device — sweep
+    harnesses report compile time separately from steady-state wall clock.
+    Sharded lowering lives in :func:`repro.fleetsim.shard.lower_sharded`
+    (it needs the padded grid plan, not just params)."""
+    entry = _entry(select_core(cfg), _is_batched(params), cfg.telemetry)
     return entry.lower(cfg, registry.version(),
                        needs_recovery_wipe(cfg, params), params)
-
-
-def lower_run(cfg: FleetConfig, params: RunParams):
-    """``jit(...).lower`` for a single staged run (scenario runners)."""
-    return _entry("staged", False, False, False, 0).lower(
-        cfg, registry.version(), needs_recovery_wipe(cfg, params), params)
-
-
-def lower_batch(cfg: FleetConfig, params: RunParams):
-    """``jit(...).lower`` for the staged batch runner."""
-    return _entry("staged", True, False, False, 0).lower(
-        cfg, registry.version(), needs_recovery_wipe(cfg, params), params)
-
-
-def lower_batch_telemetry(cfg: FleetConfig, params: RunParams):
-    """``jit(...).lower`` for the staged telemetry batch runner."""
-    _check_telemetry(cfg)
-    return _entry("staged", True, True, False, 0).lower(
-        cfg, registry.version(), needs_recovery_wipe(cfg, params), params)
-
-
-# ------------------------------------------------------- deprecated shims --
-# The five-way entry-point split (simulate / simulate_batch /
-# simulate_telemetry / simulate_batch_telemetry / simulate_batch_sharded)
-# collapsed into simulate(cfg, params, options=EngineOptions(...)).  The old
-# names keep working — pinned to backend='staged', so their programs and
-# results are exactly what they always were — but warn; internal callsites
-# are ruff-gated off them (TID251, pyproject.toml).  docs/api.md carries
-# the migration table and removal schedule.
-def _warn_deprecated(old: str, new: str) -> None:
-    import warnings
-
-    warnings.warn(f"repro.fleetsim.{old} is deprecated; use {new}",
-                  DeprecationWarning, stacklevel=3)
-
-
-def simulate_batch(cfg: FleetConfig, params: RunParams) -> Metrics:
-    """Deprecated: ``simulate`` infers the batch from the params axis."""
-    _warn_deprecated("simulate_batch(cfg, params)",
-                     "simulate(cfg, params) — the leading sweep axis "
-                     "selects the batched program")
-    return _entry("staged", True, False, False, 0)(
-        cfg, registry.version(), needs_recovery_wipe(cfg, params), params)
-
-
-def simulate_telemetry(cfg: FleetConfig, params: RunParams
-                       ) -> tuple[Metrics, TraceBuffer, SeriesState]:
-    """Deprecated: use ``simulate(..., options=EngineOptions(
-    telemetry=True))``; returns the same ``(metrics, trace, series)``."""
-    _warn_deprecated("simulate_telemetry(cfg, params)",
-                     "simulate(cfg, params, options="
-                     "EngineOptions(telemetry=True))")
-    _check_telemetry(cfg)
-    return _entry("staged", False, True, False, 0)(
-        cfg, registry.version(), needs_recovery_wipe(cfg, params), params)
-
-
-def simulate_batch_telemetry(cfg: FleetConfig, params: RunParams
-                             ) -> tuple[Metrics, TraceBuffer, SeriesState]:
-    """Deprecated: use ``simulate(..., options=EngineOptions(
-    telemetry=True))`` with batched params."""
-    _warn_deprecated("simulate_batch_telemetry(cfg, params)",
-                     "simulate(cfg, params, options="
-                     "EngineOptions(telemetry=True)) — the leading sweep "
-                     "axis selects the batched program")
-    _check_telemetry(cfg)
-    return _entry("staged", True, True, False, 0)(
-        cfg, registry.version(), needs_recovery_wipe(cfg, params), params)

@@ -1,8 +1,8 @@
-"""TickFuse: the fused FleetSim engine backend.
+"""TickFuse: the fused FleetSim core.
 
-The staged backend advances one tick per ``lax.scan`` step with the whole
+The staged core advances one tick per ``lax.scan`` step with the whole
 :class:`~repro.fleetsim.state.FleetState` as the int32/float32 carry.  This
-backend restructures the *execution* of the same tick — never its
+core restructures the *execution* of the same tick — never its
 semantics:
 
 * **chunked scan** — an outer ``lax.scan`` advances ``K`` ticks per step
@@ -16,21 +16,21 @@ semantics:
   uint8 / int16, widening — never wrapping) at chunk boundaries and
   unpacked inside the chunk.  Integer round-trips within the bound are
   exact, so packing cannot change a single bit of the results.  REQ_ID
-  carriers (spine ``seq``, filter tables, client dedup) stay int32;
-* **fused switch kernel (opt-in)** — a config with
-  ``filter_backend="tickfuse"`` runs the switch response path as the
-  TickFuse kernel (``repro.kernels.tickfuse``): StateT write + fingerprint
-  filter in one launch.  Nothing selects it automatically — the default
-  stays the ``vectorized`` scatter path on every platform until a chip
-  measurement says which is faster.
+  carriers (spine ``seq``, filter tables, client dedup) stay int32.
+
+The switch response path is whatever ``FleetConfig.filter_backend`` names,
+as in the staged core: the TickFuse Pallas kernel
+(``repro.kernels.tickfuse``: StateT write + fingerprint filter in one
+launch) runs only for ``filter_backend="tickfuse"``, which nothing selects
+by default.
 
 Because every tick replays :func:`repro.fleetsim.stages.build_step`
-verbatim — same PRNG draws, same op order — the fused backend is
-**bit-identical** to the staged backend on the non-stage policy matrix
+verbatim — same PRNG draws, same op order — the fused core is
+**bit-identical** to the staged core on the non-stage policy matrix
 (enforced by ``tests/test_fused.py`` against the staged engine and the
-checked-in goldens).  Configs with optional stages (coordinator /
-hedge_timer) or telemetry are staged-only; ``EngineOptions`` routes them
-there (``backend='auto'``) or rejects them (``backend='fused'``).
+checked-in goldens).  ``engine.select_core`` picks it on an accelerator
+for configs without an optional stage (coordinator / hedge_timer /
+telemetry / batch server); those run staged.
 """
 
 from __future__ import annotations
@@ -43,7 +43,7 @@ from repro.fleetsim.config import FleetConfig
 from repro.fleetsim.stages import build_step, with_recovery_wipe
 from repro.fleetsim.state import FleetState, init_fleet_state
 
-#: default K — ticks advanced per outer scan step (0/auto in EngineOptions)
+#: K — ticks advanced per outer scan step (clipped to n_ticks)
 DEFAULT_TICKS_PER_CHUNK = 512
 
 
@@ -102,30 +102,25 @@ def unpack_state(state: FleetState) -> FleetState:
 
 
 # ----------------------------------------------------------------- runner ---
-def resolve_chunk(cfg: FleetConfig, ticks_per_chunk: int = 0) -> int:
-    """The concrete K for this config (0 → default, clipped to n_ticks)."""
-    k = ticks_per_chunk or DEFAULT_TICKS_PER_CHUNK
-    return max(1, min(k, cfg.n_ticks))
+def fused_core(cfg: FleetConfig, params, recovery_wipe: bool = True,
+               chunk: int = DEFAULT_TICKS_PER_CHUNK) -> FleetState:
+    """Advance one fabric for ``cfg.n_ticks`` ticks on the fused core.
 
-
-def fused_core(cfg: FleetConfig, params, ticks_per_chunk: int = 0,
-               recovery_wipe: bool = True) -> FleetState:
-    """Advance one fabric for ``cfg.n_ticks`` ticks on the fused backend.
-
-    Chunks of ``K`` ticks ride an outer ``lax.scan`` whose carry is the
-    dtype-packed state; each chunk unpacks, replays the exact staged tick
-    ``K`` times (an inner scan over :func:`stages.build_step`), and
-    repacks.  A remainder ``n_ticks mod K`` runs as a staged tail — so any
-    K yields bit-identical results, K only moves the pack points.
+    Chunks of ``K = min(chunk, n_ticks)`` ticks ride an outer ``lax.scan``
+    whose carry is the dtype-packed state; each chunk unpacks, replays the
+    exact staged tick ``K`` times (an inner scan over
+    :func:`stages.build_step`), and repacks.  A remainder ``n_ticks mod K``
+    runs as a staged tail — so any K yields bit-identical results, K only
+    moves the pack points (the engine always uses the default).
     ``recovery_wipe`` opens each tick with the §3.6 wipe, as in the staged
     engine (``engine.needs_recovery_wipe``).
     """
     if cfg.coordinator or cfg.hedge_timer or cfg.telemetry:
         raise ValueError(
-            "the fused backend supports the always-on pipeline only; "
+            "the fused core supports the always-on pipeline only; "
             "coordinator/hedge_timer/telemetry configs run staged "
-            "(EngineOptions(backend='auto') routes them automatically)")
-    k = resolve_chunk(cfg, ticks_per_chunk)
+            "(engine.select_core routes them there)")
+    k = max(1, min(chunk, cfg.n_ticks))
     with jax.named_scope("fleetsim.init"):
         gp = group_pairs_array(cfg.n_servers)
         k_pois, k0 = jax.random.split(jax.random.PRNGKey(params.seed))

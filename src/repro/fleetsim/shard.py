@@ -2,7 +2,7 @@
 
 The unsharded engine vmaps a whole policy × load × seed (× hedge-delay)
 grid onto *one* device.  This module is the multi-device execution path —
-``simulate(cfg, params, options=EngineOptions(shard=...))`` — the same
+``simulate(cfg, params, shard=...)`` — the same
 grid is laid out on a 1-D :class:`jax.sharding.Mesh` (axis ``'grid'``) and
 run under ``shard_map``, so each device owns a **contiguous slab of
 configurations** and advances it with the exact per-configuration program
@@ -52,9 +52,9 @@ from repro.fleetsim.config import FleetConfig
 from repro.fleetsim.engine import (
     PinnedPrng,
     RunParams,
-    _entry,
-    _simulate_core,
     needs_recovery_wipe,
+    run_core,
+    select_core,
 )
 from repro.fleetsim.state import Metrics
 from repro.scenarios import registry
@@ -192,27 +192,20 @@ def plan_grid(params: RunParams, spec: ShardSpec) -> GridPlan:
 # ---------------------------------------------------------------- runner ----
 # Like engine._entry's programs, the cache is keyed on registry.version()
 # (post-compile policy registrations must retrace the grown switch tables)
-# and additionally on the mesh + backend, so layout and backend changes
-# each get their own executable, and on whether the §3.6 recovery wipe is
-# compiled in (engine.needs_recovery_wipe of the padded grid).
+# and additionally on the mesh and the core (engine.select_core), so layout
+# and core changes each get their own executable, and on whether the §3.6
+# recovery wipe is compiled in (engine.needs_recovery_wipe of the padded
+# grid).
 def _simulate_sharded(cfg: FleetConfig, registry_version: int,
                       mesh: Mesh, params: RunParams, mask: jax.Array,
-                      backend: str = "staged", ticks_per_chunk: int = 0,
-                      recovery_wipe: bool = True):
+                      core: str, recovery_wipe: bool = True):
     axis = mesh.axis_names[0]
-    if backend == "fused":
-        from repro.fleetsim.fused import fused_core
-
-        def core(q):
-            return fused_core(cfg, q, ticks_per_chunk, recovery_wipe).metrics
-    else:
-        def core(q):
-            return _simulate_core(cfg, q, recovery_wipe).metrics
 
     def slab(p: RunParams, m: jax.Array):
         # each device advances its contiguous slab with the per-config
         # program of the unsharded engine — no cross-device traffic …
-        met = jax.vmap(core)(p)
+        met = jax.vmap(
+            lambda q: run_core(core, cfg, q, recovery_wipe).metrics)(p)
         # … except the histogram merge: mask out padding, reduce the slab
         # locally, then one psum (tree/ring all-reduce) across the mesh
         keep = m.astype(met.hist.dtype)
@@ -231,18 +224,15 @@ def _simulate_sharded(cfg: FleetConfig, registry_version: int,
 
 _simulate_sharded_jit = PinnedPrng(jax.jit(
     _simulate_sharded, static_argnames=("cfg", "registry_version", "mesh",
-                                        "backend", "ticks_per_chunk",
-                                        "recovery_wipe")))
+                                        "core", "recovery_wipe")))
 
 
-def lower_sharded(cfg: FleetConfig, plan: GridPlan,
-                  backend: str = "staged", ticks_per_chunk: int = 0):
+def lower_sharded(cfg: FleetConfig, plan: GridPlan):
     """``jit(...).lower`` for the sharded runner (sweeps report compile
     time separately from steady-state wall clock, like ``engine.lower``)."""
     return _simulate_sharded_jit.lower(cfg, registry.version(), plan.mesh,
                                        plan.params, plan.mask,
-                                       backend=backend,
-                                       ticks_per_chunk=ticks_per_chunk,
+                                       core=select_core(cfg),
                                        recovery_wipe=needs_recovery_wipe(
                                            cfg, plan.params))
 
@@ -251,15 +241,13 @@ def _strip_pad(plan: GridPlan, metrics: Metrics) -> Metrics:
     return jax.tree.map(lambda a: a[:plan.n_grid], metrics)
 
 
-def run_sharded(cfg: FleetConfig, params: RunParams, spec: ShardSpec, *,
-                backend: str = "staged",
-                ticks_per_chunk: int = 0) -> ShardedMetrics:
-    """The mesh-sharded execution path behind ``simulate(..., options=
-    EngineOptions(shard=...))``.
+def run_sharded(cfg: FleetConfig, params: RunParams,
+                spec: ShardSpec) -> ShardedMetrics:
+    """The mesh-sharded execution path behind ``simulate(..., shard=...)``.
 
     Pads the grid onto ``spec``'s mesh and runs the ``shard_map`` program
-    on the selected backend; per-configuration results are
-    bitwise-identical to the unsharded run (enforced by
+    on :func:`~repro.fleetsim.engine.select_core`'s core; per-configuration
+    results are bitwise-identical to the unsharded run (enforced by
     ``validate.shard_equivalence`` and ``tests/test_fleetsim_shard.py``).
     """
     if cfg.telemetry:
@@ -271,38 +259,7 @@ def run_sharded(cfg: FleetConfig, params: RunParams, spec: ShardSpec, *,
     plan = plan_grid(params, spec)
     met, grid_hist = _simulate_sharded_jit(cfg, registry.version(),
                                            plan.mesh, plan.params, plan.mask,
-                                           backend=backend,
-                                           ticks_per_chunk=ticks_per_chunk,
+                                           core=select_core(cfg),
                                            recovery_wipe=needs_recovery_wipe(
                                                cfg, plan.params))
     return ShardedMetrics(metrics=_strip_pad(plan, met), grid_hist=grid_hist)
-
-
-def simulate_batch_sharded(cfg: FleetConfig, params: RunParams,
-                           shard=None) -> ShardedMetrics:
-    """Deprecated: use ``simulate(cfg, params, options=EngineOptions(
-    shard=...))``.
-
-    Behavior is unchanged: ``shard=None`` is the honest single-device
-    fallback (the exact staged batch program, aggregate histogram computed
-    from its output); any other ``shard`` runs :func:`run_sharded` on the
-    staged backend.
-    """
-    import warnings
-
-    warnings.warn(
-        "repro.fleetsim.simulate_batch_sharded(cfg, params, shard) is "
-        "deprecated; use simulate(cfg, params, options="
-        "EngineOptions(shard=shard))", DeprecationWarning, stacklevel=2)
-    spec = as_shard(shard)
-    if cfg.telemetry and spec is not None:
-        raise ValueError(
-            "telemetry is not supported on the sharded runner (the trace "
-            "ring would be sharded too and its per-device rings cannot be "
-            "merged into one chronological stream); run the traced config "
-            "unsharded, or drop cfg.telemetry for the sharded sweep")
-    if spec is None:
-        met = _entry("staged", True, False, False, 0)(
-            cfg, registry.version(), needs_recovery_wipe(cfg, params), params)
-        return ShardedMetrics(metrics=met, grid_hist=met.hist.sum(axis=0))
-    return run_sharded(cfg, params, spec)

@@ -27,9 +27,9 @@ from repro.fleetsim.engine import (
     check_hedge_delay,
     lower,
     needs_recovery_wipe,
+    select_core,
 )
 from repro.fleetsim.metrics import FleetResult, summarize
-from repro.fleetsim.options import EngineOptions
 from repro.fleetsim.shard import (
     ShardSpec,
     as_shard,
@@ -55,8 +55,9 @@ class SweepResult:
     n_devices: int = 1
     shard: ShardSpec | None = None
     n_pad: int = 0                   # grid rows added to divide the mesh
-    # the concrete engine backend the sweep compiled ('staged' | 'fused')
-    # — perf baselines key on it (tools/check_perf_trend.py)
+    # the core the sweep compiled ('staged' | 'fused',
+    # engine.select_core) — perf baselines key on it
+    # (tools/check_perf_trend.py)
     backend: str = "staged"
     # grid-aggregate latency histogram (n_racks, hist_bins), merged
     # device-locally + tree-reduced on the mesh (shard.ShardedMetrics)
@@ -145,10 +146,10 @@ def rack_skew(cfg: FleetConfig, hot_rack_weight: float = 1.0,
 
 def _grid_inputs(service, policies, loads, seeds, cfg, slowdown,
                  rack_weights, fail_window_ticks, link_failure,
-                 resize_arrival_lanes, hedge_delays, shard, engine, cfg_kw):
+                 resize_arrival_lanes, hedge_delays, shard, cfg_kw):
     """Check a sweep's arguments and build its inputs: the stage-complete
-    config, the grid rows, the rate per load, the batched ``RunParams``,
-    the resolved backend, the shard layout and the engine options."""
+    config, the grid rows, the rate per load, the batched ``RunParams``
+    and the shard layout."""
     spec = _as_spec(service)
     if cfg is None:
         cfg = FleetConfig(service=spec, **cfg_kw)
@@ -213,21 +214,12 @@ def _grid_inputs(service, policies, loads, seeds, cfg, slowdown,
     )
     params = jax.tree.map(lambda a: jax.numpy.asarray(a), params)
 
-    opts = engine if engine is not None else EngineOptions()
     shard_spec = as_shard(shard)
-    if shard_spec is not None and opts.shard is not None:
-        raise ValueError("pass the shard layout once: either shard= or "
-                         "engine=EngineOptions(shard=...), not both")
-    shard_spec = shard_spec if shard_spec is not None else opts.shard
     if cfg.telemetry and shard_spec is not None:
         raise ValueError(
             "telemetry sweeps cannot shard (per-device trace rings have no "
             "merged chronological order); drop shard= or cfg.telemetry")
-    # resolve the backend against the *stage-complete* cfg: an explicit
-    # fused request fails here with the options-layer error when the
-    # policy set compiled in a staged-only stage; 'auto' falls back
-    backend = opts.resolve_backend(cfg)
-    return cfg, grid, rates, params, backend, shard_spec, opts
+    return cfg, grid, rates, params, shard_spec
 
 
 def sweep_grid(
@@ -243,7 +235,6 @@ def sweep_grid(
     resize_arrival_lanes: bool = True,
     hedge_delays: list[float] | None = None,
     shard: ShardSpec | int | None = None,
-    engine: EngineOptions | None = None,
     **cfg_kw,
 ) -> SweepResult:
     """Run every (policy, load, seed[, hedge delay]) combination in one
@@ -271,10 +262,9 @@ def sweep_grid(
     running per-delay duplicates.  ``shard`` (``None`` | device count |
     ``ShardSpec``)
     spreads the grid over a device mesh via :mod:`repro.fleetsim.shard`;
-    ``None`` compiles the exact single-device program.  ``engine``
-    (:class:`~repro.fleetsim.options.EngineOptions`) selects the execution
-    backend — staged or fused (TickFuse) — and may carry the shard layout
-    itself; passing a shard both ways is an error.
+    ``None`` compiles the exact single-device program.  The core is
+    :func:`~repro.fleetsim.engine.select_core`'s for the stage-complete
+    config, and ``SweepResult.backend`` records it.
 
     Returns host-side results plus wall-clock accounting (compile time
     reported separately so sweep cost is judged on the steady-state
@@ -286,22 +276,19 @@ def sweep_grid(
     phases: dict[str, float] = {}
     counts_before = compile_counts()
     with phase(phases, "params"):
-        cfg, grid, rates, params, backend, shard_spec, opts = _grid_inputs(
+        cfg, grid, rates, params, shard_spec = _grid_inputs(
             service, policies, loads, seeds, cfg, slowdown, rack_weights,
             fail_window_ticks, link_failure, resize_arrival_lanes,
-            hedge_delays, shard, engine, cfg_kw)
+            hedge_delays, shard, cfg_kw)
     g = len(grid)
     with phase(phases, "lower"):
         if shard_spec is None:
             args = (params,)
-            lowered = lower(cfg, params, options=EngineOptions(
-                backend=backend, telemetry=cfg.telemetry,
-                ticks_per_chunk=opts.ticks_per_chunk))
+            lowered = lower(cfg, params)
         else:
             plan = plan_grid(params, shard_spec)
             args = (plan.params, plan.mask)
-            lowered = lower_sharded(cfg, plan, backend=backend,
-                                    ticks_per_chunk=opts.ticks_per_chunk)
+            lowered = lower_sharded(cfg, plan)
     with phase(phases, "compile"):
         compiled = lowered.compile()
     with phase(phases, "device"):
@@ -357,7 +344,7 @@ def sweep_grid(
         n_devices=n_devices,
         shard=shard_spec,
         n_pad=n_pad,
-        backend=backend,
+        backend=select_core(cfg),
         grid_hist=grid_hist,
         telemetry=telemetry,
         cost_flops=cost_flops,

@@ -33,7 +33,6 @@ from repro.fleetsim.chaos import LinkFailure
 from repro.fleetsim.config import FleetConfig
 from repro.fleetsim.engine import make_params, simulate
 from repro.fleetsim.metrics import FleetResult, summarize
-from repro.fleetsim.options import EngineOptions
 from repro.fleetsim.shard import ShardSpec
 from repro.fleetsim.spans import compile_counts, compile_events, phase
 from repro.fleetsim.sweep import SweepResult, rack_skew, sweep_grid
@@ -95,10 +94,6 @@ class Scenario:
     # FleetScope observability (repro.fleetsim.telemetry): None runs the
     # exact telemetry-off program; a spec compiles the trace/series stages in
     telemetry: TelemetrySpec | None = None
-    # engine execution options (repro.fleetsim.options): None runs the
-    # default ('auto' backend — staged, or fused where native); pinned
-    # options ride the JSON so a file reproduces its exact execution path
-    engine: EngineOptions | None = None
 
     def __post_init__(self):
         # injection windows are validated at spec load: a window hanging
@@ -202,9 +197,8 @@ class Scenario:
     def fleet_metrics(self, **cfg_overrides):
         """Run the array engine; returns ``(cfg, raw device Metrics)``."""
         cfg = self.fleet_config(**cfg_overrides)
-        m = jax.block_until_ready(
-            simulate(cfg, self.run_params(cfg), options=self.engine))
-        return cfg, m
+        out = jax.block_until_ready(simulate(cfg, self.run_params(cfg)))
+        return cfg, out[0] if cfg.telemetry else out
 
     def run_fleetsim(self, **cfg_overrides) -> FleetResult:
         cfg, m = self.fleet_metrics(**cfg_overrides)
@@ -224,10 +218,8 @@ class Scenario:
         sc = self if self.telemetry is not None and self.telemetry.enabled \
             else replace(self, telemetry=TelemetrySpec())
         cfg = sc.fleet_config(**cfg_overrides)
-        opts = replace(self.engine or EngineOptions(),
-                       telemetry=True, shard=None)
         m, trace, series = jax.block_until_ready(
-            simulate(cfg, sc.run_params(cfg), options=opts))
+            simulate(cfg, sc.run_params(cfg)))
         m, trace, series = jax.device_get((m, trace, series))
         result = summarize(cfg, m, policy=self.policy,
                            load=self.effective_load(cfg.n_ticks),
@@ -305,8 +297,6 @@ class Scenario:
             d["dt_us"] = self.dt_us
         if self.telemetry is not None:
             d["telemetry"] = self.telemetry.to_json()
-        if self.engine is not None:
-            d["engine"] = self.engine.to_json()
         return d
 
     _JSON_KEYS = ("name", "policy", "load", "seed", "racks", "servers",
@@ -314,7 +304,7 @@ class Scenario:
                   "straggler_rack_mult", "queue_cap", "max_arrivals",
                   "server_model", "batch_slots", "batch_coupling", "dt_us",
                   "service", "arrival", "slowdown", "fail_window_ticks",
-                  "link_failure", "telemetry", "engine")
+                  "link_failure", "telemetry")
 
     @classmethod
     def from_json(cls, d: dict) -> "Scenario":
@@ -327,7 +317,7 @@ class Scenario:
         kw = {k: d[k] for k in cls._JSON_KEYS
               if k in d and k not in ("service", "arrival", "slowdown",
                                       "fail_window_ticks", "link_failure",
-                                      "telemetry", "engine")}
+                                      "telemetry")}
         if "service" in d:
             kw["service"] = ServiceSpec.from_json(d["service"])
         kw["arrival"] = arrival_from_json(d.get("arrival"))
@@ -339,8 +329,6 @@ class Scenario:
             kw["link_failure"] = LinkFailure.from_json(d["link_failure"])
         if d.get("telemetry") is not None:
             kw["telemetry"] = TelemetrySpec.from_json(d["telemetry"])
-        if d.get("engine") is not None:
-            kw["engine"] = EngineOptions.from_json(d["engine"])
         return cls(**kw)
 
     def to_file(self, path) -> Path:
@@ -375,10 +363,6 @@ class SweepSpec:
     seeds: tuple[int, ...] = (0,)
     hedge_delays: tuple[float, ...] = ()
     shard: ShardSpec | None = None
-    # engine execution options for the whole grid (backend, chunking);
-    # None runs the default 'auto' backend.  The shard layout stays in
-    # ``shard`` — an engine sub-object carrying one too is rejected.
-    engine: EngineOptions | None = None
 
     def resolved_policies(self) -> list[str]:
         if self.policies == "registered":
@@ -421,7 +405,7 @@ class SweepSpec:
                               link_failure=base.link_failure,
                               resize_arrival_lanes=not pinned,
                               hedge_delays=list(self.hedge_delays) or None,
-                              shard=self.shard, engine=self.engine)
+                              shard=self.shard)
         if self.shard is not None or self.hedge_delays:
             raise ValueError("shard / hedge_delays are Poisson-grid "
                              "features (one vmapped program); trace "
@@ -444,12 +428,10 @@ class SweepSpec:
             d["hedge_delays"] = list(self.hedge_delays)
         if self.shard is not None:
             d["shard"] = self.shard.to_json()
-        if self.engine is not None:
-            d["engine"] = self.engine.to_json()
         return d
 
     _JSON_KEYS = ("base", "policies", "loads", "seeds", "hedge_delays",
-                  "shard", "engine")
+                  "shard")
 
     @classmethod
     def from_json(cls, d: dict) -> "SweepSpec":
@@ -459,14 +441,12 @@ class SweepSpec:
                              f"valid: {sorted(cls._JSON_KEYS)}")
         pol = d.get("policies", "registered")
         shard = d.get("shard")
-        eng = d.get("engine")
         return cls(base=Scenario.from_json(d["base"]),
                    policies=pol if isinstance(pol, str) else tuple(pol),
                    loads=tuple(d.get("loads", ())),
                    seeds=tuple(d.get("seeds", (0,))),
                    hedge_delays=tuple(d.get("hedge_delays", ())),
-                   shard=None if shard is None else ShardSpec.from_json(shard),
-                   engine=None if eng is None else EngineOptions.from_json(eng))
+                   shard=None if shard is None else ShardSpec.from_json(shard))
 
     def to_file(self, path) -> Path:
         path = Path(path)
@@ -498,26 +478,25 @@ def run_scenarios(scenarios: list[Scenario], **cfg_overrides) -> SweepResult:
         for sc in scenarios:
             cfg = sc.fleet_config(**cfg_overrides)
             prepared.append((sc, cfg, sc.run_params(cfg)))
-    # scenarios sharing a (static config, engine options) pair reuse one
-    # compiled program — EngineOptions is frozen/hashable by design —
+    # scenarios sharing a static config reuse one compiled program,
     # lowered on the params of one that needs the wipe, if any does
     lowering: dict = {}
     for sc, cfg, params in prepared:
-        key, wipe = (cfg, sc.engine), needs_recovery_wipe(cfg, params)
-        if key not in lowering or (wipe and not lowering[key][1]):
-            lowering[key] = (params, wipe)
+        wipe = needs_recovery_wipe(cfg, params)
+        if cfg not in lowering or (wipe and not lowering[cfg][1]):
+            lowering[cfg] = (params, wipe)
     compiled: dict = {}
-    for (cfg, engine), (params, _) in lowering.items():
+    for cfg, (params, _) in lowering.items():
         with phase(phases, "lower"):
-            lowered = lower(cfg, params, options=engine)
+            lowered = lower(cfg, params)
         with phase(phases, "compile"):
-            compiled[cfg, engine] = lowered.compile()
+            compiled[cfg] = lowered.compile()
     results = []
     for sc, cfg, params in prepared:
         with phase(phases, "device"):
-            m = jax.block_until_ready(compiled[cfg, sc.engine](params))
+            m = jax.block_until_ready(compiled[cfg](params))
         with phase(phases, "fetch"):
-            m = jax.device_get(m)
+            m = jax.device_get(m[0] if cfg.telemetry else m)
         with phase(phases, "summarize"):
             results.append(summarize(
                 cfg, m, policy=sc.policy,

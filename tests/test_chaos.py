@@ -26,11 +26,11 @@ from dataclasses import replace
 
 import numpy as np
 import pytest
+from _cores import use_core
 
 from repro.core.simulator import Simulator
 from repro.core.workloads import ExponentialService
 from repro.fleetsim.chaos import LinkFailure, check_link_failure
-from repro.fleetsim.options import EngineOptions
 from repro.fleetsim.validate import cross_check_scenario
 from repro.scenarios import fuzz as fuzz_mod
 from repro.scenarios.arrival import TraceArrival
@@ -208,10 +208,10 @@ def test_single_rack_wipe_counters_reconcile_with_trace():
 
 
 # ------------------------------------------- trace replay under TickFuse --
-def test_trace_replay_exact_under_fused():
+def test_trace_replay_exact_under_fused(monkeypatch):
     # seeded property sweep: for arbitrary (valid) traces, the fused
-    # backend ingests the exact per-tick counts and its Metrics row is
-    # identical to the staged backend's
+    # core ingests the exact per-tick counts and its Metrics row is
+    # identical to the staged core's
     rng = np.random.default_rng(11)
     for _ in range(3):
         counts = tuple(int(c) for c in rng.poisson(rng.uniform(0.5, 2.0),
@@ -219,11 +219,11 @@ def test_trace_replay_exact_under_fused():
         if not any(counts):
             counts = counts + (1,)
         sc = _sc(n_ticks=1_500, seed=int(rng.integers(1 << 16)),
-                 arrival=TraceArrival(counts=counts),
-                 engine=EngineOptions(backend="fused"))
+                 arrival=TraceArrival(counts=counts))
+        use_core(monkeypatch, "fused")
         r_fused = sc.run_fleetsim()
-        r_staged = replace(
-            sc, engine=EngineOptions(backend="staged")).run_fleetsim()
+        use_core(monkeypatch, "staged")
+        r_staged = sc.run_fleetsim()
         assert r_fused.row() == r_staged.row()
         tiled = np.tile(counts, -(-1_500 // len(counts)))[:1_500]
         assert r_fused.n_arrivals == tiled.sum()

@@ -11,6 +11,7 @@ import sys
 from pathlib import Path
 
 import pytest
+from _cores import use_core
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
@@ -33,8 +34,10 @@ def test_paper_grid_phase_runs_staged(paper):
     assert paper.metrics.n_arrivals.shape == (4,)
 
 
-def test_fused_phase_is_bit_identical_to_staged(paper):
-    sw = chip_smoke.phase_fused(paper, backend="fused", **TINY)
+def test_fused_phase_is_bit_identical_to_staged(paper, monkeypatch):
+    # the phase must find the fused core selected: on the chip it is
+    use_core(monkeypatch, "fused")
+    sw = chip_smoke.phase_fused(paper, **TINY)
     assert sw.backend == "fused"
 
 

@@ -2,7 +2,7 @@
 
 The acceptance contracts of the shard layer (``repro.fleetsim.shard``):
 
-* ``shard=None`` routes to the untouched ``simulate_batch`` program, and a
+* ``shard=None`` routes to the untouched unsharded batch program, and a
   1-device :class:`ShardSpec` exercises the real ``shard_map`` path with
   results identical to the vmap — both run in-process on any host;
 * on a 2-"device" CPU host (``XLA_FLAGS=
@@ -33,8 +33,6 @@ from repro.fleetsim import (  # noqa: E402
     ServiceSpec,
     ShardSpec,
     make_params,
-    simulate,
-    simulate_batch_sharded,
     sweep_grid,
 )
 from repro.fleetsim.shard import as_shard, pad_params  # noqa: E402
@@ -126,22 +124,6 @@ def test_one_device_shard_matches_vmap():
     for a, b in zip(plain.results, sharded.results):
         assert a == b
     np.testing.assert_array_equal(plain.grid_hist, sharded.grid_hist)
-
-
-def test_simulate_batch_sharded_none_is_plain_batch():
-    """The honest fallback: shard=None must agree with the single-run
-    engine (same per-config program, no mesh in sight)."""
-    cfg = small_cfg()
-    p = make_params(cfg, policy_id=2, rate_per_us=0.05, seed=3)
-    batch = jax.tree.map(lambda a: np.asarray(a)[None], p)
-    out = simulate_batch_sharded(cfg, batch, shard=None)
-    single = simulate(cfg, p)
-    for leaf_b, leaf_s in zip(jax.tree.leaves(out.metrics),
-                              jax.tree.leaves(single)):
-        np.testing.assert_array_equal(np.asarray(leaf_b)[0],
-                                      np.asarray(leaf_s))
-    np.testing.assert_array_equal(np.asarray(out.grid_hist),
-                                  np.asarray(single.hist))
 
 
 # -------------------------------------------------- traced hedge delay ------

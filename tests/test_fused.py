@@ -1,8 +1,8 @@
-"""TickFuse (the fused backend) and the redesigned ``simulate`` entry point.
+"""TickFuse (the fused core) and the one ``simulate`` entry point.
 
-Four contracts from the PR-7 API redesign:
+Four contracts:
 
-* the fused backend is **bit-identical** to the staged backend on the
+* the fused core is **bit-identical** to the staged core on the
   non-stage policy matrix (baseline / c-clone / netclone / racksched /
   netclone+racksched), for every rack count, filter backend (including the
   Pallas TickFuse megakernel in interpret mode), and chunk length — and
@@ -10,16 +10,14 @@ Four contracts from the PR-7 API redesign:
 * dtype packing (``pick_count_dtype`` / ``pack_array``) widens or raises,
   never wraps: an exact integer round-trip for every in-bound value
   (property-tested);
-* :class:`EngineOptions` is the one knob object — invalid combinations
-  fail at options construction/resolution with clear errors, its JSON form
-  is strict-keyed, and ``'auto'`` falls back to staged where fused cannot
-  run;
-* the deprecated entry points (``simulate_batch`` & co.) warn but return
-  results identical to the unified ``simulate``.
+* ``engine.select_core`` alone picks the core: fused on an accelerator for
+  a config without an optional stage, staged everywhere else.  The tests
+  fake the platform it reads (``tests/_cores.py``);
+* ``shard`` is the one execution argument, and scenario and sweep files
+  carry no ``engine`` key.
 """
 
 import json
-import warnings
 from dataclasses import replace
 from pathlib import Path
 
@@ -27,17 +25,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _cores import use_core
 from _hypothesis_compat import given, settings, st
 
 from repro.core.workloads import ExponentialService, load_to_rate
 from repro.fleetsim import (
     POLICY_IDS,
-    EngineOptions,
     FleetConfig,
     ServiceSpec,
     make_params,
+    select_core,
     simulate,
 )
+from repro.fleetsim.engine import PinnedPrng
 from repro.fleetsim.fused import (
     fused_core,
     pack_array,
@@ -79,26 +79,28 @@ def assert_tree_equal(a, b, what=""):
 # ------------------------------------------------- fused == staged, bitwise --
 @pytest.mark.parametrize("n_racks", [1, 2])
 @pytest.mark.parametrize("policy", FUSED_POLICIES)
-def test_fused_bit_identical_to_staged(policy, n_racks):
-    """Same ticks, same draws, same bits: the fused backend replays the
+def test_fused_bit_identical_to_staged(policy, n_racks, monkeypatch):
+    """Same ticks, same draws, same bits: the fused core replays the
     staged program exactly on the whole non-stage policy matrix."""
     cfg = fused_cfg(n_racks=n_racks)
     params = run_params(cfg, policy, load=0.6, seed=3)
-    staged = simulate(cfg, params, options=EngineOptions(backend="staged"))
-    fused = simulate(cfg, params, options=EngineOptions(backend="fused"))
+    staged = simulate(cfg, params)
+    use_core(monkeypatch, "fused")
+    fused = simulate(cfg, params)
     assert_tree_equal(staged, fused, f"{policy}/racks={n_racks}")
 
 
 @pytest.mark.parametrize("policy", ["netclone", "netclone+racksched"])
-def test_fused_tickfuse_kernel_bit_identical(policy):
+def test_fused_tickfuse_kernel_bit_identical(policy, monkeypatch):
     """The Pallas TickFuse switch megakernel (interpret mode on CPU) slots
-    into the fused backend with bit-identical results to the vectorized
+    into the fused core with bit-identical results to the vectorized
     filter path."""
     cfg = fused_cfg(n_racks=2)
     params = run_params(cfg, policy, load=0.6, seed=5)
-    staged = simulate(cfg, params, options=EngineOptions(backend="staged"))
+    staged = simulate(cfg, params)
+    use_core(monkeypatch, "fused")
     cfg_tf = replace(cfg, filter_backend="tickfuse")
-    fused = simulate(cfg_tf, params, options=EngineOptions(backend="fused"))
+    fused = simulate(cfg_tf, params)
     assert_tree_equal(staged, fused, policy)
 
 
@@ -108,15 +110,18 @@ def test_fused_chunk_length_invariant(k):
     with a tail remainder and one clipped to n_ticks) is bit-identical."""
     cfg = fused_cfg(n_racks=2)
     params = run_params(cfg, "netclone", load=0.7, seed=1)
-    ref = simulate(cfg, params, options=EngineOptions(backend="staged"))
-    out = simulate(cfg, params,
-                   options=EngineOptions(backend="fused", ticks_per_chunk=k))
+    ref = simulate(cfg, params)
+    # the engine always runs the default K; the core takes any
+    out = PinnedPrng(jax.jit(
+        lambda p: fused_core(cfg, p, False, k).metrics))(params)
     assert_tree_equal(ref, out, f"K={k}")
 
 
-def test_fused_bit_identical_to_golden():
-    """The fused backend reproduces the PR-1 single-ToR goldens bit for bit
-    (every checked-in case is a non-stage policy)."""
+def test_fused_bit_identical_to_golden(monkeypatch):
+    """The fused core reproduces the PR-1 single-ToR goldens bit for bit
+    (every checked-in case is a non-stage policy; 2,000 ticks are three
+    chunks and a tail)."""
+    use_core(monkeypatch, "fused")
     g = json.loads(GOLDEN.read_text())
     cfg = FleetConfig(service=ServiceSpec.exponential(25.0), **g["cfg"])
     for c in g["cases"]:
@@ -128,9 +133,7 @@ def test_fused_bit_identical_to_golden():
             kw["fail_window"] = tuple(c["fail_window"])
         params = make_params(cfg, POLICY_IDS[c["policy"]], rate, c["seed"],
                              **kw)
-        m = simulate(cfg, params,
-                     options=EngineOptions(backend="fused",
-                                           ticks_per_chunk=300))
+        m = simulate(cfg, params)
         for field, want in c["metrics"].items():
             got = np.asarray(getattr(m, field)).reshape(-1)
             assert np.array_equal(got, np.asarray(want).reshape(-1)), \
@@ -190,39 +193,58 @@ def test_pack_state_round_trip():
     assert_tree_equal(state, unpack_state(packed), "pack round-trip")
 
 
-# -------------------------------------------------------- EngineOptions -----
+# -------------------------------------------------- the one execution path --
+STAGES = {"plain": {}, "coordinator": dict(coordinator=True),
+          "hedge": dict(hedge_timer=True),
+          "telemetry": dict(telemetry=True, window_ticks=100),
+          "batch": dict(server_model="batch")}
+
+
+@pytest.mark.parametrize("stage", list(STAGES))
+@pytest.mark.parametrize("platform", ["cpu", "tpu", "gpu"])
+def test_select_core(platform, stage, monkeypatch):
+    """Fused exactly on an accelerator for a config without an optional
+    stage; staged for every stage and everywhere on the CPU."""
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    want = "fused" if platform != "cpu" and stage == "plain" else "staged"
+    assert select_core(fused_cfg(**STAGES[stage])) == want
+
+
+def _batchify(cfg, policies):
+    return jax.tree.map(lambda *xs: jnp.stack(xs),
+                        *[run_params(cfg, p, seed=i)
+                          for i, p in enumerate(policies)])
+
+
 def test_options_validation():
-    with pytest.raises(ValueError, match="unknown backend"):
-        EngineOptions(backend="warp")
-    with pytest.raises(ValueError, match="ticks_per_chunk"):
-        EngineOptions(ticks_per_chunk=-1)
+    """``shard`` refuses what the sharded runner cannot run: a telemetry
+    config, and scalar params."""
+    tel = fused_cfg(telemetry=True, window_ticks=100)
     with pytest.raises(ValueError, match="sharded runner"):
-        EngineOptions(telemetry=True, shard=2)
+        simulate(tel, _batchify(tel, ["baseline", "netclone"]), shard=1)
+    cfg = fused_cfg()
+    with pytest.raises(ValueError, match="leading sweep axis"):
+        simulate(cfg, run_params(cfg, "netclone"), shard=1)
 
 
-def test_options_json_round_trip_and_strict_keys():
-    o = EngineOptions(backend="fused", ticks_per_chunk=64)
-    assert EngineOptions.from_json(o.to_json()) == o
-    assert EngineOptions.from_json({}) == EngineOptions()
-    with pytest.raises(ValueError, match="unknown engine keys"):
-        EngineOptions.from_json({"backand": "fused"})
-    with pytest.raises(ValueError, match="unknown engine keys"):
-        # the shard layout lives in the shard sub-object, not in engine
-        EngineOptions.from_json({"backend": "fused", "shard": {}})
+def test_scenario_and_sweep_files_refuse_engine_key(tmp_path):
+    """Files are the API: an ``engine`` key (the execution knobs this repo
+    no longer has) is refused by the strict-key check, which names it."""
+    from repro.scenarios import Scenario, SweepSpec
 
-
-def test_resolve_backend():
-    plain = fused_cfg()
-    coord = fused_cfg(coordinator=True)
-    assert EngineOptions(backend="staged").resolve_backend(plain) == "staged"
-    assert EngineOptions(backend="fused").resolve_backend(plain) == "fused"
-    # 'auto' falls back for staged-only stages; explicit 'fused' raises
-    assert EngineOptions(backend="auto").resolve_backend(coord) == "staged"
-    with pytest.raises(ValueError, match="coordinator"):
-        EngineOptions(backend="fused").resolve_backend(coord)
-    with pytest.raises(ValueError, match="telemetry"):
-        EngineOptions(backend="fused",
-                      telemetry=True).resolve_backend(plain)
+    sc = Scenario(name="t", n_ticks=500)
+    sc_file = tmp_path / "scenario.json"
+    sc_file.write_text(json.dumps({**sc.to_json(),
+                                   "engine": {"backend": "fused"}}))
+    with pytest.raises(ValueError, match=r"unknown scenario keys \['engine'\]"):
+        Scenario.from_file(sc_file)
+    sp = SweepSpec(base=sc, policies=("baseline",))
+    sp_file = tmp_path / "sweep.json"
+    sp_file.write_text(json.dumps({**sp.to_json(),
+                                   "engine": {"backend": "staged"}}))
+    with pytest.raises(ValueError, match=r"unknown sweep keys \['engine'\]"):
+        SweepSpec.from_file(sp_file)
+    assert SweepSpec.from_file(sp.to_file(tmp_path / "ok.json")) == sp
 
 
 def test_simulate_rejects_bad_params_shapes():
@@ -231,69 +253,12 @@ def test_simulate_rejects_bad_params_shapes():
     bad = jax.tree.map(lambda a: jnp.stack([jnp.stack([a, a])] * 2), params)
     with pytest.raises(ValueError, match="scalar .*or 1-D"):
         simulate(cfg, bad)
-    with pytest.raises(ValueError, match="leading sweep axis"):
-        simulate(cfg, params, options=EngineOptions(shard=1))
-    with pytest.raises(TypeError, match="EngineOptions"):
-        simulate(cfg, params, options="fused")
-
-
-# ---------------------------------------------------- deprecated shims ------
-def _batchify(cfg, policies):
-    return jax.tree.map(lambda *xs: jnp.stack(xs),
-                        *[run_params(cfg, p, seed=i)
-                          for i, p in enumerate(policies)])
-
-
-def test_simulate_batch_shim_warns_and_matches():
-    from repro.fleetsim import simulate_batch
-
-    cfg = fused_cfg()
-    grid = _batchify(cfg, ["baseline", "netclone"])
-    new = simulate(cfg, grid, options=EngineOptions(backend="staged"))
-    with pytest.warns(DeprecationWarning, match="simulate_batch"):
-        old = simulate_batch(cfg, grid)
-    assert_tree_equal(new, old, "simulate_batch shim")
-
-
-def test_telemetry_shims_warn_and_match():
-    from repro.fleetsim import simulate_batch_telemetry, simulate_telemetry
-
-    cfg = fused_cfg(telemetry=True, window_ticks=100)
-    params = run_params(cfg, "netclone")
-    m_new, tr_new, se_new = simulate(
-        cfg, params, options=EngineOptions(telemetry=True))
-    with pytest.warns(DeprecationWarning, match="simulate_telemetry"):
-        m_old, tr_old, se_old = simulate_telemetry(cfg, params)
-    assert_tree_equal((m_new, tr_new, se_new), (m_old, tr_old, se_old),
-                      "simulate_telemetry shim")
-
-    grid = _batchify(cfg, ["netclone", "c-clone"])
-    b_new = simulate(cfg, grid, options=EngineOptions(telemetry=True))
-    with pytest.warns(DeprecationWarning, match="simulate_batch_telemetry"):
-        b_old = simulate_batch_telemetry(cfg, grid)
-    assert_tree_equal(b_new, b_old, "simulate_batch_telemetry shim")
-
-
-def test_sharded_shim_warns_and_matches():
-    from repro.fleetsim import simulate_batch_sharded
-
-    cfg = fused_cfg()
-    grid = _batchify(cfg, ["baseline", "netclone"])
-    new = simulate(cfg, grid, options=EngineOptions(shard=1))
-    with pytest.warns(DeprecationWarning, match="simulate_batch_sharded"):
-        old = simulate_batch_sharded(cfg, grid, shard=1)
-    assert_tree_equal(new, old, "simulate_batch_sharded shim")
-    # the shard=None honest fallback still works (plain batch + host merge)
-    with pytest.warns(DeprecationWarning):
-        fb = simulate_batch_sharded(cfg, grid)
-    assert_tree_equal(new.metrics, fb.metrics, "shard=None fallback")
-    assert np.array_equal(np.asarray(new.grid_hist),
-                          np.asarray(fb.grid_hist))
+    with pytest.raises(TypeError, match="shard must be"):
+        simulate(cfg, _batchify(cfg, ["netclone"]), shard="fused")
 
 
 def test_no_warning_on_unified_path(recwarn):
-    """The redesigned entry point itself never raises DeprecationWarning —
-    only the legacy names do."""
+    """The entry point raises no DeprecationWarning."""
     cfg = fused_cfg()
     simulate(cfg, run_params(cfg, "netclone"))
     assert not [w for w in recwarn.list
@@ -301,54 +266,36 @@ def test_no_warning_on_unified_path(recwarn):
 
 
 # ------------------------------------------------------------ unified misc --
-def test_unified_fused_batch_and_donate():
-    """Batched fused runs (and donated params) match per-run staged."""
+def test_unified_fused_batch_and_donate(monkeypatch):
+    """Batched fused runs match per-run staged, and the call donates
+    nothing: the caller's params stay usable after it."""
     cfg = fused_cfg(n_racks=2)
     grid = _batchify(cfg, ["netclone", "racksched", "baseline"])
-    ref = simulate(cfg, grid, options=EngineOptions(backend="staged"))
-    out = simulate(cfg, grid, options=EngineOptions(backend="fused"))
+    ref = simulate(cfg, grid)
+    use_core(monkeypatch, "fused")
+    out = simulate(cfg, grid)
     assert_tree_equal(ref, out, "fused batch")
-    donated = simulate(cfg, grid,
-                       options=EngineOptions(backend="fused", donate=True))
-    assert_tree_equal(ref, donated, "fused batch, donated params")
+    assert_tree_equal(ref, simulate(cfg, grid), "fused batch, params reused")
 
 
-def test_lower_compiles_every_backend():
+def test_lower_compiles_every_backend(monkeypatch):
     from repro.fleetsim import lower
 
     cfg = fused_cfg()
     params = run_params(cfg, "netclone")
-    for opts in (None, EngineOptions(backend="fused"),
-                 EngineOptions(backend="staged")):
-        compiled = lower(cfg, params, options=opts).compile()
+    for core in ("staged", "fused"):
+        use_core(monkeypatch, core)
+        compiled = lower(cfg, params).compile()
         m = jax.block_until_ready(compiled(params))
         assert int(m.n_arrivals) > 0
-    with pytest.raises(ValueError, match="lower_sharded"):
-        lower(cfg, _batchify(cfg, ["baseline"]),
-              options=EngineOptions(shard=1))
 
 
-def test_scenario_engine_sub_object_round_trip():
-    from repro.scenarios import Scenario, SweepSpec
-
-    sc = Scenario(name="t", n_ticks=500,
-                  engine=EngineOptions(backend="fused", ticks_per_chunk=50))
-    sc2 = Scenario.from_json(json.loads(json.dumps(sc.to_json())))
-    assert sc2.engine == sc.engine
-    sp = SweepSpec(base=sc, policies=("baseline",),
-                   engine=EngineOptions(backend="staged"))
-    sp2 = SweepSpec.from_json(json.loads(json.dumps(sp.to_json())))
-    assert sp2.engine == sp.engine
-    with pytest.raises(ValueError, match="unknown scenario keys"):
-        Scenario.from_json({"engine": {"backend": "auto"}, "enginee": {}})
-
-
-def test_sweep_backend_recorded():
+def test_sweep_backend_recorded(monkeypatch):
     from repro.fleetsim import sweep_grid
 
-    res = sweep_grid(SVC, ["baseline"], [0.5], [0], n_racks=1, n_ticks=500,
-                     engine=EngineOptions(backend="fused"))
-    assert res.backend == "fused"
     res2 = sweep_grid(SVC, ["baseline"], [0.5], [0], n_racks=1, n_ticks=500)
-    assert res2.backend == "staged"  # 'auto' on CPU
+    assert res2.backend == "staged"  # the CPU
+    use_core(monkeypatch, "fused")
+    res = sweep_grid(SVC, ["baseline"], [0.5], [0], n_racks=1, n_ticks=500)
+    assert res.backend == "fused"
     assert [r.row() for r in res.results] == [r.row() for r in res2.results]

@@ -30,7 +30,6 @@ import pytest
 from repro.core.workloads import LLMBimodalService, load_to_rate
 from repro.fleetsim import (
     POLICY_IDS,
-    EngineOptions,
     FleetConfig,
     ServiceSpec,
     make_params,
@@ -129,10 +128,6 @@ def test_batch_config_validation():
                       server_model="batch")
     assert cfg.n_slots == 4
     assert replace(cfg, batch_slots=6).n_slots == 6
-    # fused backend: batch is staged-only; auto falls back
-    with pytest.raises(ValueError, match="batch server stage"):
-        EngineOptions(backend="fused").resolve_backend(cfg)
-    assert EngineOptions(backend="auto").resolve_backend(cfg) == "staged"
 
 
 # --------------------------------------------- fcfs golden / batch == fcfs --

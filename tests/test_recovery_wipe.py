@@ -18,11 +18,11 @@ them:
 import jax
 import numpy as np
 import pytest
+from _cores import use_core
 
 from repro.core.workloads import ExponentialService, load_to_rate
 from repro.fleetsim import (
     POLICY_IDS,
-    EngineOptions,
     FleetConfig,
     ServiceSpec,
     make_params,
@@ -30,7 +30,6 @@ from repro.fleetsim import (
     sweep_grid,
 )
 from repro.fleetsim import engine
-from repro.fleetsim.fused import resolve_chunk
 from repro.scenarios import registry
 from repro.scenarios.spec import Scenario, run_scenarios
 
@@ -55,8 +54,7 @@ def _params(cfg, policy, window, seed=3):
 
 def _forced(cfg, params, backend, wipe):
     """The entry's program for one run with the wipe forced in or out."""
-    k = resolve_chunk(cfg) if backend == "fused" else 0
-    return engine._entry(backend, False, False, False, k)(
+    return engine._entry(backend, False, False)(
         cfg, registry.version(), wipe, params)
 
 
@@ -65,22 +63,21 @@ def _equal(a, b) -> bool:
                for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
 
 
-def _scenario(backend, window, seed):
+def _scenario(window, seed):
     return Scenario(name=f"wipe-{seed}", policy="netclone", load=0.6,
                     seed=seed, racks=1, servers=4, workers=8,
                     n_ticks=N_TICKS, service=ServiceSpec.exponential(25.0),
-                    fail_window_ticks=window,
-                    engine=EngineOptions(backend=backend))
+                    fail_window_ticks=window)
 
 
 @pytest.mark.parametrize("backend", ["staged", "fused"])
-def test_mixed_batch_matches_each_scenario_alone(backend):
+def test_mixed_batch_matches_each_scenario_alone(backend, monkeypatch):
     """One program serves both scenarios and keeps the wipe (the run
     without a window comes first, so the program is not lowered on its
     params); each row is the answer of that scenario run alone, the one
     without a window on the program without the wipe."""
-    plain, windowed = _scenario(backend, None, 1), _scenario(backend,
-                                                            WINDOW, 2)
+    use_core(monkeypatch, backend)
+    plain, windowed = _scenario(None, 1), _scenario(WINDOW, 2)
     both = run_scenarios([plain, windowed])
     alone = [run_scenarios([sc]) for sc in (plain, windowed)]
     assert both.recovery_wipe is True
@@ -90,12 +87,12 @@ def test_mixed_batch_matches_each_scenario_alone(backend):
 
 
 @pytest.mark.parametrize("backend", ["staged", "fused"])
-def test_recovery_at_the_horizon_compiles_no_wipe(backend):
+def test_recovery_at_the_horizon_compiles_no_wipe(backend, monkeypatch):
     """``tick == n_ticks`` is never reached: a window recovering there, or
     one that neither opens nor recovers inside the horizon, compiles no
     wipe, and answers as the program that keeps it."""
+    use_core(monkeypatch, backend)
     cfg = _cfg("netclone")
-    opts = EngineOptions(backend=backend)
     never = _params(cfg, "netclone", (N_TICKS, N_TICKS))
     late = _params(cfg, "netclone", (600, N_TICKS))
     assert not engine.needs_recovery_wipe(cfg, _params(cfg, "netclone",
@@ -104,31 +101,32 @@ def test_recovery_at_the_horizon_compiles_no_wipe(backend):
     assert not engine.needs_recovery_wipe(cfg, late)
     assert engine.needs_recovery_wipe(
         cfg, _params(cfg, "netclone", (600, N_TICKS - 1)))
-    assert _equal(simulate(cfg, never, options=opts),
-                  simulate(cfg, _params(cfg, "netclone", None),
-                           options=opts))
-    got = simulate(cfg, late, options=opts)
+    assert _equal(simulate(cfg, never),
+                  simulate(cfg, _params(cfg, "netclone", None)))
+    got = simulate(cfg, late)
     assert int(got.n_dropped_down) > 0
     assert _equal(got, _forced(cfg, late, backend, True))
 
 
 @pytest.mark.parametrize("backend, policy", [
     ("staged", "netclone"), ("staged", "hedge"), ("fused", "netclone")])
-def test_simulate_under_outer_jit_keeps_the_wipe(backend, policy):
+def test_simulate_under_outer_jit_keeps_the_wipe(backend, policy,
+                                                 monkeypatch):
     """Under a caller's ``jit`` the entry sees tracers and keeps the wipe;
     the eager call reads the window and keeps it too.  Both answer as the
     program with the wipe forced in, and the wipe acts in this run: the
     program with it forced out answers otherwise."""
+    use_core(monkeypatch, backend)
     cfg = _cfg(policy)
-    opts = EngineOptions(backend=backend)
+    assert engine.select_core(cfg) == backend
     params = _params(cfg, policy, WINDOW)
     seen = []
 
     def traced(p):
         seen.append(engine.needs_recovery_wipe(cfg, p))
-        return simulate(cfg, p, options=opts)
+        return simulate(cfg, p)
 
-    eager = simulate(cfg, params, options=opts)
+    eager = simulate(cfg, params)
     assert _equal(jax.jit(traced)(params), eager)
     assert seen == [True]
     assert _equal(eager, _forced(cfg, params, backend, True))

@@ -8,16 +8,17 @@ from dataclasses import replace
 import jax
 import jax.numpy as jnp
 import pytest
+from _cores import use_core
 
 from repro.core.workloads import ExponentialService, load_to_rate
 from repro.fleetsim import (
     POLICY_IDS,
-    EngineOptions,
     FleetConfig,
     ServiceSpec,
     make_params,
 )
 from repro.fleetsim.engine import lower
+from repro.fleetsim.fused import DEFAULT_TICKS_PER_CHUNK
 from repro.fleetsim.spans import (
     COUNTERS,
     compile_counts,
@@ -38,14 +39,14 @@ OPTIONAL_SCOPES = {"tick.coordinator", "tick.hedge_timer"}
 PHASES = {"params", "lower", "compile", "device", "fetch", "summarize"}
 
 
-def compiled_scopes(cfg, policies, options, fail_window=None):
+def compiled_scopes(cfg, policies, fail_window=None):
     """Every ``tick.*``/``fleetsim.*`` scope named in the ``op_name``
     metadata of the compiled batch program of ``policies``."""
     rate = load_to_rate(0.5, SVC, cfg.n_servers_total, cfg.n_workers)
     rows = [make_params(cfg, POLICY_IDS[p], rate, 0, fail_window=fail_window)
             for p in policies]
     params = jax.tree.map(lambda *a: jnp.stack(a), *rows)
-    text = lower(cfg, params, options=options).compile().as_text()
+    text = lower(cfg, params).compile().as_text()
     return {m.group(0) for op in re.findall(r'op_name="([^"]*)"', text)
             for m in re.finditer(r"(tick|fleetsim)\.[a-z_]+", op)}
 
@@ -55,11 +56,12 @@ def tiny_cfg(**kw):
 
 
 @pytest.mark.parametrize("backend", ["staged", "fused"])
-def test_default_programs_carry_every_default_stage_scope(backend):
+def test_default_programs_carry_every_default_stage_scope(backend,
+                                                          monkeypatch):
+    use_core(monkeypatch, backend)
     # four fused chunks, so the carry is packed between chunks
-    scopes = compiled_scopes(tiny_cfg(), ["baseline", "netclone"],
-                             EngineOptions(backend=backend,
-                                           ticks_per_chunk=16))
+    cfg = replace(tiny_cfg(), n_ticks=4 * DEFAULT_TICKS_PER_CHUNK)
+    scopes = compiled_scopes(cfg, ["baseline", "netclone"])
     assert DEFAULT_SCOPES <= scopes
     # stages compiled out leave no op behind to carry their scope
     assert not scopes & OPTIONAL_SCOPES
@@ -72,19 +74,18 @@ def test_optional_stage_scopes_appear_when_compiled_in():
     cfg = replace(tiny_cfg(), telemetry=True,
                   window_ticks=16).with_policy_stages(policies)
     assert cfg.coordinator and cfg.hedge_timer
-    scopes = compiled_scopes(cfg, policies,
-                             EngineOptions(backend="staged", telemetry=True))
+    scopes = compiled_scopes(cfg, policies)
     assert DEFAULT_SCOPES | OPTIONAL_SCOPES | {"tick.telemetry"} <= scopes
 
 
 @pytest.mark.parametrize("backend", ["staged", "fused"])
-def test_wipe_scope_only_where_a_run_recovers(backend):
+def test_wipe_scope_only_where_a_run_recovers(backend, monkeypatch):
     """The §3.6 wipe runs under ``tick.arrival/tick.wipe`` in a program
     whose window recovers inside the horizon, and a window-free program
     holds no op of it."""
-    opts = EngineOptions(backend=backend)
-    plain = compiled_scopes(tiny_cfg(), ["netclone"], opts)
-    windowed = compiled_scopes(tiny_cfg(), ["netclone"], opts,
+    use_core(monkeypatch, backend)
+    plain = compiled_scopes(tiny_cfg(), ["netclone"])
+    windowed = compiled_scopes(tiny_cfg(), ["netclone"],
                                fail_window=(16, 32))
     assert "tick.wipe" not in plain
     assert DEFAULT_SCOPES | {"tick.wipe"} <= windowed
@@ -118,9 +119,9 @@ def test_sweep_phases_and_their_sums(call):
     assert set(sw.compile_events) == set(COUNTERS)
 
 
-def test_repeated_call_reuses_the_program():
-    kw = dict(policies=["baseline"], loads=[0.3], seeds=[0],
-              engine=EngineOptions(backend="fused"), **TINY)
+def test_repeated_call_reuses_the_program(monkeypatch):
+    use_core(monkeypatch, "fused")
+    kw = dict(policies=["baseline"], loads=[0.3], seeds=[0], **TINY)
     kw["n_ticks"] = 48      # a program no other test compiles
     first = sweep_grid(SVC, **kw).compile_events
     again = sweep_grid(SVC, **{**kw, "seeds": [1]}).compile_events

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.fleetsim import EngineOptions, FleetConfig, lower, make_params
+from repro.fleetsim import FleetConfig, lower, make_params, select_core
 from repro.kernels import ops
 from repro.kernels.fingerprint_filter import fingerprint_filter
 from repro.kernels.tickfuse import (
@@ -106,18 +106,19 @@ def test_smem_limit_is_the_compilers(one_chip):
 def test_fused_tickfuse_sweep_program_compiles(one_chip, monkeypatch):
     """The whole fused 4-rack sweep program with the TickFuse kernel, as
     the engine builds it for a 56-config grid on one chip."""
-    # the engine asks the process's backend (CPU here) whether to interpret
+    # the engine asks the process's backend (CPU here) which core to run
+    # and whether to interpret: the chip's answers are fused and no
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(ops, "interpret_mode", lambda: False)
     cfg = FleetConfig(n_racks=4, filter_backend="tickfuse")
+    assert select_core(cfg) == "fused"
     one = make_params(cfg, 0, 0.5, 0)
     params = jax.tree.map(
         lambda a: _sds(one_chip, (GRID,) + a.shape, a.dtype), one)
-    compiled = lower(cfg, params,
-                     options=EngineOptions(backend="fused")).compile()
+    compiled = lower(cfg, params).compile()
     assert "tpu_custom_call" in compiled.as_text()
     # the vectorized filter path of the same program has no kernel
-    vec = lower(replace(cfg, filter_backend="vectorized"), params,
-                options=EngineOptions(backend="fused")).compile()
+    vec = lower(replace(cfg, filter_backend="vectorized"), params).compile()
     assert "tpu_custom_call" not in vec.as_text()
 
 
@@ -186,20 +187,21 @@ def tick_table_ops(text: str, n: int) -> dict[str, list[str]]:
 
 
 @pytest.mark.parametrize("racks", [1, 4])
-def test_tick_keeps_the_filter_tables_in_place(one_chip, racks):
+def test_tick_keeps_the_filter_tables_in_place(one_chip, racks, monkeypatch):
     """The fused sweep program of the testbed cell (and of a 4-rack fabric)
     takes no relayout of the filter tables inside the tick: no copy,
     reshape or transpose has their size, and the only ops over them are
     the recovery wipe's select (abstract params keep it) and the filter's
     scatter."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = FleetConfig(n_racks=racks, n_servers=6, n_workers=15, n_clients=2,
                       n_filter_tables=2, n_filter_slots=2 ** 17,
                       n_ticks=1024)
+    assert select_core(cfg) == "fused"
     one = make_params(cfg, 0, 0.5, 0)
     params = jax.tree.map(
         lambda a: _sds(one_chip, (ROWS,) + a.shape, a.dtype), one)
-    text = lower(cfg, params,
-                 options=EngineOptions(backend="fused")).compile().as_text()
+    text = lower(cfg, params).compile().as_text()
     ops_ = tick_table_ops(text, ROWS * cfg.filter_table_size)
     print(f"{racks} rack(s): {len(ops_['relayout'])} table-sized relayouts "
           f"in the tick body; ops {ops_}")
@@ -208,7 +210,7 @@ def test_tick_keeps_the_filter_tables_in_place(one_chip, racks):
         "scatter", "select"]
 
 
-def test_staged_tick_keeps_the_filter_tables_in_place(one_chip):
+def test_staged_tick_keeps_the_filter_tables_in_place(one_chip, monkeypatch):
     """The staged sweep program of the seven-policy testbed grid (the
     coordinator and hedge-timer stages compiled in, 7 policies x 8 loads)
     takes no relayout of the filter tables inside
@@ -222,7 +224,9 @@ def test_staged_tick_keeps_the_filter_tables_in_place(one_chip):
     one = make_params(cfg, 0, 0.5, 0)
     params = jax.tree.map(
         lambda a: _sds(one_chip, (GRID,) + a.shape, a.dtype), one)
-    assert EngineOptions().resolve_backend(cfg) == "staged"
+    # staged on the chip too: the optional stages keep it off the fused core
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert select_core(cfg) == "staged"
     text = lower(cfg, params).compile().as_text()
     ops_ = tick_table_ops(text, GRID * cfg.filter_table_size)
     print(f"staged: {len(ops_['relayout'])} table-sized relayouts in the "
@@ -254,16 +258,19 @@ def _testbed_grid(one_chip, staged: bool, window):
 @pytest.mark.parametrize("window", [None, (100, 200)],
                          ids=["no-window", "window"])
 @pytest.mark.parametrize("staged", [False, True], ids=["fused", "staged"])
-def test_tick_wipes_the_tables_only_with_a_window(one_chip, staged, window):
+def test_tick_wipes_the_tables_only_with_a_window(one_chip, staged, window,
+                                                  monkeypatch):
     """Without a failure window no switch recovers inside the horizon, so
     the tick holds no select over the filter tables (the §3.6 wipe is not
     compiled in) and only the filter's scatter is table-sized compute; a
     window recovering inside the horizon compiles the wipe's select in."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg, params, rows = _testbed_grid(one_chip, staged, window)
-    opts = EngineOptions(backend="staged" if staged else "fused")
-    text = lower(cfg, params, options=opts).compile().as_text()
+    core = select_core(cfg)
+    assert core == ("staged" if staged else "fused")
+    text = lower(cfg, params).compile().as_text()
     ops_ = tick_table_ops(text, rows * cfg.filter_table_size)
-    print(f"{opts.backend}, window {window}: ops {ops_}")
+    print(f"{core}, window {window}: ops {ops_}")
     assert ops_["relayout"] == []
     assert sorted(o.split()[-1] for o in ops_["compute"]) == (
         ["scatter"] if window is None else ["scatter", "select"])
