@@ -476,6 +476,7 @@ def stage_coordinator(cfg: FleetConfig, params, state: FleetState,
     waiting = coord.count > 0
     u = jax.random.uniform(arr.k_stage, (A, 2))
     branches = registry.coordinator_branches()
+    sid = jnp.arange(ST)
 
     def decide(outstanding, x):
         g, u_j = x
@@ -487,10 +488,10 @@ def stage_coordinator(cfg: FleetConfig, params, state: FleetState,
         # only while no server is idle, so this is the DES's order too)
         send = g & (n_idle >= 1) & ~waiting
         clone = send & want_clone
-        outstanding = outstanding.at[jnp.where(send, s1, ST)].add(
-            1, mode="drop")
-        outstanding = outstanding.at[jnp.where(clone, s2, ST)].add(
-            1, mode="drop")
+        # one-hot adds, not scatters: in the scan a 1-element scatter into
+        # the (rows, ST) counts costs far more than an elementwise add
+        outstanding = (outstanding + ((sid == s1) & send)
+                       + ((sid == s2) & clone))
         return outstanding, (send, clone, s1, s2)
 
     outstanding, (send, clone, s1, s2) = jax.lax.scan(

@@ -274,3 +274,38 @@ def test_tick_wipes_the_tables_only_with_a_window(one_chip, staged, window,
     assert ops_["relayout"] == []
     assert sorted(o.split()[-1] for o in ops_["compute"]) == (
         ["scatter"] if window is None else ["scatter", "select"])
+
+
+def test_coordinator_scan_holds_no_scatter(one_chip, monkeypatch):
+    """The LÆDGE coordinator's decision scan in ``all7``'s staged grid
+    counts each server's outstanding copies with one-hot adds: its body
+    holds no scatter, neither as an op nor inside a fusion.  The scan is
+    the ``while`` that carries the counts (``s32[rows, servers]``) beside
+    the per-lane decisions it emits (``pred[lanes, rows]``)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params, rows = _testbed_grid(one_chip, True, None)
+    text = lower(cfg, params).compile().as_text()
+    comps = _computations(text)
+    carry = {("s32", f"{rows},{cfg.n_servers_total}"),
+             ("pred", f"{cfg.max_arrivals},{rows}")}
+
+    def arrays(shape):
+        return set(re.findall(r"([a-z]\w*)\[([\d,]*)\]", shape))
+
+    scans = [re.search(r"body=%([\w.-]+)", rest).group(1)
+             for insts in comps.values()
+             for _, _, shape, op, rest in insts
+             if op == "while" and carry <= arrays(shape)]
+    assert len(scans) == 1, scans
+
+    def scatters(comp):
+        for _, name, shape, op, rest in comps[comp]:
+            called = re.search(r"calls=%([\w.-]+)", rest)
+            if op == "scatter" or (called
+                                     and any(scatters(called.group(1)))):
+                yield f"{name} {shape} {op}"
+
+    found = list(scatters(scans[0]))
+    print(f"decision scan body: {len(comps[scans[0]])} ops, "
+          f"scatters {found}")
+    assert found == []
